@@ -119,3 +119,49 @@ def test_crl_refresher_reloads_on_mtime_change(tmp_path):
     assert r.refresh() == 1
     assert r.refreshes == 2
     assert ctx.verify_flags & ssl.VERIFY_CRL_CHECK_LEAF
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("generation,pause_s,frozen", [
+    (2, 0.08, 1),    # a full pass that stopped every thread too long
+    (2, 0.0, 0),     # a quick full pass: nothing to do
+    (1, 0.08, 0),    # young generations never walk the long-lived heap
+])
+async def test_long_full_gc_freezes_its_survivors(monkeypatch, generation,
+                                                  pause_s, frozen):
+    """A full collector pass walks every tracked object with every
+    thread stopped — seconds at a million subscriptions. One that paused
+    past a fifth of the lag threshold has its survivors frozen out of
+    later passes, on the loop, not from inside the collector."""
+    import gc
+
+    class FakeMetrics:
+        counts = {}
+
+        def incr(self, name, n=1):
+            self.counts[name] = self.counts.get(name, 0) + n
+
+    class FakeBroker:
+        metrics = FakeMetrics()
+        overload = None
+
+    calls = []
+    monkeypatch.setattr(gc, "freeze", lambda: calls.append("freeze"))
+    monkeypatch.setattr(gc, "collect", lambda *a: calls.append("collect"))
+    mon = Sysmon(FakeBroker(), lag_threshold=0.25)
+    assert mon.gc_freeze_pause == pytest.approx(0.05)
+    mon.start()
+    try:
+        assert mon._on_gc in gc.callbacks
+        mon._on_gc("start", {"generation": generation})
+        time.sleep(pause_s)
+        mon._on_gc("stop", {"generation": generation})
+        assert calls == []  # never from inside the collector
+        await asyncio.sleep(0.01)
+        assert calls == ["freeze", "collect"] * frozen
+        assert mon.gc_freezes == frozen
+        assert FakeBroker.metrics.counts.get("sysmon_long_gc", 0) == frozen
+        assert mon.status()["gc_freezes"] == frozen
+    finally:
+        mon.stop()
+    assert mon._on_gc not in gc.callbacks

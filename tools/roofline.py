@@ -49,7 +49,7 @@ def run_probe(args) -> None:
         match_many_probe
     from vernemq_tpu.models.tpu_table import SubscriptionTable
 
-    jax_mod, devices, fallback = init_backend()
+    jax_mod, devices = init_backend(args.platform)
     platform = devices[0].platform
     smoke = platform == "cpu"
     subs = min(args.subs, 100_000) if smoke else args.subs
@@ -69,7 +69,7 @@ def run_probe(args) -> None:
     out = match_many_probe(wb, ks=ks, reps=args.probe_reps,
                            probe_batch=batch)
     out.update({"mode": "measured_match_many_probe",
-                "platform": platform, "platform_fallback": fallback,
+                "platform": platform,
                 "subs": subs, "batch": batch})
     print(json.dumps(out, indent=1))
 
@@ -94,6 +94,9 @@ def main() -> None:
                          "amortization probe (K-batch ladder, measured) "
                          "instead of the analytic model; smoke-scales "
                          "on CPU")
+    ap.add_argument("--platform", default=None,
+                    help="--probe: force a jax platform (e.g. cpu); "
+                         "without it no accelerator is an error")
     ap.add_argument("--probe-ks", default="1,2,4,8,16")
     ap.add_argument("--probe-reps", type=int, default=2)
     ap.add_argument("--probe-batch", type=int, default=None)

@@ -1,6 +1,7 @@
 #!/usr/bin/env bash
-# Canonical tier-1 verification (the exact command ROADMAP.md specifies,
-# encapsulated so CI and humans run the same thing).
+# Canonical tier-1 verification: the pytest invocation the driver runs
+# (6 xdist workers, --dist loadfile, 1470 s wall), behind the native
+# warm-up and the vmqlint gate, so CI and humans run the same thing.
 #
 #   tools/run_tier1.sh                 # tier-1: everything but -m slow
 #   tools/run_tier1.sh -m chaos        # your -m replaces the marker filter
@@ -16,7 +17,8 @@ set -o pipefail
 cd "$(dirname "$0")/.."
 
 LOG=${TIER1_LOG:-/tmp/_t1.log}
-TIMEOUT=${TIER1_TIMEOUT:-870}
+TIMEOUT=${TIER1_TIMEOUT:-1470}
+WORKERS=${TIER1_WORKERS:-6}
 if [ $# -gt 0 ]; then
   case " $* " in
     *" -m "*|*" -m="*|*" --markers "*) EXTRA=("$@") ;;
@@ -80,8 +82,8 @@ rm -f "$LOG"
 timeout -k 10 "$TIMEOUT" env JAX_PLATFORMS=cpu \
   TIER1_FAULTHANDLER_S="$DUMP_S" \
   python -m pytest tests/ -q "${EXTRA[@]}" \
-  --continue-on-collection-errors -p no:cacheprovider -p no:xdist \
-  -p no:randomly 2>&1 | tee "$LOG"
+  --continue-on-collection-errors -p no:cacheprovider -p xdist \
+  -n "$WORKERS" --dist loadfile -p no:randomly 2>&1 | tee "$LOG"
 rc=${PIPESTATUS[0]}
 
 # opt-in chaos leg (TIER1_CHAOS=1): after a green tier-1 run, also run
@@ -95,8 +97,8 @@ if [ "${TIER1_CHAOS:-0}" = "1" ] && [ "$rc" -eq 0 ]; then
   timeout -k 10 "$TIMEOUT" env JAX_PLATFORMS=cpu \
     TIER1_FAULTHANDLER_S="$DUMP_S" \
     python -m pytest tests/ -q -m chaos \
-    --continue-on-collection-errors -p no:cacheprovider -p no:xdist \
-    -p no:randomly 2>&1 | tee "$CLOG"
+    --continue-on-collection-errors -p no:cacheprovider -p xdist \
+    -n "$WORKERS" --dist loadfile -p no:randomly 2>&1 | tee "$CLOG"
   rc=${PIPESTATUS[0]}
   cat "$CLOG" >> "$LOG"
 fi

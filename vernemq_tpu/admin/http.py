@@ -21,6 +21,7 @@ import logging
 from typing import Any, Dict, List, Optional, Tuple
 from urllib.parse import parse_qsl, unquote, urlsplit
 
+from ..utils.aio import close_server
 from .commands import CommandError, CommandRegistry, register_core_commands, valid_api_key
 
 log = logging.getLogger("vernemq_tpu.http")
@@ -41,6 +42,7 @@ class HttpServer:
         self.registry = registry or register_core_commands(CommandRegistry())
         self.ssl_context = ssl_context
         self._server: Optional[asyncio.AbstractServer] = None
+        self._writers: set = set()  # live accepted connections
 
     async def start(self) -> None:
         self._server = await asyncio.start_server(
@@ -50,14 +52,13 @@ class HttpServer:
         self.broker._servers.append(self._server)
 
     async def stop(self) -> None:
-        if self._server is not None:
-            self._server.close()
-            await self._server.wait_closed()
+        await close_server(self._server, self._writers)
 
     # ------------------------------------------------------------- plumbing
 
     async def _handle(self, reader: asyncio.StreamReader,
                       writer: asyncio.StreamWriter) -> None:
+        self._writers.add(writer)
         try:
             while True:
                 try:
@@ -113,6 +114,7 @@ class HttpServer:
         except Exception:
             log.exception("http handler crashed")
         finally:
+            self._writers.discard(writer)
             try:
                 writer.close()
             except Exception:

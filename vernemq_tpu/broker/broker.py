@@ -747,11 +747,6 @@ class Broker:
         view = self.registry.reg_views.get("tpu")
         st = getattr(view, "mesh_status", None)
         st = st() if st is not None else None
-        if view is not None and st is None:
-            # tpu view built but serving single-chip (tpu_mesh degraded
-            # / mesh-native off): local residency must read zero — the
-            # configured slice count stays visible for diagnosis
-            out["mesh_slices_local"] = 0.0
         if st:
             out["mesh_slices_total"] = max(out["mesh_slices_total"],
                                            float(st["slices"]))
@@ -1601,14 +1596,12 @@ class Broker:
 
     def retained_collector(self):
         """Retained-replay batch collector, or None when the device
-        retained path is off (config) or the accelerator never came up —
-        the subscribe path then serves the exact host walk directly."""
+        retained path is off (config) — the subscribe path then serves
+        the exact host walk directly."""
         cfg = self.config
         if (cfg.default_reg_view != "tpu"
                 or not cfg.get("tpu_retained_enabled", True)):
             return None
-        if not self.registry.batched_view_active():
-            return None  # accelerator down/cold: host walk serves replays
         if self._retained_collector is None:
             from ..retained.collector import RetainedBatchCollector
 
@@ -1796,19 +1789,6 @@ class Broker:
         if self.mesh_map is not None:
             def _mesh_reclaim(*_a) -> None:
                 try:
-                    # a built tpu view that came up WITHOUT its mesh
-                    # (tpu_mesh asked for more devices than exist — the
-                    # documented loud degrade to single-chip) must not
-                    # keep advertising slice ownership it cannot serve
-                    view = self.registry.reg_views.get("tpu")
-                    if view is not None and (
-                            getattr(view, "mesh_status", None) is None
-                            or view.mesh_status() is None):
-                        log.warning(
-                            "mesh slice claim skipped: the tpu view is "
-                            "serving single-chip (tpu_mesh degraded or "
-                            "mesh-native disabled)")
-                        return
                     members = (self.cluster.members()
                                if self.cluster is not None else None)
                     self.mesh_map.claim_local(members)
@@ -1959,15 +1939,25 @@ class Broker:
                 log.error("reg_views names unknown view %r (valid: %s)",
                           view_name, ", ".join(valid_views))
                 continue
+            if view_name == self.config.default_reg_view:
+                continue  # built below, where failure is a boot error
             try:
                 self.registry.reg_view(view_name)
             except Exception:
-                # pre-building is an optimization, never a boot gate: a
-                # failing device-view build logs and stays lazy (the
-                # accel probe/recovery machinery retries it), routing
-                # serves on the default view either way
+                # pre-building a NON-default view is an optimization,
+                # never a boot gate: it logs and stays lazy
                 log.exception("reg_views: building view %r failed at "
                               "boot; it stays lazy", view_name)
+        if self.config.default_reg_view == "tpu":
+            # the view routing depends on: a backend that cannot
+            # initialise (or a tpu_mesh it cannot satisfy) stops the
+            # boot here instead of serving from the host trie unnoticed
+            view = self.registry.reg_view("tpu")
+            if len(self.registry.trie("")) and hasattr(view, "begin_load"):
+                # subscriptions came back with the subscriber DB: build
+                # their device table now, off the loop thread, instead of
+                # behind the first publish burst
+                view.begin_load("")
         # adaptive overload governor BEFORE sysmon so the lag sampler can
         # feed it from its very first sample (robustness/overload.py)
         from ..robustness.overload import OverloadGovernor

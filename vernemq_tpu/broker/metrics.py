@@ -167,6 +167,9 @@ COUNTERS: List[Tuple[str, str]] = [
      "Event-loop lag events over the sysmon threshold."),
     ("sysmon_large_heap",
      "Forced GCs after crossing the memory high watermark."),
+    ("sysmon_long_gc",
+     "Full GC passes that paused past a fifth of the lag threshold and "
+     "had their survivors frozen out of later passes."),
     # adaptive overload governor (robustness/overload.py): one counter
     # per shed stage so operators see WHICH response is carrying load
     ("overload_publish_throttled",

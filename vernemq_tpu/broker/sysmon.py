@@ -10,6 +10,15 @@ asyncio equivalents:
   ``overloaded`` flag, which the session layer turns into read throttling
   (the load-shedding role of the reference's throttle return,
   ``vmq_ranch.erl:198-203``).
+- **long GC**: a full collection of Python's cyclic collector walks every
+  tracked object with every thread stopped. A broker holds millions of
+  long-lived ones (a trie node, a table row and a SubOpts per
+  subscription): seconds per pass at a million subscriptions — the whole
+  loop-lag alarm by itself. A full pass that paused longer than a fifth
+  of the lag threshold has its survivors frozen (``gc.freeze()``): they
+  just proved long-lived, and later passes walk only what came after.
+  Reference counting still frees frozen objects; only a cycle formed
+  among them later is never collected.
 - **memory watermark**: RSS read from ``/proc/self/statm``; crossing the
   high watermark triggers ``gc.collect()`` (the forced-GC response to
   large_heap) and counts a metric.
@@ -63,17 +72,58 @@ class Sysmon:
         self.lag_events = 0
         self.overload_extends = 0  # cooldowns re-armed by boundary lag
         self.gc_forced = 0
+        # long-GC response (module docstring): full-collection pauses
+        # past this freeze their survivors
+        self.gc_freeze_pause = lag_threshold / 5.0
+        self.gc_freezes = 0
+        self.gc_max_pause = 0.0
+        self._gc_t0 = 0.0
+        self._loop: Optional[asyncio.AbstractEventLoop] = None
         self.last_lag = 0.0
         self.overloaded_until = 0.0
         self._task: Optional[asyncio.Task] = None
 
     def start(self) -> None:
-        self._task = asyncio.get_event_loop().create_task(self._run())
+        self._loop = asyncio.get_event_loop()
+        self._task = self._loop.create_task(self._run())
+        if self.gc_freeze_pause > 0:
+            gc.callbacks.append(self._on_gc)
 
     def stop(self) -> None:
         if self._task is not None:
             self._task.cancel()
             self._task = None
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+
+    def _on_gc(self, phase: str, info: Dict[str, Any]) -> None:
+        """gc callback: runs on whichever thread triggered the
+        collection, with every other thread stopped."""
+        if info.get("generation") != 2:
+            return
+        if phase == "start":
+            self._gc_t0 = time.monotonic()
+            return
+        pause = time.monotonic() - self._gc_t0
+        self.gc_max_pause = max(self.gc_max_pause, pause)
+        if pause > self.gc_freeze_pause and self._loop is not None:
+            try:
+                # not from inside the collector: on the loop, next turn
+                self._loop.call_soon_threadsafe(self._freeze, pause)
+            except RuntimeError:
+                pass  # loop closed under us (shutdown)
+
+    def _freeze(self, pause: float) -> None:
+        gc.freeze()
+        # an (empty, instant) full pass resets the collector's count of
+        # long-lived objects: left at its pre-freeze value, the next
+        # full pass waits for a quarter of THAT to pile up first
+        gc.collect()
+        self.gc_freezes += 1
+        self.broker.metrics.incr("sysmon_long_gc")
+        log.info("full GC paused every thread %.3fs (over %.3fs): froze "
+                 "%d surviving objects out of later passes",
+                 pause, self.gc_freeze_pause, gc.get_freeze_count())
 
     @property
     def overloaded(self) -> bool:
@@ -134,6 +184,8 @@ class Sysmon:
             "lag_events": self.lag_events,
             "overload_extends": self.overload_extends,
             "gc_forced": self.gc_forced,
+            "gc_freezes": self.gc_freezes,
+            "gc_max_pause_s": round(self.gc_max_pause, 4),
             "overloaded": self.overloaded,
             "rss_bytes": rss_bytes(),
         }

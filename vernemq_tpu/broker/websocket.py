@@ -13,6 +13,7 @@ import logging
 import struct
 from typing import Optional, Tuple
 
+from ..utils.aio import close_server
 from .session import Transport
 
 log = logging.getLogger("vernemq_tpu.websocket")
@@ -257,6 +258,7 @@ class WebSocketServer:
         self.connection_count = 0
         self.reuse_port = reuse_port
         self._server: Optional[asyncio.AbstractServer] = None
+        self._writers: set = set()  # live accepted connections
 
     async def start(self) -> None:
         self._server = await asyncio.start_server(
@@ -267,9 +269,7 @@ class WebSocketServer:
         self.broker._servers.append(self._server)
 
     async def stop(self) -> None:
-        if self._server is not None:
-            self._server.close()
-            await self._server.wait_closed()
+        await close_server(self._server, self._writers)
 
     async def _handle(self, reader: asyncio.StreamReader,
                       writer: asyncio.StreamWriter) -> None:
@@ -280,9 +280,11 @@ class WebSocketServer:
             writer.close()
             return
         self.connection_count += 1
+        self._writers.add(writer)
         try:
             await self._handle_inner(reader, writer)
         finally:
+            self._writers.discard(writer)
             self.connection_count -= 1
 
     async def _handle_inner(self, reader: asyncio.StreamReader,

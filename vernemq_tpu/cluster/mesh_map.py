@@ -158,9 +158,7 @@ class MeshSliceMap:
 
     def release_local(self) -> List[int]:
         """Retract every slice this node currently claims (tombstones
-        gossip like any other write). The registry calls this when the
-        tpu view comes up WITHOUT its mesh (tpu_mesh unsatisfiable —
-        the loud single-chip degrade): a node must not keep advertising
+        gossip like any other write): a node must not keep advertising
         slices it cannot serve."""
         released = []
         for s in range(self.n_slices):

@@ -126,13 +126,17 @@ class SubscriptionTrie:
 
     def entries(self) -> Iterator[Tuple[Tuple[str, ...], Hashable, Any]]:
         """Iterate every (filter, key, value) — used for warm-loading the TPU
-        table, mirroring the trie warm-load fold (vmq_reg_trie.erl:144-151)."""
+        table, mirroring the trie warm-load fold (vmq_reg_trie.erl:144-151).
+        Each node's dicts are copied as it is visited, so a consumer may
+        suspend the walk while the trie changes (the background table
+        load does, between chunks): it then sees each node as it was when
+        reached, never a dict that changed size under it."""
         stack: List[Tuple[_Node, Tuple[str, ...]]] = [(self._root, ())]
         while stack:
             node, path = stack.pop()
-            for k, v in node.subs.items():
+            for k, v in list(node.subs.items()):
                 yield (path, k, v)
-            for w, child in node.children.items():
+            for w, child in list(node.children.items()):
                 stack.append((child, path + (w,)))
 
     def stats(self) -> Dict[str, int]:

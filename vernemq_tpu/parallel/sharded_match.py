@@ -19,24 +19,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.5: top-level export, replication check named check_vma
-    from jax import shard_map as _shard_map
-    _SM_CHECK_KW = "check_vma"
-except ImportError:  # jax 0.4.x: experimental module, check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SM_CHECK_KW = "check_rep"
-
-
-def shard_map(f, **kw):
-    """Version-tolerant shard_map: maps the ``check_vma`` kwarg to this
-    jax build's name for it (``check_rep`` before 0.5) so the kernels
-    compile on both the image's 0.4.x and newer runtimes."""
-    if "check_vma" in kw and _SM_CHECK_KW != "check_vma":
-        kw[_SM_CHECK_KW] = kw.pop("check_vma")
-    return _shard_map(f, **kw)
 
 from ..ops.match_kernel import extract_indices, match_mask_unrolled
 
