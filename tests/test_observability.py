@@ -1086,3 +1086,373 @@ async def test_merged_events_fold_worker_slots_and_dump_merge(tmp_path):
     finally:
         stats.close()
         stats.unlink()
+
+
+# ------------------------------------------------------------------- spans
+# (ISSUE 25: one name for the histogram family and the profiler's
+# annotation, and the publish's journey under such spans)
+
+
+class _FakeAnnotation:
+    """Stands where ``jax.profiler.TraceAnnotation`` stands."""
+
+    log = []
+    session = True  # a profiler session is running
+
+    @classmethod
+    def is_enabled(cls):
+        return cls.session
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        _FakeAnnotation.log.append(("enter", self.name))
+
+    def __exit__(self, *exc):
+        _FakeAnnotation.log.append(("exit", self.name))
+
+
+@pytest.fixture
+def fake_annotation(monkeypatch):
+    _FakeAnnotation.log = []
+    monkeypatch.setattr(_FakeAnnotation, "session", True)
+    monkeypatch.setattr(hist, "_ANNOTATION", _FakeAnnotation)
+    monkeypatch.setattr(hist, "_TRACING", _FakeAnnotation.is_enabled)
+    return _FakeAnnotation.log
+
+
+def _count(family):
+    return hist.get(family).snapshot()[2]
+
+
+def _sum(family):
+    return hist.get(family).snapshot()[1]
+
+
+@pytest.mark.parametrize("form", ["with", "begin_end"])
+def test_span_observes_once_under_one_name(fake_annotation, form):
+    if form == "with":
+        with hist.span("stage_route_ms"):
+            time.sleep(0.002)
+    else:
+        sp = hist.span("stage_route_ms").begin()
+        time.sleep(0.002)
+        assert sp.end() >= 2.0
+    assert _count("stage_route_ms") == 1
+    assert 2.0 <= _sum("stage_route_ms") < 500.0
+    assert fake_annotation == [("enter", "stage_route_ms"),
+                               ("exit", "stage_route_ms")]
+
+
+def test_span_makes_no_annotation_while_no_profiler_session_runs(
+        fake_annotation, monkeypatch):
+    monkeypatch.setattr(_FakeAnnotation, "session", False)
+    with hist.span("stage_route_ms"):
+        pass
+    assert _count("stage_route_ms") == 1 and fake_annotation == []
+
+
+def test_span_begin_end_is_the_start_time_alone_outside_a_session(
+        fake_annotation, monkeypatch):
+    """The per-publish form: no object while no profiler session runs, a
+    span like any other while one does, nothing when observability is
+    off, and one observation either way."""
+    monkeypatch.setattr(_FakeAnnotation, "session", False)
+    tok = hist.span_begin("stage_ack_in_ms")
+    assert isinstance(tok, float)
+    hist.span_end("stage_ack_in_ms", tok)
+    assert _count("stage_ack_in_ms") == 1 and fake_annotation == []
+    monkeypatch.setattr(_FakeAnnotation, "session", True)
+    tok = hist.span_begin("stage_ack_in_ms")
+    assert isinstance(tok, hist.Span)
+    try:
+        raise ValueError("the section failed")
+    except ValueError:
+        pass
+    finally:
+        hist.span_end("stage_ack_in_ms", tok)
+    assert _count("stage_ack_in_ms") == 2
+    assert fake_annotation == [("enter", "stage_ack_in_ms"),
+                               ("exit", "stage_ack_in_ms")]
+    hist.set_enabled(False)
+    tok = hist.span_begin("stage_ack_in_ms")
+    assert tok is None
+    hist.span_end("stage_ack_in_ms", tok)
+    hist.set_enabled(True)
+    assert _count("stage_ack_in_ms") == 2
+    with pytest.raises(KeyError):
+        hist.span_end("stage_no_such_family_ms", 1.0)
+
+
+def test_span_end_without_record_leaves_the_family_alone(fake_annotation):
+    sp = hist.span("stage_fold_wait_ms").begin()
+    assert sp.end(record=False) >= 0.0
+    assert _count("stage_fold_wait_ms") == 0
+    assert fake_annotation[-1] == ("exit", "stage_fold_wait_ms")
+
+
+def test_span_disabled_is_one_shared_noop(fake_annotation):
+    hist.set_enabled(False)
+    sp = hist.span("stage_route_ms")
+    assert sp is hist.span("stage_ack_in_ms")  # nothing is allocated
+    with sp:
+        pass
+    assert sp.begin().end() == 0.0
+    hist.set_enabled(True)
+    assert _count("stage_route_ms") == 0 and fake_annotation == []
+
+
+def test_span_survives_an_exception_in_the_body(fake_annotation):
+    with pytest.raises(ValueError):
+        with hist.span("stage_route_ms"):
+            raise ValueError("routing failed")
+    # the section ended: observed once, the annotation closed
+    assert _count("stage_route_ms") == 1
+    assert fake_annotation[-1] == ("exit", "stage_route_ms")
+    with hist.span("stage_route_ms"):
+        pass
+    assert _count("stage_route_ms") == 2
+
+
+def test_span_names_a_registered_family_or_raises():
+    with pytest.raises(KeyError):
+        hist.span("stage_no_such_family_ms")
+
+
+def test_span_imports_no_jax_in_a_process_without_it():
+    """Workers and load generators observe without JAX: the span is the
+    histogram alone there and JAX stays unimported."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from vernemq_tpu.observability import histogram as h\n"
+        "with h.span('stage_route_ms'):\n"
+        "    pass\n"
+        "assert 'jax' not in sys.modules, 'span imported jax'\n"
+        "assert h._ANNOTATION is None\n"
+        "print(h.get('stage_route_ms').snapshot()[2])\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    p = subprocess.run([sys.executable, "-c", code], cwd=root,
+                       capture_output=True, text=True, timeout=60)
+    assert p.returncode == 0, p.stderr[-2000:]
+    assert p.stdout.strip() == "1"
+
+
+def test_span_uses_jax_own_annotation_once_jax_is_loaded():
+    import jax
+
+    hist._ANNOTATION, hist._TRACING = None, hist._find_session
+    with hist.span("stage_route_ms"):
+        pass
+    assert hist._ANNOTATION is jax.profiler.TraceAnnotation
+    assert hist._TRACING == jax.profiler.TraceAnnotation.is_enabled
+    assert hist._TRACING() is False  # no profiler session here
+    assert _count("stage_route_ms") == 1
+
+
+FOLD_FAMILIES = ("stage_fold_prep_ms", "stage_fold_launch_ms",
+                 "stage_fold_wait_ms", "stage_fold_resolve_ms")
+FOLD_FIELDS = ("prep_ms", "launch_ms", "wait_ms", "resolve_ms",
+               "lock_wait_ms")
+
+
+@pytest.fixture(scope="module")
+def fold_matchers():
+    """A bucketed table (the windowed kernels) and a flat one (the
+    full-scan kernel), each with its shapes compiled."""
+    from vernemq_tpu.models.tpu_matcher import TpuMatcher
+
+    out = {}
+    for name, cap in (("windowed", 8192), ("flat", 1024)):
+        m = TpuMatcher(max_levels=8, initial_capacity=cap)
+        for i in range(300):
+            m.table.add(("bench", str(i)), i, None)
+        assert m.table.bucketed == (name == "windowed")
+        m.match_batch(_fold_topics(20), _warmup=True)
+        out[name] = m
+    out["windowed"].match_many([_fold_topics(20)] * 2, _warmup=True)
+    return out
+
+
+def _fold_topics(n):
+    return [("bench", str(i)) for i in range(n)]
+
+
+@pytest.mark.parametrize("call,table", [("batch", "windowed"),
+                                        ("many", "windowed"),
+                                        ("batch", "flat")])
+def test_fold_puts_one_observation_in_each_of_its_four_phases(
+        fold_matchers, fake_annotation, call, table):
+    m = fold_matchers[table]
+    t0 = time.monotonic()
+    if call == "batch":
+        rows = m.match_batch(_fold_topics(20))
+        assert [len(r) for r in rows] == [1] * 20
+    else:
+        rows = m.match_many([_fold_topics(20)] * 2)
+        assert [len(r) for b in rows for r in b] == [1] * 40
+    wall_ms = (time.monotonic() - t0) * 1e3
+    assert [_count(f) for f in FOLD_FAMILIES] == [1, 1, 1, 1]
+    assert _count("stage_device_dispatch_ms") == 1
+    total = sum(_sum(f) for f in FOLD_FAMILIES)
+    assert 0.0 < total <= wall_ms
+    # the dispatch span of old starts after the encode and ends before
+    # the resolve: it lies inside the four
+    assert _sum("stage_device_dispatch_ms") <= total
+    rec = profiler().snapshot("match")[-1]
+    for field in FOLD_FIELDS:
+        assert rec[field] >= 0.0, field
+    assert sum(rec[f] for f in FOLD_FIELDS[:4]) == pytest.approx(
+        total, abs=0.01)
+    assert rec["lock_wait_ms"] <= rec["prep_ms"]
+    # each phase is a span of its own in the profiler's trace, in order
+    entered = [n for what, n in fake_annotation if what == "enter"]
+    assert entered == list(FOLD_FAMILIES)
+    assert len(fake_annotation) == 8
+
+
+@pytest.mark.parametrize("call", ["batch", "many"])
+def test_a_warmup_fold_observes_nothing(fold_matchers, call):
+    m = fold_matchers["windowed"]
+    if call == "batch":
+        m.match_batch(_fold_topics(20), _warmup=True)
+    else:
+        m.match_many([_fold_topics(20)] * 2, _warmup=True)
+    assert [_count(f) for f in FOLD_FAMILIES] == [0, 0, 0, 0]
+    assert _count("stage_device_dispatch_ms") == 0
+    assert profiler().snapshot("match") == []
+
+
+def test_a_fold_with_observability_off_observes_nothing(fold_matchers):
+    hist.set_enabled(False)
+    rows = fold_matchers["windowed"].match_batch(_fold_topics(20))
+    hist.set_enabled(True)
+    assert [len(r) for r in rows] == [1] * 20
+    assert [_count(f) for f in FOLD_FAMILIES] == [0, 0, 0, 0]
+    assert profiler().snapshot("match") == []
+
+
+async def _apoll(cond, timeout=5.0, interval=0.02):
+    """``_poll`` for a condition the running loop itself brings about."""
+    deadline = time.monotonic() + timeout
+    while not cond() and time.monotonic() < deadline:
+        await asyncio.sleep(interval)
+    return cond()
+
+
+class _InstantView:
+    registry = None
+
+    def matcher(self, mp):
+        return None
+
+    def fold_batch(self, mp, topics, lock_timeout=None):
+        return [[("row", t)] for t in topics]
+
+
+@pytest.mark.asyncio
+async def test_release_wait_is_observed_once_a_chunk_of_64():
+    from vernemq_tpu.models.tpu_matcher import BatchCollector
+
+    col = BatchCollector(_InstantView(), window_us=200, max_batch=4096,
+                         host_threshold=0)
+    released = []
+    futs = [col.submit("", ("t", str(i))) for i in range(200)]
+    for i, f in enumerate(futs):
+        f.add_done_callback(lambda _f, i=i: released.append(i))
+    await asyncio.gather(*futs)
+    assert released == list(range(200))  # submission order, as before
+    # 200 futures of one flush leave in chunks of 64, 64, 64 and 8
+    assert _count("stage_release_wait_ms") == 4
+    assert _sum("stage_release_wait_ms") >= 0.0
+    hist.reset_all()
+    hist.set_enabled(False)
+    await asyncio.gather(*[col.submit("", ("t", str(i)))
+                           for i in range(200)])
+    hist.set_enabled(True)
+    assert _count("stage_release_wait_ms") == 0
+
+
+def test_recorder_separates_the_release_queue_from_routing():
+    """A sampled publish's record: ``release_wait_ms`` is the settled
+    future's wait for its turn, ``route_ms`` what came after it; and
+    the publish's admission (start to collector submit) is observed."""
+    rec = FlightRecorder(sample_n=1)
+    tr = rec.admit("c", "a/b", 1)
+    for label, pause in (("admit", 0.002), ("submit", 0.002),
+                         ("dequeue", 0.0), ("match", 0.0),
+                         ("settle", 0.0), ("release", 0.03),
+                         ("route", 0.004)):
+        time.sleep(pause)
+        tr.stamp(label)
+    st = rec.finish(tr)["stages"]
+    assert set(st) >= {"settle_ms", "release_wait_ms", "route_ms"}
+    assert st["release_wait_ms"] >= 30.0
+    assert 4.0 <= st["route_ms"] < 30.0  # the wait is not in it
+    assert _count("stage_pub_admit_ms") == 1
+    assert 4.0 <= _sum("stage_pub_admit_ms") < 30.0
+    # a publish that never reached the collector has no admission span
+    tr2 = rec.admit("c", "a/b", 0)
+    tr2.stamp("admit")
+    tr2.stamp("route")
+    rec.finish(tr2)
+    assert _count("stage_pub_admit_ms") == 1
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("fastpath_on", [True, False])
+async def test_broker_qos1_journey_is_under_spans(fastpath_on):
+    """QoS 1 through the batched view on the CPU: one route span a
+    publish routed, one ack span a PUBACK received (wire plane and
+    classic handler alike), sampled records that carry the release
+    queue apart from routing, and the loop's CPU seconds rising."""
+    from vernemq_tpu.broker.config import Config
+    from vernemq_tpu.broker.server import start_broker
+    from vernemq_tpu.client import MQTTClient
+
+    cfg = Config(systree_enabled=False, allow_anonymous=True,
+                 default_reg_view="tpu", flight_recorder_sample_n=2,
+                 wire_fastpath_enabled=fastpath_on)
+    broker, server = await start_broker(cfg, port=0)
+    broker.sysmon.interval = 0.05  # from its second tick on
+    try:
+        sub = MQTTClient("127.0.0.1", server.port, client_id="span-sub")
+        pub = MQTTClient("127.0.0.1", server.port, client_id="span-pub")
+        assert (await sub.connect()).rc == 0
+        assert (await pub.connect()).rc == 0
+        await sub.subscribe("a/b", qos=1)
+        hist.reset_all()
+        n_pub = 24
+        for _ in range(n_pub):
+            await pub.publish("a/b", b"p", qos=1)
+        for _ in range(n_pub):
+            assert (await sub.recv(timeout=10.0)).topic == "a/b"
+        m = broker.metrics
+        assert await _apoll(
+            lambda: m.value("mqtt_puback_received") >= n_pub)
+        assert _count("stage_route_ms") == n_pub
+        assert _count("stage_ack_in_ms") == m.value("mqtt_puback_received")
+        assert await _apoll(lambda: broker.recorder.finished
+                            == broker.recorder.sampled)
+        recs = broker.recorder.snapshot()
+        assert len(recs) == n_pub // 2
+        for r in recs:
+            assert {"settle_ms", "release_wait_ms",
+                    "route_ms"} <= set(r["stages"])
+        assert _count("stage_pub_admit_ms") == len(recs)
+        assert await _apoll(lambda: broker.sysmon.loop_cpu_s > 0)
+        c0 = broker.sysmon.loop_cpu_s
+        t_end = time.monotonic() + 0.2
+        while time.monotonic() < t_end:
+            pass  # the loop's thread on the CPU
+        await asyncio.sleep(0.2)
+        assert broker.sysmon.loop_cpu_s >= c0 + 0.15
+        assert broker._gauges()["loop_cpu_s"] >= c0 + 0.15
+        await pub.disconnect()
+        await sub.disconnect()
+    finally:
+        await broker.stop()
+        await server.stop()

@@ -120,14 +120,20 @@ def _total_bytes(compiled) -> int:
             + ma.temp_size_in_bytes + ma.generated_code_size_in_bytes)
 
 
+_COMPILED = {}  # a program is compiled once for the tests that read it
+
+
 def _compile_packed(m, one_chip, n):
     from vernemq_tpu.ops import match_kernel as K
 
-    args, statics = _prep(m, n)
-    packed = K.flat_pack_args(args)
-    return K.match_extract_windowed_flat_packed.lower(
-        *_table_sds(m, one_chip), _sds(packed, one_chip),
-        **K._packed_geometry(args), **statics).compile()
+    if ("packed", n) not in _COMPILED:
+        args, statics = _prep(m, n)
+        packed = K.flat_pack_args(args)
+        _COMPILED["packed", n] = \
+            K.match_extract_windowed_flat_packed.lower(
+                *_table_sds(m, one_chip), _sds(packed, one_chip),
+                **K._packed_geometry(args), **statics).compile()
+    return _COMPILED["packed", n]
 
 
 @pytest.mark.parametrize("n", [4096, 9], ids=["B4096", "Bmin"])
@@ -158,22 +164,46 @@ def test_match_many_compiles(matcher, one_chip):
     assert _total_bytes(compiled) < HBM_BYTES
 
 
+def _compile_delta(m, one_chip):
+    from vernemq_tpu.ops import match_kernel as K
+
+    if "delta" not in _COMPILED:
+        D = 128
+        L = m.table.words.shape[1]
+        z = np.zeros(D, np.int32)
+        zb = np.zeros(D, bool)
+        packed = K.delta_pack_args(z, np.zeros((D, L), np.int32), z,
+                                   zb, zb, zb)
+        _COMPILED["delta"] = K.apply_delta_fused.lower(
+            *(_sds(a, one_chip) for a in m._dev_arrays),
+            *_table_sds(m, one_chip), _sds(packed, one_chip),
+            D=D, L=L, id_bits=m._ops_bits).compile()
+    return _COMPILED["delta"]
+
+
 def test_delta_scatter_compiles(matcher, one_chip):
     """The SUBSCRIBE/UNSUBSCRIBE write-through (the donating fused
     scatter ``_apply_delta_device_inner`` picks) at the top of the
     pre-warmed ladder, Dpad=128."""
-    from vernemq_tpu.ops import match_kernel as K
+    assert _total_bytes(_compile_delta(matcher, one_chip)) < HBM_BYTES
 
-    m, D = matcher, 128
-    L = m.table.words.shape[1]
-    z = np.zeros(D, np.int32)
-    zb = np.zeros(D, bool)
-    packed = K.delta_pack_args(z, np.zeros((D, L), np.int32), z, zb, zb, zb)
-    compiled = K.apply_delta_fused.lower(
-        *(_sds(a, one_chip) for a in m._dev_arrays),
-        *_table_sds(m, one_chip), _sds(packed, one_chip),
-        D=D, L=L, id_bits=m._ops_bits).compile()
-    assert _total_bytes(compiled) < HBM_BYTES
+
+@pytest.mark.parametrize("program,scopes", [
+    ("packed", ("unpack_transport", "dense_region0", "probe_a", "probe_b",
+                "flat_combine")),
+    ("delta", ("delta_scatter",))])
+def test_device_programs_name_their_phases(matcher, one_chip, program,
+                                           scopes):
+    """``jax.named_scope`` reaches the v5e's compiled program: every phase
+    of the match and the delta scatter is the ``op_name`` of instructions
+    that survived optimisation, which is where a device trace's
+    operations are attributed from (``benchmark/trace/spans.py``)."""
+    compiled = (_compile_packed(matcher, one_chip, 9)
+                if program == "packed" else _compile_delta(matcher,
+                                                           one_chip))
+    text = compiled.as_text()
+    for scope in scopes:
+        assert f"/{scope}/" in text, scope
 
 
 def test_pallas_match_compiles(matcher, one_chip):

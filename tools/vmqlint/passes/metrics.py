@@ -1,4 +1,4 @@
-"""``metrics`` pass: metric-registry HELP + observe() family names.
+"""``metrics`` pass: metric-registry HELP + observe()/span() family names.
 
 Port of the original ``tools/lint_metrics.py`` (PR 8) onto the vmqlint
 framework.  Two invariants, both cheap to break silently and annoying
@@ -8,9 +8,11 @@ to debug at scrape time:
    table (broker/metrics.py), the ``STAGE_FAMILIES`` histogram table
    (observability/histogram.py), and every literal descriptions dict
    passed to ``Metrics.register_gauges``.
-2. Every ``observe("name", ...)`` call site names a REGISTERED
-   histogram family — a typo'd family raises KeyError on the hot path,
-   in production, at the first sampled publish, instead of here.
+2. Every ``observe("name", ...)`` and ``span("name")`` (also
+   ``span_begin`` / ``span_end``) call site names
+   a REGISTERED histogram family — a typo'd family raises KeyError on
+   the hot path, in production, at the first sampled publish, instead
+   of here.
 
 Suppress a delegation seam (Metrics.observe -> histogram.observe
 forwards a dynamic name by design) with the vmqlint allow marker
@@ -106,33 +108,29 @@ def _check_observe_sites(tree: ast.AST, rel: str, families: Set[str],
         if not isinstance(node, ast.Call) or not node.args:
             continue
         fn = node.func
-        if isinstance(fn, ast.Attribute):
-            # exact-name match: observe_lag and other observe-ish
-            # methods fall out here without needing an exempt list
-            if fn.attr != "observe":
-                continue
-        elif isinstance(fn, ast.Name):
-            if fn.id != "observe":
-                continue
-        else:
+        # exact-name match: observe_lag and other observe-ish methods
+        # fall out here without needing an exempt list
+        what = (fn.attr if isinstance(fn, ast.Attribute)
+                else fn.id if isinstance(fn, ast.Name) else None)
+        if what not in ("observe", "span", "span_begin", "span_end"):
             continue
         fam = _const_str(node.args[0])
         if fam is None:
             errors.append(Finding(
                 PASS.name, rel, node.lineno,
-                "observe() family is not a string literal (cannot "
+                f"{what}() family is not a string literal (cannot "
                 "verify registration statically)"))
         elif fam not in families:
             errors.append(Finding(
                 PASS.name, rel, node.lineno,
-                f"observe() names unregistered histogram family "
+                f"{what}() names unregistered histogram family "
                 f"'{fam}'"))
 
 
 class MetricsPass(Pass):
     name = "metrics"
     describe = ("every counter/gauge/histogram has HELP text; every "
-                "observe() names a registered family")
+                "observe()/span() names a registered family")
     defect = ("an empty HELP ships a broken exposition line; a typo'd "
               "family KeyErrors on the hot path under load")
     tree_scoped = True  # the family registry lives in two fixed files

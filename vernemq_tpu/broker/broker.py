@@ -339,6 +339,10 @@ class Broker:
                                        "(hysteresis extends).",
             "sysmon_last_loop_lag_seconds": "Most recent event-loop "
                                             "lag sample.",
+            "loop_cpu_s": "CPU seconds the event loop's thread has "
+                          "consumed (time.thread_time(), sampled on "
+                          "sysmon's tick); its rate is the share of a "
+                          "second the loop is busy.",
             "tpu_breaker_state": "Device circuit breaker state "
                                  "(0 closed, 1 half-open, 2 open; worst "
                                  "across mountpoints).",
@@ -774,6 +778,7 @@ class Broker:
             st = self.sysmon
             out["sysmon_overload_extends"] = float(st.overload_extends)
             out["sysmon_last_loop_lag_seconds"] = round(st.last_lag, 4)
+            out["loop_cpu_s"] = round(st.loop_cpu_s, 4)
         spool = getattr(self.cluster, "spool", None)
         if spool is not None:
             out.update(spool.stats())

@@ -32,6 +32,7 @@ import time
 from typing import TYPE_CHECKING, Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 from ..models.trie import SubscriptionTrie
+from ..observability import histogram as obs
 from ..protocol import fastpath
 from ..protocol.topic import is_shared, unshare
 from ..protocol.types import PROTO_5, SubOpts
@@ -83,6 +84,16 @@ class TrieRegView:
         """Yield match rows: (filter, key, subopts). Keys are SubscriberId
         for plain subs or ("$g", group, SubscriberId) for shared subs."""
         return self._registry.trie(mountpoint).match(topic)
+
+
+def _route_begin(trace):
+    """The routing of one publish whose rows the collector just released
+    begins: the span it is timed under (close with ``obs.span_end``), and
+    the flight recorder's ``release`` stamp of a sampled one (what came
+    before was the release queue)."""
+    if trace is not None:
+        trace.stamp("release")
+    return obs.span_begin("stage_route_ms")
 
 
 class Registry:
@@ -669,7 +680,11 @@ class Registry:
         msg = self._pre_publish(msg)
         rows = await self.broker.batch_collector().submit(
             msg.mountpoint, msg.topic, trace, feat=self._filters_feat(msg))
-        return self.route_rows(msg, rows, from_sid, trace=trace)
+        tok = _route_begin(trace)
+        try:
+            return self.route_rows(msg, rows, from_sid, trace=trace)
+        finally:
+            obs.span_end("stage_route_ms", tok)
 
     def publish_nowait(self, msg: Msg,
                        from_sid: Optional[SubscriberId] = None,
@@ -691,7 +706,11 @@ class Registry:
             if exc is not None:
                 self.broker.metrics.incr("mqtt_publish_error")
                 return
-            self.route_rows(msg, f.result(), from_sid, trace=trace)
+            tok = _route_begin(trace)
+            try:
+                self.route_rows(msg, f.result(), from_sid, trace=trace)
+            finally:
+                obs.span_end("stage_route_ms", tok)
             if trace is not None:
                 trace.stamp("route")
                 self.broker.recorder.finish(trace)
@@ -730,9 +749,13 @@ class Registry:
                 if exc is not None:
                     self.broker.metrics.incr("mqtt_publish_error")
                     return
-                self._wire_route(mountpoint, words, topic_str, payload,
-                                 f.result(), from_sid, wire_frame,
-                                 payload_skip)
+                tok = _route_begin(trace)
+                try:
+                    self._wire_route(mountpoint, words, topic_str, payload,
+                                     f.result(), from_sid, wire_frame,
+                                     payload_skip)
+                finally:
+                    obs.span_end("stage_route_ms", tok)
                 if trace is not None:
                     trace.stamp("route")
                     self.broker.recorder.finish(trace)

@@ -12,9 +12,9 @@ from __future__ import annotations
 
 import asyncio
 import logging
-import time
 from typing import Optional, Tuple
 
+from ..observability import histogram as obs
 from ..protocol import codec_v4, codec_v5, fastpath, wire
 from ..protocol.types import (
     PROTO_5,
@@ -243,11 +243,12 @@ async def mqtt_connection(
         unpack_rec = fastpath.REC.unpack_from
         while not session.closed:
             if buf:
-                t0 = time.monotonic()
-                table, nrec, consumed = fastpath.parse_batch(
-                    buf, max_frame_size, v5)
-                metrics.observe("stage_wire_parse_ms",
-                                (time.monotonic() - t0) * 1e3)
+                tok = obs.span_begin("stage_wire_parse_ms")
+                try:
+                    table, nrec, consumed = fastpath.parse_batch(
+                        buf, max_frame_size, v5)
+                finally:
+                    obs.span_end("stage_wire_parse_ms", tok)
                 fast_gate = nrec > 0 and session.wire_fast_ready()
                 fast_pubs = 0
                 fast_qpubs = 0

@@ -32,6 +32,7 @@ from typing import TYPE_CHECKING, Any, Dict, List, Optional, Tuple
 log = logging.getLogger("vernemq_tpu.session")
 
 from ..filters.predicate import FilterError, parse_filter, split_filter_suffix
+from ..observability import histogram as obs
 from ..protocol import codec_v4, codec_v5, fastpath
 from ..protocol import topic as T
 from ..protocol.types import (
@@ -931,12 +932,7 @@ class Session:
         self.last_activity = time.monotonic()
         if ptype == PUBACK_T:
             m.incr("mqtt_puback_received")
-            entry = self.waiting_acks.get(pid)
-            if entry and entry[0] == "puback":
-                del self.waiting_acks[pid]
-                self._pump_pending()
-            else:  # ack for nothing we sent (vmq_metrics *_invalid_error)
-                m.incr("mqtt_puback_invalid_error")
+            self._ack_in(pid, "puback", "mqtt_puback_invalid_error")
         elif ptype == PUBREC_T:
             m.incr("mqtt_pubrec_received")
             entry = self.waiting_acks.get(pid)
@@ -959,12 +955,7 @@ class Session:
             m.incr("mqtt_pubcomp_sent")
         else:  # PUBCOMP
             m.incr("mqtt_pubcomp_received")
-            entry = self.waiting_acks.get(pid)
-            if entry and entry[0] == "pubcomp":
-                del self.waiting_acks[pid]
-                self._pump_pending()
-            else:
-                m.incr("mqtt_pubcomp_invalid_error")
+            self._ack_in(pid, "pubcomp", "mqtt_pubcomp_invalid_error")
         fastpath.fastpath_acks += 1
 
     def wire_take_qos(self, msg: Msg) -> Optional[int]:
@@ -1286,13 +1277,23 @@ class Session:
         # capacity freed: a rate-throttled reader may re-check its budget
         self._throttle_wake.set()
 
+    def _ack_in(self, pid: int, awaited: str, invalid: str) -> None:
+        """The last ack of a delivery (PUBACK of QoS 1, PUBCOMP of QoS 2),
+        from the wire plane or the classic handler: free the in-flight
+        slot and pump what waited for it."""
+        tok = obs.span_begin("stage_ack_in_ms")
+        try:
+            entry = self.waiting_acks.get(pid)
+            if entry and entry[0] == awaited:
+                del self.waiting_acks[pid]
+                self._pump_pending()
+            else:  # ack for nothing we sent (vmq_metrics *_invalid_error)
+                self.broker.metrics.incr(invalid)
+        finally:
+            obs.span_end("stage_ack_in_ms", tok)
+
     def _handle_puback(self, f: Puback) -> None:
-        entry = self.waiting_acks.get(f.packet_id)
-        if entry and entry[0] == "puback":
-            del self.waiting_acks[f.packet_id]
-            self._pump_pending()
-        else:  # ack for nothing we sent (vmq_metrics *_invalid_error)
-            self.broker.metrics.incr("mqtt_puback_invalid_error")
+        self._ack_in(f.packet_id, "puback", "mqtt_puback_invalid_error")
 
     def _handle_pubrec(self, f: Pubrec) -> None:
         entry = self.waiting_acks.get(f.packet_id)
@@ -1311,12 +1312,7 @@ class Session:
             self.broker.metrics.incr("mqtt_pubrec_invalid_error")
 
     def _handle_pubcomp(self, f: Pubcomp) -> None:
-        entry = self.waiting_acks.get(f.packet_id)
-        if entry and entry[0] == "pubcomp":
-            del self.waiting_acks[f.packet_id]
-            self._pump_pending()
-        else:
-            self.broker.metrics.incr("mqtt_pubcomp_invalid_error")
+        self._ack_in(f.packet_id, "pubcomp", "mqtt_pubcomp_invalid_error")
 
     # ----------------------------------------------------------- SUBSCRIBE
 

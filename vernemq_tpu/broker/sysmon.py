@@ -10,6 +10,10 @@ asyncio equivalents:
   ``overloaded`` flag, which the session layer turns into read throttling
   (the load-shedding role of the reference's throttle return,
   ``vmq_ranch.erl:198-203``).
+- **loop CPU**: on the same tick, ``time.thread_time()`` of the loop's
+  thread (``loop_cpu_s``): its rate is the share of a second the loop is
+  on the CPU, the number that says how host-bound a broker is. Lag says
+  how late the loop ran one timer; this says how full it is.
 - **long GC**: a full collection of Python's cyclic collector walks every
   tracked object with every thread stopped. A broker holds millions of
   long-lived ones (a trie node, a table row and a SubOpts per
@@ -80,6 +84,7 @@ class Sysmon:
         self._gc_t0 = 0.0
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self.last_lag = 0.0
+        self.loop_cpu_s = 0.0  # thread CPU seconds of the loop, per tick
         self.overloaded_until = 0.0
         self._task: Optional[asyncio.Task] = None
 
@@ -154,6 +159,7 @@ class Sysmon:
             t0 = time.monotonic()
             await asyncio.sleep(self.interval)
             lag = time.monotonic() - t0 - self.interval
+            self.loop_cpu_s = time.thread_time()  # this IS the loop's thread
             self.observe_lag(lag)
             gov = getattr(self.broker, "overload", None)
             if gov is not None:
@@ -181,6 +187,7 @@ class Sysmon:
     def status(self) -> Dict[str, Any]:
         return {
             "last_loop_lag_s": round(self.last_lag, 4),
+            "loop_cpu_s": round(self.loop_cpu_s, 4),
             "lag_events": self.lag_events,
             "overload_extends": self.overload_extends,
             "gc_forced": self.gc_forced,

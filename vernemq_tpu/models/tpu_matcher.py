@@ -259,6 +259,54 @@ class RebuildInProgress(Exception):
     there)."""
 
 
+class _FoldPhases:
+    """The host phases of one fold, each a span under its family's name
+    in the profiler's trace. Their milliseconds are observed together at
+    the fold's end and only for a dispatch that counts
+    (``stage_device_dispatch_ms``'s rule: no warm-up, no abandoned
+    straggler, no failure)."""
+
+    __slots__ = ("t_in", "lock_wait_ms", "ms", "_open")
+
+    def __init__(self) -> None:
+        self.t_in = time.monotonic()
+        self.lock_wait_ms = 0.0
+        self.ms: Dict[str, float] = {}
+        self._open = None
+
+    def enter(self, span) -> None:
+        """Close the phase that is open and open ``span``."""
+        self.close()
+        self._open = span.begin()
+
+    def close(self) -> None:
+        sp, self._open = self._open, None
+        if sp is not None:
+            self.ms[sp.family] = sp.end(record=False)
+
+    def locked(self) -> None:
+        self.lock_wait_ms = (time.monotonic() - self.t_in) * 1e3
+
+    def record(self, t_disp: float, dur: float, **fields: Any) -> None:
+        """One observation a family and the dispatch ring's record
+        (``dur``: what ``stage_device_dispatch_ms`` observed)."""
+        self.close()
+        ms = self.ms.get
+        prep = ms("stage_fold_prep_ms", 0.0)
+        launch = ms("stage_fold_launch_ms", 0.0)
+        wait = ms("stage_fold_wait_ms", 0.0)
+        resolve = ms("stage_fold_resolve_ms", 0.0)
+        obs.observe("stage_fold_prep_ms", prep)
+        obs.observe("stage_fold_launch_ms", launch)
+        obs.observe("stage_fold_wait_ms", wait)
+        obs.observe("stage_fold_resolve_ms", resolve)
+        record_dispatch(
+            "match", t_disp, dur, prep_ms=round(prep, 4),
+            launch_ms=round(launch, 4), wait_ms=round(wait, 4),
+            resolve_ms=round(resolve, 4),
+            lock_wait_ms=round(self.lock_wait_ms, 4), **fields)
+
+
 class TpuMatcher:
     def __init__(self, max_levels: int = 16, initial_capacity: int = 1024,
                  max_fanout: int = 256, device=None, flat_avg: int = 128,
@@ -1022,11 +1070,22 @@ class TpuMatcher:
 
     def _match_batch_impl(self, topics, _warmup, lock_timeout,
                           require_warm) -> List[List[Row]]:
+        ph = _FoldPhases()
+        ph.enter(obs.span("stage_fold_prep_ms"))
+        try:
+            return self._match_batch_phased(topics, _warmup, lock_timeout,
+                                            require_warm, ph)
+        finally:
+            ph.close()
+
+    def _match_batch_phased(self, topics, _warmup, lock_timeout,
+                            require_warm, ph) -> List[List[Row]]:
         if lock_timeout is None:
             self.lock.acquire()
         elif not self.lock.acquire(timeout=lock_timeout):
             self.busy_sheds += 1
             raise MatcherBusy(cold=False)
+        ph.locked()
         try:
             try:
                 self.sync()
@@ -1059,11 +1118,12 @@ class TpuMatcher:
             self._last_shape = ("batch", len(topics))
         t_disp = time.monotonic()
         warm_before = len(self._warm_sigs)
+        dur = None  # set for a dispatch that counts
         try:
             if bucketed:
                 idx_rows, need_host = self._match_windowed(
                     dev_arrays, operands, meta, reg_start, reg_end,
-                    glob_pad, bits, pw, pl, pd, pb, gb, len(topics),
+                    glob_pad, bits, pw, pl, pd, pb, gb, len(topics), ph,
                     require_warm=require_warm)
             else:
                 chunk = 1024 if pw.shape[0] > 1024 else 0  # lax.map serialises
@@ -1080,14 +1140,17 @@ class TpuMatcher:
                 if require_warm and sig not in self._warm_sigs:
                     self.busy_sheds += 1
                     raise MatcherBusy(cold=True)
+                ph.enter(obs.span("stage_fold_launch_ms"))
                 faults.inject("device.dispatch")
                 matcher = K.match_extract_mxu if fast else K.match_extract
                 idx, valid, count = matcher(
                     *dev_arrays, pw, pl, pd, k=self.max_fanout, chunk=chunk
                 )
+                ph.enter(obs.span("stage_fold_wait_ms"))
                 idx = np.asarray(idx)
                 valid = np.asarray(valid)
                 counts = np.asarray(count)
+                ph.enter(obs.span("stage_fold_resolve_ms"))
                 idx_rows = [idx[i][valid[i]] for i in range(len(topics))]
                 need_host = counts[:len(topics)] > self.max_fanout
                 self._warm_sigs.add(sig)
@@ -1105,17 +1168,19 @@ class TpuMatcher:
             if not _warmup and not watchdog_mod.current_op_abandoned():
                 dur = (time.monotonic() - t_disp) * 1e3
                 obs.observe("stage_device_dispatch_ms", dur)
-                record_dispatch(
-                    "match", t_disp, dur, k=1, batch=len(topics),
-                    bpad=int(pw.shape[0]),
-                    # a dispatch that grew the warm-signature set just
-                    # paid an XLA compile; everything else executed a
-                    # cached executable (compile-vs-execute detection)
-                    compiled=len(self._warm_sigs) > warm_before)
         finally:
             with self.lock:
                 self._inflight -= 1
-        return self._resolve_rows(topics, idx_rows, need_host, snapshot)
+        out = self._resolve_rows(topics, idx_rows, need_host, snapshot)
+        if dur is not None:
+            ph.record(
+                t_disp, dur, k=1, batch=len(topics),
+                bpad=int(pw.shape[0]),
+                # a dispatch that grew the warm-signature set just
+                # paid an XLA compile; everything else executed a
+                # cached executable (compile-vs-execute detection)
+                compiled=len(self._warm_sigs) > warm_before)
+        return out
 
     def _resolve_rows(self, topics, idx_rows, need_host,
                       snapshot) -> List[List[Row]]:
@@ -1174,11 +1239,22 @@ class TpuMatcher:
         batches = [list(b) for b in batches]
         if not batches:
             return []
+        ph = _FoldPhases()
+        ph.enter(obs.span("stage_fold_prep_ms"))
+        try:
+            return self._match_many_phased(batches, _warmup, lock_timeout,
+                                           require_warm, ph)
+        finally:
+            ph.close()
+
+    def _match_many_phased(self, batches, _warmup, lock_timeout,
+                           require_warm, ph) -> List[List[List[Row]]]:
         if lock_timeout is None:
             self.lock.acquire()
         elif not self.lock.acquire(timeout=lock_timeout):
             self.busy_sheds += 1
             raise MatcherBusy(cold=False)
+        ph.locked()
         fast = False
         try:
             try:
@@ -1214,6 +1290,7 @@ class TpuMatcher:
             # impl, not the public wrapper: passage through the breaker
             # gate was already granted (re-entering could eat or be
             # refused the half-open probe this call holds)
+            ph.close()  # each batch is a fold of its own, with its phases
             return [self._match_batch_impl(topics, _warmup, lock_timeout,
                                            require_warm)
                     for topics in batches]
@@ -1228,6 +1305,7 @@ class TpuMatcher:
                                 max(len(b) for b in batches))
         t_disp = time.monotonic()
         warm_before = len(self._warm_sigs)
+        dur = None  # set for a dispatch that counts
         try:
             preps: List[tuple] = []
             lefts: List[set] = []
@@ -1245,8 +1323,12 @@ class TpuMatcher:
                 self.busy_sheds += 1
                 raise MatcherBusy(cold=True)
             F_t, t1 = operands
+            ph.enter(obs.span("stage_fold_launch_ms"))
             out = K.call_match_many(F_t, t1, meta, preps, statics,
                                     device=self.device)
+            ph.enter(obs.span("stage_fold_wait_ms"))
+            out = np.asarray(out)  # the ONE host pull
+            ph.enter(obs.span("stage_fold_resolve_ms"))
             results = K.unpack_many_results(out, Bpad, statics["C"])
             self._warm_sigs.add(sig)
             if not _warmup:
@@ -1261,10 +1343,6 @@ class TpuMatcher:
             if not _warmup and not watchdog_mod.current_op_abandoned():
                 dur = (time.monotonic() - t_disp) * 1e3
                 obs.observe("stage_device_dispatch_ms", dur)
-                record_dispatch(
-                    "match", t_disp, dur, k=len(batches), batch=n_pubs,
-                    bpad=int(Bpad),
-                    compiled=len(self._warm_sigs) > warm_before)
         finally:
             with self.lock:
                 self._inflight -= 1
@@ -1278,6 +1356,10 @@ class TpuMatcher:
             idx_rows = [flat[pre[i]:pre[i] + total[i]] for i in range(n)]
             outs.append(self._resolve_rows(topics, idx_rows, need_host,
                                            snapshot))
+        if dur is not None:
+            ph.record(t_disp, dur, k=len(batches), batch=n_pubs,
+                      bpad=int(Bpad),
+                      compiled=len(self._warm_sigs) > warm_before)
         return outs
 
     @property
@@ -1393,7 +1475,7 @@ class TpuMatcher:
 
     def _match_windowed(self, dev_arrays, operands, meta, reg_start,
                         reg_end, glob_pad, bits, pw, pl, pd, pb, gb, n,
-                        require_warm: bool = False):
+                        ph, require_warm: bool = False):
         """Run the windowed device path (the production kernel, flat
         variant): a dense pass over region 0 plus probe-A (level-0
         bucket) and probe-B (level-1 g-bucket) window tiles, compacted
@@ -1401,7 +1483,8 @@ class TpuMatcher:
         views, need_host bool array) in original batch order; need_host
         marks pubs the device could not serve exactly (window-overflow
         leftovers, per-part clip at k, flat-capacity overflow) for the
-        exact host fallback."""
+        exact host fallback. ``ph``: the caller's fold phases (prep is
+        open on entry, resolve on return)."""
         S = int(dev_arrays[0].shape[0])
         pallas = (self.use_pallas and S % 2048 == 0 and glob_pad % 2048 == 0
                   and self._gb_end % 2048 == 0)
@@ -1419,6 +1502,7 @@ class TpuMatcher:
             self.busy_sheds += 1
             raise MatcherBusy(cold=True)
         F_t, t1 = operands
+        ph.enter(obs.span("stage_fold_launch_ms"))
         if pallas:
             faults.inject("device.dispatch")
             table_args = (F_t, t1, dev_arrays[1], dev_arrays[2],
@@ -1432,7 +1516,10 @@ class TpuMatcher:
             # single-upload / single-pull transport (see pack_meta /
             # flat_pack_args): one int32 vector each way instead of 12
             # uploads + 4 pulls
-            out = np.asarray(K.call_packed(F_t, t1, meta, args, statics))
+            out = K.call_packed(F_t, t1, meta, args, statics)
+            ph.enter(obs.span("stage_fold_wait_ms"))
+            out = np.asarray(out)
+            ph.enter(obs.span("stage_fold_resolve_ms"))
             flat, pre, total, overflow = K.unpack_flat_result(
                 out, args[0].shape[0], statics["C"])
             need_host = overflow[:n].copy()
@@ -1447,10 +1534,13 @@ class TpuMatcher:
                           dev_arrays[3], dev_arrays[4])
             flat, pre, total, overflow = K.match_extract_windowed_flat(
                 *table_args, *args, **statics)
+        ph.enter(obs.span("stage_fold_wait_ms"))
         flat = np.asarray(flat)
         pre = np.asarray(pre)
         total = np.asarray(total)
-        need_host = np.asarray(overflow)[:n].copy()
+        overflow = np.asarray(overflow)
+        ph.enter(obs.span("stage_fold_resolve_ms"))
+        need_host = overflow[:n].copy()
         for i in left:
             need_host[i] = True
         # per-pub results are VIEWS into flat — no per-pub copies
@@ -1944,6 +2034,8 @@ class BatchCollector:
         fut._vmq_ready = False  # type: ignore[attr-defined]
         fut._vmq_res = None  # type: ignore[attr-defined]
         fut._vmq_exc = None  # type: ignore[attr-defined]
+        fut._vmq_t = 0.0  # type: ignore[attr-defined]  # settled at
+        fut._vmq_trace = None  # type: ignore[attr-defined]
         self._order.append(fut)
         return fut
 
@@ -1964,6 +2056,10 @@ class BatchCollector:
         fut._vmq_ready = True
         fut._vmq_res = res
         fut._vmq_exc = exc
+        if obs.enabled():
+            fut._vmq_t = time.monotonic()
+            if fut._vmq_trace is not None:
+                fut._vmq_trace.stamp("settle")
         if (not self._releasing and self._order
                 and self._order[0]._vmq_ready):
             self._releasing = True
@@ -1976,6 +2072,12 @@ class BatchCollector:
             f = order.popleft()
             if f.done():  # cancelled by the caller
                 continue
+            if budget == self._RELEASE_CHUNK and f._vmq_t:
+                # how long this chunk's head stood settled while the
+                # chunks before it were released and routed: a wait, so
+                # a histogram family and no span
+                obs.observe("stage_release_wait_ms",
+                            (time.monotonic() - f._vmq_t) * 1e3)
             budget -= 1
             if f._vmq_exc is not None:
                 f.set_exception(f._vmq_exc)
@@ -2015,13 +2117,14 @@ class BatchCollector:
         """``trace`` — an optional flight-recorder PublishTrace
         (observability/recorder.py): the sampled-at-admission context
         rides the pending item into the flush, where the collector
-        stamps dequeue/match and, in worker mode, attaches the
+        stamps dequeue/match/settle and, in worker mode, attaches the
         match-service fold meta (the cross-process ring stamps).
         ``feat`` — the publish's payload feature row (filters/engine
         encode) riding the same staging into the predicate phase; None
         for unfiltered mountpoints (zero-cost)."""
         loop = asyncio.get_event_loop()
         fut = self._enqueue_fut(loop)
+        fut._vmq_trace = trace  # the collector stamps its settling
         if (self._inflight >= self.MAX_INFLIGHT
                 and len(self._pending) >= self.max_batch
                 and len(self._pending) >= self.max_batch * (

@@ -9,7 +9,10 @@ Three pieces, all always-on and cheap enough for the publish hot path:
   queue flush, spool journal write, cluster ack RTT). Exposed as proper
   Prometheus ``_bucket``/``_sum``/``_count`` families and aggregated
   across worker processes at the scrape point via
-  ``WorkerStatsBlock`` histogram slots.
+  ``WorkerStatsBlock`` histogram slots. ``span(family)`` times a
+  synchronous section into its family and, for the same interval and
+  under the same name, holds a ``jax.profiler.TraceAnnotation`` open:
+  the program's sections on the clock of a device trace.
 
 - :mod:`.recorder` — the publish-path flight recorder: a bounded ring
   of stage-stamped samples. The 1-in-N sample decision is made ONCE at
@@ -46,13 +49,13 @@ off, every seam pays a single module-global boolean test.
 """
 
 from . import events, histogram
-from .histogram import observe, set_enabled, enabled
+from .histogram import observe, set_enabled, enabled, span
 from .profiler import DispatchProfiler, profiler
 from .recorder import (ClockSync, FlightRecorder, PublishTrace,
                        chrome_trace, clock_sync)
 
 __all__ = [
-    "events", "histogram", "observe", "set_enabled", "enabled",
+    "events", "histogram", "observe", "span", "set_enabled", "enabled",
     "DispatchProfiler", "profiler",
     "ClockSync", "FlightRecorder", "PublishTrace", "chrome_trace",
     "clock_sync",

@@ -16,11 +16,22 @@ and collector code observes without threading a metrics handle through
 every layer, and the broker's ``Metrics`` object reads the registry at
 scrape time. ``set_enabled(False)`` (the ``observability_enabled``
 knob) reduces every seam to one module-global boolean test.
+
+``span(family)`` times a synchronous section of one thread under ONE
+name: the elapsed milliseconds go into the family, and for the same
+interval a ``jax.profiler.TraceAnnotation`` of that name is held open,
+so the section shows on the host plane of a running profiler session,
+on the clock of the device's events (``span_begin`` / ``span_end``: the
+same for a per-publish seam, without the object while no session runs).
+JAX is never imported from here: a process without it (workers, a load
+generator) gets the histogram alone.
 """
 
 from __future__ import annotations
 
+import sys
 import threading
+import time
 import weakref
 from bisect import bisect_left
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
@@ -36,7 +47,8 @@ BUCKET_BOUNDS_MS: Tuple[float, ...] = tuple(
 #: N_BUCKETS + overflow bucket + sum + count
 FLAT_WIDTH = N_BUCKETS + 3
 
-#: the instrumented seams. Every ``observe()`` call site must name one
+#: the instrumented seams. Every ``observe()`` and ``span()`` call site
+#: must name one
 #: of these (tools/lint_metrics.py enforces it), and every family gets
 #: HELP/TYPE in the Prometheus exposition.
 STAGE_FAMILIES: List[Tuple[str, str]] = [
@@ -126,6 +138,36 @@ STAGE_FAMILIES: List[Tuple[str, str]] = [
      "moving unit parks new arrivals, observed per completed handoff "
      "(the bounded-pause guarantee; informs "
      "handoff_freeze_deadline_ms and bench config 15's pause p99)."),
+    ("stage_fold_prep_ms",
+     "Device fold host prep, per dispatch: entry of match_batch/"
+     "match_many to just before the kernel call (matcher-lock wait, "
+     "sync, topic encode, window prep)."),
+    ("stage_fold_launch_ms",
+     "Device fold launch, per dispatch: the kernel call until it "
+     "returns (argument pack, upload, enqueue); the device may still "
+     "be running."),
+    ("stage_fold_wait_ms",
+     "Device fold wait, per dispatch: the blocking pull of the result "
+     "(device run + device-to-host copy)."),
+    ("stage_fold_resolve_ms",
+     "Device fold host resolve, per dispatch: result unpack and slot "
+     "ids to entry rows, up to the fold's return."),
+    ("stage_release_wait_ms",
+     "Collector release-queue wait per release chunk: from the "
+     "settling of a chunk's head future to its set_result (futures "
+     "are released in submission order, 64 per loop callback, each "
+     "waking a session that routes before the next chunk)."),
+    ("stage_route_ms",
+     "Publish routing per publish under the batched view: route_rows "
+     "after the collector's rows arrived (queue enqueue, session "
+     "deliver, PUBLISH encode and socket write of every recipient)."),
+    ("stage_ack_in_ms",
+     "Inbound PUBACK/PUBCOMP handling per ack: in-flight window "
+     "bookkeeping, pending pump and queue notify_ready."),
+    ("stage_pub_admit_ms",
+     "Sampled publish admission: frame handling start to the "
+     "collector submit stamp (rate/governor gates, topic validation, "
+     "auth, pre-publish, collector submit; flight-recorder samples)."),
 ]
 
 _ENABLED = True
@@ -265,6 +307,119 @@ def observe(name: str, ms: float) -> None:
     sites statically too)."""
     if _ENABLED:
         _REGISTRY[name].observe(ms)  # lint: observe-passthrough
+
+
+class Span:
+    """One timed section: ``with span(f):`` or ``s = span(f).begin()``
+    ... ``s.end()``. Not re-entrant, one thread, never across an
+    ``await`` (the annotation nests by thread)."""
+
+    __slots__ = ("_hist", "_ann", "_t0")
+
+    def __init__(self, hist: Histogram, ann) -> None:
+        self._hist = hist
+        self._ann = ann
+
+    @property
+    def family(self) -> str:
+        return self._hist.name
+
+    def begin(self) -> "Span":
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = _monotonic()
+        return self
+
+    def end(self, record: bool = True) -> float:
+        """Close the section; returns its milliseconds. ``record=False``
+        leaves the family alone (the caller observes later, or never:
+        a warm-up or an abandoned dispatch)."""
+        ms = (_monotonic() - self._t0) * 1e3
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        if record and _ENABLED:
+            self._hist.observe(ms)  # lint: observe-passthrough
+        return ms
+
+    __enter__ = begin
+
+    def __exit__(self, _et, _ev, _tb) -> None:
+        self.end()
+
+
+class _NoSpan:
+    """What ``span`` hands out while observability is off."""
+
+    __slots__ = ()
+    family = ""
+
+    def begin(self) -> "_NoSpan":
+        return self
+
+    def end(self, record: bool = True) -> float:
+        return 0.0
+
+    __enter__ = begin
+
+    def __exit__(self, _et, _ev, _tb) -> None:
+        return None
+
+
+_NO_SPAN = _NoSpan()
+_monotonic = time.monotonic
+_modules = sys.modules
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, once JAX is loaded
+
+
+def _find_session() -> bool:
+    """Does a profiler session run? Until this process has imported JAX
+    (never imported from here) none can: look for it, and once it is
+    there let ``_TRACING`` be its own ``TraceAnnotation.is_enabled``."""
+    global _ANNOTATION, _TRACING
+    prof = getattr(_modules.get("jax"), "profiler", None)
+    ann = getattr(prof, "TraceAnnotation", None)  # None while JAX imports
+    if ann is None:
+        return False
+    _ANNOTATION, _TRACING = ann, ann.is_enabled
+    return _TRACING()
+
+
+_TRACING = _find_session
+
+
+def span(family: str):
+    """A section timed into ``family`` and, while a profiler session
+    runs, shown under that name in its trace (with none running the
+    annotation is not made: a per-publish seam pays for the histogram
+    alone). One boolean test when observability is off; unknown names
+    raise here, not at the section's end."""
+    if not _ENABLED:
+        return _NO_SPAN
+    return Span(_REGISTRY[family],
+                _ANNOTATION(family) if _TRACING() else None)
+
+
+def span_begin(family: str):
+    """``span(family).begin()`` for a per-publish seam, where the object
+    costs as much as the section it times: with no profiler session
+    running the token is the start time alone. Close it with
+    :func:`span_end` in a ``finally``."""
+    if not _ENABLED:
+        return None
+    if _TRACING():
+        return Span(_REGISTRY[family], _ANNOTATION(family)).begin()
+    return _monotonic()
+
+
+def span_end(family: str, token) -> None:
+    if token is None:
+        return
+    if token.__class__ is float:
+        if _ENABLED:
+            _REGISTRY[family].observe(  # lint: observe-passthrough
+                (_monotonic() - token) * 1e3)
+    else:
+        token.end()
 
 
 def get(name: str) -> Histogram:

@@ -3,7 +3,8 @@
 One :class:`PublishTrace` per SAMPLED publish (1-in-N, decided once at
 admission in ``session._handle_publish``), carried through the routing
 layers and the batch-collector fold envelope: the session stamps
-admission and route completion, the collector stamps dequeue/dispatch,
+admission and route completion, the collector stamps dequeue/dispatch
+and the settling of the publish's future, the registry its release,
 and in worker mode the match-service fold meta (service receive/done
 monotonic stamps + pid, carried back in the ring reply) lands in the
 same trace — ONE record per publish with per-stage deltas including the
@@ -33,6 +34,13 @@ _STAGE_OF = {
     "submit": "collector_submit",
     "dequeue": "collector_wait",
     "match": "match",
+    # results ready -> the future settled (a trie-served publish has no
+    # match stamp: its settle stage is the host walk)
+    "settle": "settle",
+    # settled -> the publish's routing began: the collector releases
+    # futures in submission order, 64 a loop callback, and every
+    # released publish routes before the next chunk
+    "release": "release_wait",
     "route": "route",
     "forward": "cluster_forward",
     "remote_recv": "cluster_ingress",
@@ -235,7 +243,8 @@ class FlightRecorder:
     def finish(self, trace: PublishTrace) -> Dict[str, Any]:
         """Compute per-stage deltas and append ONE record. Also feeds
         the sampled ``stage_parse_route_ms`` histogram (total broker
-        residency of the sampled publish)."""
+        residency of the sampled publish) and ``stage_pub_admit_ms``
+        (its start to the collector submit)."""
         cid, topic, qos = trace.info
         stages: Dict[str, float] = {}
         prev = trace.t0
@@ -245,6 +254,8 @@ class FlightRecorder:
             stages[f"{name}_ms"] = round((t - prev) * 1e3, 4)
             prev = t
             last = max(last, t)
+            if label == "submit":
+                hist.observe("stage_pub_admit_ms", (t - trace.t0) * 1e3)
         meta = trace.meta
         if meta and "svc_recv" in meta:
             # cross-process split of the ring round trip: request

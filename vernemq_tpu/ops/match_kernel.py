@@ -688,24 +688,29 @@ def _windowed_flat_core(F_t, t1, sub_eff_len, has_hash, first_wild, active,
                         a_tile, a_pos, b_tile, b_pos, *,
                         id_bits, k, glob_pad, seg_max, seg2_max, gc, C):
     """Shared body of the flat windowed kernels (plain and packed-I/O)."""
+    # the named scopes go into the operations' metadata and nowhere
+    # else: a device trace attributes each operation's time to its phase
     B = pub_words.shape[0]
     real = jnp.arange(B, dtype=jnp.int32) < n_real
 
-    g = _dense_region0(F_t, t1, sub_eff_len, has_hash, first_wild, active,
-                       pub_words, pub_len, pub_dollar, id_bits=id_bits,
-                       k=k, glob_pad=glob_pad, gc=gc)
+    with jax.named_scope("dense_region0"):
+        g = _dense_region0(F_t, t1, sub_eff_len, has_hash, first_wild,
+                           active, pub_words, pub_len, pub_dollar,
+                           id_bits=id_bits, k=k, glob_pad=glob_pad, gc=gc)
 
     args = (F_t, t1, sub_eff_len, has_hash, first_wild, active,
             pub_words, pub_len, pub_dollar)
-    tidx, tvalid, tcount = _window_tiles_sel(
-        *args, t_sel, t_start, id_bits=id_bits, k=k,
-        seg_max=seg_max, glob_pad=glob_pad, wild_rows=False)
-    a = _gather_parts(tidx, tvalid, tcount, a_tile, a_pos)
+    with jax.named_scope("probe_a"):
+        tidx, tvalid, tcount = _window_tiles_sel(
+            *args, t_sel, t_start, id_bits=id_bits, k=k,
+            seg_max=seg_max, glob_pad=glob_pad, wild_rows=False)
+        a = _gather_parts(tidx, tvalid, tcount, a_tile, a_pos)
     if seg2_max:
-        t2idx, t2valid, t2count = _window_tiles_sel(
-            *args, t2_sel, t2_start, id_bits=id_bits, k=k,
-            seg_max=seg2_max, glob_pad=glob_pad, wild_rows=True)
-        b = _gather_parts(t2idx, t2valid, t2count, b_tile, b_pos)
+        with jax.named_scope("probe_b"):
+            t2idx, t2valid, t2count = _window_tiles_sel(
+                *args, t2_sel, t2_start, id_bits=id_bits, k=k,
+                seg_max=seg2_max, glob_pad=glob_pad, wild_rows=True)
+            b = _gather_parts(t2idx, t2valid, t2count, b_tile, b_pos)
     else:
         b = (jnp.zeros((B, k), jnp.int32), jnp.zeros((B, k), bool),
              jnp.zeros((B,), jnp.int32))
@@ -717,7 +722,8 @@ def _windowed_flat_core(F_t, t1, sub_eff_len, has_hash, first_wild, active,
     # charging the raw count would let one mega-fanout pub reserve its
     # entire raw fanout and cascade spurious capacity overflows (= slow
     # exact host scans) across the rest of the batch.
-    return _flat_combine(real, k, C, g, a, b)
+    with jax.named_scope("flat_combine"):
+        return _flat_combine(real, k, C, g, a, b)
 
 
 @jax.jit
@@ -918,8 +924,10 @@ def _packed_core(F_t, t1, meta, packed, *, B, L, T, TP, T2, id_bits, k,
                  glob_pad, seg_max, seg2_max, gc, C):
     """Unpack + match + repack (shared by the jitted packed entry point
     and the device-resident throughput scan)."""
+    with jax.named_scope("unpack_transport"):
+        unpacked = _unpack_transport(meta, packed, B, L, T, TP, T2)
     flat, pre, total, overflow = _windowed_flat_core(
-        F_t, t1, *_unpack_transport(meta, packed, B, L, T, TP, T2),
+        F_t, t1, *unpacked,
         id_bits=id_bits, k=k, glob_pad=glob_pad, seg_max=seg_max,
         seg2_max=seg2_max, gc=gc, C=C)
     return jnp.concatenate([flat, pre, total, overflow.astype(jnp.int32)])
@@ -1042,7 +1050,8 @@ def call_match_many(F_t, t1, meta, preps, statics, device=None):
 
 def unpack_many_results(out, B: int, C: int):
     """Decode :func:`match_many`'s stacked ``[K, C + 3B]`` result into K
-    ``(flat, pre, total, overflow)`` tuples with ONE host pull."""
+    ``(flat, pre, total, overflow)`` tuples with ONE host pull (none
+    where the caller pulled already, to time the pull by itself)."""
     o = np.asarray(out)
     return [unpack_flat_result(o[i], B, C) for i in range(o.shape[0])]
 
@@ -1254,6 +1263,7 @@ def delta_pack_args(slots, words, eff, hh, fw, ac):
 
 @functools.partial(jax.jit, static_argnames=("D", "L", "id_bits"),
                    donate_argnums=(0, 1, 2, 3, 4, 5, 6, 7))
+@jax.named_scope("delta_scatter")
 def apply_delta_fused(
     sub_words, sub_eff_len, has_hash, first_wild, active,  # table [S,·]
     F_t, t1,                                               # coded operands
@@ -1335,6 +1345,7 @@ apply_delta_fused_nometa_copy = jax.jit(
 
 @functools.partial(jax.jit, static_argnames=("D", "L", "id_bits", "glob"),
                    donate_argnums=tuple(range(12)))
+@jax.named_scope("delta_scatter")
 def apply_delta_windowed_fused(
     F_t, t1, eff, hh, fw, act,          # 'sub'-sharded full-table arrays
     Fg, t1g, effg, hhg, fwg, actg,      # replicated dense g-zone mirrors
