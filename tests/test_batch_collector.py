@@ -3,6 +3,7 @@ in-flight, self-batching backpressure under saturation (the pipelined
 collector of VERDICT r3 item 2)."""
 
 import asyncio
+import functools
 import time
 
 import pytest
@@ -282,3 +283,128 @@ async def test_slowest_recent_flush_is_a_decaying_peak():
                                for i in range(8)])
     # three quick flushes: the peak has decayed, not vanished
     assert 0.4 * slow < col.dispatch_peak_ms < 0.6 * slow
+
+
+class _TrieReg:
+    """The registry a shed path asks for the host trie."""
+
+    class _T:
+        @staticmethod
+        def match(topic):
+            return [("host-row", tuple(topic))]
+
+    def trie(self, mp):
+        return self._T
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("path", ["device", "hybrid", "overload", "expiry"])
+async def test_continuations_and_futures_leave_in_one_submission_order(
+        path):
+    """A submission made with ``cont`` gets its rows by an inline call
+    from the release queue and makes no future; mixed with awaited
+    submissions, whichever path served them — the device, the host
+    trie below the threshold, the overload shed, the queued-item
+    expiry — all leave in ONE order, the order of submission."""
+    view = _SlowView(device_ms=60 if path in ("overload", "expiry") else 5)
+    view.registry = _TrieReg()
+    kw = dict(window_us=100, max_batch=8, host_threshold=0)
+    n = 40
+    if path == "hybrid":
+        kw.update(max_batch=64, host_threshold=64)
+    elif path == "device":
+        kw.update(max_batch=64)
+    elif path == "expiry":
+        kw.update(item_expiry_ms=20, latency_budget_ms=5.0)
+        n = 20  # two dispatches in flight, the rest waits and expires
+    col = BatchCollector(view, **kw)
+    order = []
+    futs = {}
+    misplaced = []
+
+    def cont(rows, exc, i):
+        # a future is done the moment the queue released it (its
+        # callbacks run a loop step later): every awaited submission
+        # before this one has left, none after it has
+        misplaced.extend(j for j, f in futs.items()
+                         if f.done() != (j < i))
+        order.append((i, rows[0][0]))
+
+    for i in range(n):
+        if i % 3 == 0:
+            futs[i] = col.submit("", ("x", str(i)))
+            futs[i].add_done_callback(
+                lambda f, i=i: order.append((i, f.result()[0][0])))
+        else:
+            r = col.submit("", ("x", str(i)),
+                           cont=functools.partial(cont, i=i))
+            assert r is None
+    assert len(col._order) == n
+    await asyncio.gather(*futs.values())
+    for _ in range(200):
+        if len(order) == n:
+            break
+        await asyncio.sleep(0.01)
+    assert misplaced == []
+    for form in (0, 1):  # each form alone is in order too
+        idx = [i for i, _ in order if (i % 3 == 0) == (form == 0)]
+        assert idx == sorted(idx)
+    assert len(order) == n
+    served = {row for _, row in order}
+    if path == "device":
+        assert served == {"row"}
+    elif path == "hybrid":
+        assert served == {"host-row"} and col.host_hybrid_pubs == n
+    elif path == "overload":
+        assert served == {"row", "host-row"} and col.overload_host_pubs
+    else:
+        assert served == {"row", "host-row"} and col.expired_host_pubs
+    assert not col._order and not col._releasing
+
+
+@pytest.mark.asyncio
+async def test_continuation_gets_the_folds_error_and_one_that_raises_is_contained():
+    """A fold that raises reaches a continuation as ``exc``; a
+    continuation that raises itself does not take the queue behind it
+    down."""
+
+    class _Boom(_SlowView):
+        def fold_batch(self, mp, topics, lock_timeout=None):
+            raise RuntimeError("device on fire")
+
+    col = BatchCollector(_Boom(), window_us=100, max_batch=8,
+                         host_threshold=0)
+    seen = []
+
+    def bad(rows, exc):
+        raise ValueError("continuation bug")
+
+    col.submit("", ("x", "0"), cont=bad)
+    col.submit("", ("x", "1"), cont=lambda rows, exc: seen.append(exc))
+    fut = col.submit("", ("x", "2"))
+    with pytest.raises(RuntimeError):
+        await fut
+    assert len(seen) == 1 and isinstance(seen[0], RuntimeError)
+    assert not col._order and col._inflight == 0
+
+
+@pytest.mark.asyncio
+async def test_release_wait_is_observed_for_continuations_too():
+    """``stage_release_wait_ms``: one observation a release chunk,
+    whichever form its head has."""
+    from vernemq_tpu.observability import histogram as obs
+
+    view = _SlowView(device_ms=1)
+    col = BatchCollector(view, window_us=100, max_batch=1024,
+                         host_threshold=0)
+    n = 3 * col._RELEASE_CHUNK
+    done = []
+    before = obs.get("stage_release_wait_ms").snapshot()[2]
+    for i in range(n):
+        col.submit("", ("t", str(i)), cont=lambda r, e: done.append(r))
+    for _ in range(500):
+        if len(done) == n:
+            break
+        await asyncio.sleep(0.01)
+    assert len(done) == n
+    assert obs.get("stage_release_wait_ms").snapshot()[2] - before == 3

@@ -182,7 +182,10 @@ async def test_broker_tpu_view_pallas_bucketed(tmp_path):
             Config(systree_enabled=False, allow_anonymous=True,
                    default_reg_view="tpu", tpu_use_pallas=True,
                    tpu_initial_capacity=8192,  # pre-sized: bucketed layout
-                   tpu_host_batch_threshold=0, tpu_batch_window_us=500),
+                   tpu_host_batch_threshold=0, tpu_batch_window_us=500,
+                   # a compile that lags the loop on a crowded machine
+                   # must not raise the governor: level 2 sheds QoS 0
+                   sysmon_enabled=False),
             port=0)
         from vernemq_tpu.protocol.types import SubOpts
 

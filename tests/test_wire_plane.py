@@ -641,3 +641,449 @@ async def test_v5_alias_lru_eviction_on_wire_path():
     finally:
         await broker.stop()
         await server.stop()
+
+
+# ------------------------------------------------- batched (device) view
+#
+# Under ``default_reg_view="tpu"`` a QoS1/2 publish that passes the gate
+# is admitted from the frame table, handed to the collector with a
+# continuation, and acknowledged from that continuation after the route.
+
+
+async def boot_batched(**cfg):
+    cfg.setdefault("default_reg_view", "tpu")
+    cfg.setdefault("tpu_batch_window_us", 2000)
+    cfg.setdefault("tpu_host_batch_threshold", 0)
+    cfg.setdefault("watchdog_enabled", False)
+    cfg.setdefault("sysmon_enabled", False)
+    broker, server = await boot(**cfg)
+    assert broker.registry.batched_view_active()
+    return broker, server
+
+
+class HeldFold:
+    """The view's ``fold_batch`` behind a gate: a flush whose number is
+    in ``hold`` waits (in its executor thread) until ``release()``; with
+    ``fail`` every flush raises instead."""
+
+    def __init__(self, broker, hold=(1,), fail=None):
+        import threading
+
+        self.view = broker.registry.reg_view("tpu")
+        self.view.matcher("")  # the table loaded: no flush sheds to the trie
+        self.orig = self.view.fold_batch
+        self.gate = threading.Event()
+        self.hold = set(hold)
+        self.fail = fail
+        self.calls = []
+        self.view.fold_batch = self
+
+    def __call__(self, mp, topics, lock_timeout=None, **kw):
+        self.calls.append(len(topics))
+        if len(self.calls) in self.hold:
+            assert self.gate.wait(20.0)
+        if self.fail is not None:
+            raise self.fail
+        return self.orig(mp, topics, lock_timeout, **kw)
+
+    def release(self):
+        self.gate.set()
+
+
+async def until(pred, timeout=10.0):
+    deadline = asyncio.get_event_loop().time() + timeout
+    while not pred():
+        assert asyncio.get_event_loop().time() < deadline, "timed out"
+        await asyncio.sleep(0.02)
+
+
+async def quiet(raw, seconds=0.3):
+    """Nothing arrives on ``raw`` for ``seconds``."""
+    try:
+        chunk = await asyncio.wait_for(raw.reader.read(65536), seconds)
+    except asyncio.TimeoutError:
+        return True
+    raw.buf += chunk
+    return False
+
+
+def q_publish(i, qos=1, topic="q/t", **kw):
+    return codec_v4.serialise(Publish(topic=topic, payload=b"q%04d" % i,
+                                      qos=qos, packet_id=i + 1, **kw))
+
+
+def session_of(broker, client_id):
+    return broker.sessions[("", client_id)]
+
+
+@pytest.mark.asyncio
+async def test_batched_qos1_builds_no_frame_no_msg_no_future():
+    """500 QoS1 publishes under the batched view: zero Publish frames,
+    zero inbound Msg objects (QoS0 recipients: no outbound one either),
+    every submission in the continuation form — no future a publish —
+    and all of them counted by wire_fastpath_pubs_qos, none classic."""
+    from vernemq_tpu.broker import message as message_mod
+
+    broker, server = await boot_batched(observability_enabled=False)
+    try:
+        sub = await Raw.connect(server.port, "bq1sub")
+        await sub.send(codec_v4.serialise(Subscribe(
+            packet_id=1, topics=[("q/#", SubOpts(qos=0))])))
+        await sub.read_frames(2)
+        pub = await Raw.connect(server.port, "bq1pub")
+        n = 500
+        blob = b"".join(q_publish(i, topic=f"q/{i % 8}") for i in range(n))
+        base_fast = fastpath.fastpath_pubs_qos
+        base_classic = fastpath.classic_pubs_qos
+
+        col = broker.batch_collector()
+        returned = []
+        submit = col.submit
+
+        def counting_submit(*a, **k):
+            returned.append(submit(*a, **k))
+            return returned[-1]
+
+        col.submit = counting_submit
+        loop = asyncio.get_event_loop()
+        futures = {"n": 0}
+        create_future = loop.create_future
+
+        def counting_future():
+            futures["n"] += 1
+            return create_future()
+
+        counts = {"publish": 0, "msg": 0}
+        pub_init = Publish.__init__
+        msg_init = message_mod.Msg.__init__
+
+        def counting_pub(self, *a, **k):
+            counts["publish"] += 1
+            return pub_init(self, *a, **k)
+
+        def counting_msg(self, *a, **k):
+            counts["msg"] += 1
+            return msg_init(self, *a, **k)
+
+        Publish.__init__ = counting_pub
+        message_mod.Msg.__init__ = counting_msg
+        loop.create_future = counting_future
+        try:
+            await pub.send(blob)
+            deadline = loop.time() + 20.0
+            while (fastpath.fastpath_pubs_qos - base_fast) < n \
+                    or session_of(broker, "bq1pub").wire_inflight:
+                assert loop.time() < deadline
+                await asyncio.sleep(0.05)
+        finally:
+            del loop.create_future
+            del col.submit
+            Publish.__init__ = pub_init
+            message_mod.Msg.__init__ = msg_init
+        assert counts == {"publish": 0, "msg": 0}
+        assert len(returned) == n and all(r is None for r in returned)
+        # the reader's waits at its run bound, socket reads, this test's
+        # own sleeps: far fewer than one a publish
+        assert futures["n"] < n // 4, futures
+        assert fastpath.classic_pubs_qos == base_classic
+        acks = (await pub.read_frames(1 + n))[1:]
+        assert [a.packet_id for a in acks] == [i + 1 for i in range(n)]
+        assert all(type(a).__name__ == "Puback" for a in acks)
+        frames = await sub.read_frames(2 + n)
+        assert [f.payload for f in frames[2:]] == \
+            [b"q%04d" % i for i in range(n)]
+        stats = broker.registry.stats()
+        assert stats["wire_fastpath_pubs_qos"] >= n
+        assert "wire_classic_pubs_qos" in stats
+        sub.close()
+        pub.close()
+    finally:
+        await broker.stop()
+        await server.stop()
+
+
+@pytest.mark.asyncio
+async def test_batched_puback_leaves_after_the_route():
+    """While the fold is held neither the delivery nor the PUBACK
+    exists; once the rows arrive the recipient's bytes are written
+    first and the PUBACK after the route returned."""
+    broker, server = await boot_batched()
+    try:
+        sub = await Raw.connect(server.port, "arsub")
+        await sub.send(codec_v4.serialise(Subscribe(
+            packet_id=1, topics=[("q/#", SubOpts(qos=1))])))
+        await sub.read_frames(2)
+        pub = await Raw.connect(server.port, "arpub")
+        held = HeldFold(broker)
+        events = []
+        reg = broker.registry
+        route = reg._wire_route
+
+        def traced_route(*a, **k):
+            n = route(*a, **k)
+            events.append(("routed", n))
+            return n
+
+        reg._wire_route = traced_route
+        psess = session_of(broker, "arpub")
+        send = psess.send
+
+        def traced_send(frame):
+            events.append(("sent", type(frame).__name__))
+            return send(frame)
+
+        psess.send = traced_send
+        await pub.send(q_publish(0))
+        await until(lambda: held.calls)
+        assert await quiet(pub) and await quiet(sub, 0.05)
+        assert psess.wire_inflight == 1 and events == []
+        held.release()
+        delivered = (await sub.read_frames(3))[2]
+        assert delivered.payload == b"q0000" and delivered.qos == 1
+        ack = (await pub.read_frames(2))[1]
+        assert type(ack).__name__ == "Puback" and ack.packet_id == 1
+        assert events == [("routed", 1), ("sent", "Puback")]
+        assert psess.wire_inflight == 0
+        sub.close()
+        pub.close()
+    finally:
+        await broker.stop()
+        await server.stop()
+
+
+@pytest.mark.asyncio
+async def test_batched_puback_order_is_arrival_order():
+    """Two device flushes with a trie-served one between them, the
+    first held until the others have settled: the PUBACKs still leave
+    in the order the publishes arrived, and so do the deliveries."""
+    broker, server = await boot_batched(tpu_host_batch_threshold=2)
+    try:
+        sub = await Raw.connect(server.port, "orsub")
+        await sub.send(codec_v4.serialise(Subscribe(
+            packet_id=1, topics=[("q/#", SubOpts(qos=0))])))
+        await sub.read_frames(2)
+        pub = await Raw.connect(server.port, "orpub")
+        held = HeldFold(broker)
+        col = broker.batch_collector()
+        await pub.send(b"".join(q_publish(i) for i in range(5)))
+        await until(lambda: held.calls == [5])
+        await pub.send(q_publish(5))  # <= threshold: the trie serves it
+        await until(lambda: col.host_hybrid_pubs == 1)
+        await pub.send(b"".join(q_publish(i) for i in range(6, 11)))
+        await until(lambda: held.calls == [5, 5] and col._inflight == 1)
+        assert await quiet(pub, 0.2)  # all settled behind the held head
+        held.release()
+        acks = (await pub.read_frames(12))[1:]
+        assert [a.packet_id for a in acks] == list(range(1, 12))
+        frames = await sub.read_frames(13)
+        assert [f.payload for f in frames[2:]] == \
+            [b"q%04d" % i for i in range(11)]
+        sub.close()
+        pub.close()
+    finally:
+        await broker.stop()
+        await server.stop()
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("behind", ["subscribe", "disconnect", "pubrel"])
+async def test_batched_classic_record_waits_for_the_continuation(behind):
+    """A record that is neither a fast publish nor a fast ack runs only
+    after the publishes before it were routed and acknowledged."""
+    broker, server = await boot_batched()
+    try:
+        pub = await Raw.connect(server.port, "cwpub")
+        held = HeldFold(broker)
+        psess = session_of(broker, "cwpub")
+        if behind == "subscribe":
+            tail = codec_v4.serialise(Subscribe(
+                packet_id=9, topics=[("q/#", SubOpts(qos=0))]))
+        elif behind == "disconnect":
+            from vernemq_tpu.protocol.types import Disconnect
+            tail = codec_v4.serialise(Disconnect())
+        else:
+            from vernemq_tpu.protocol.types import Pubrel
+            tail = codec_v4.serialise(Pubrel(packet_id=1))
+        qos = 2 if behind == "pubrel" else 1
+        await pub.send(q_publish(0, qos=qos) + tail)
+        await until(lambda: held.calls)
+        assert await quiet(pub)
+        assert psess.wire_inflight == 1 and not psess.closed
+        if behind == "pubrel":
+            assert 1 in psess.awaiting_rel  # credit held at admission
+        held.release()
+        if behind == "disconnect":
+            ack = (await pub.read_frames(2))[1]
+            assert type(ack).__name__ == "Puback"
+            await until(lambda: psess.closed)
+            assert psess.close_reason == "client_disconnect"
+        else:
+            first, second = (await pub.read_frames(3))[1:]
+            want = (("Pubrec", "Pubcomp") if behind == "pubrel"
+                    else ("Puback", "Suback"))
+            assert (type(first).__name__, type(second).__name__) == want
+            if behind == "subscribe":
+                # the SUBSCRIBE ran AFTER the publish was routed: the
+                # publisher's own new subscription saw nothing of it
+                assert await quiet(pub, 0.1)
+            else:
+                assert psess.awaiting_rel == {}
+        pub.close()
+    finally:
+        await broker.stop()
+        await server.stop()
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("qos", [1, 2])
+async def test_batched_failed_fold_withholds_the_ack(qos):
+    """A fold that raises: no PUBACK / PUBREC, the QoS2 receive credit
+    comes back, and the client's DUP retry is routed and acknowledged."""
+    broker, server = await boot_batched()
+    try:
+        sub = await Raw.connect(server.port, "ffsub")
+        await sub.send(codec_v4.serialise(Subscribe(
+            packet_id=1, topics=[("q/#", SubOpts(qos=0))])))
+        await sub.read_frames(2)
+        pub = await Raw.connect(server.port, "ffpub")
+        held = HeldFold(broker, hold=(), fail=ValueError("boom"))
+        psess = session_of(broker, "ffpub")
+        errors = broker.metrics.value("mqtt_publish_error")
+        await pub.send(q_publish(0, qos=qos))
+        await until(lambda: held.calls and not psess.wire_inflight)
+        assert await quiet(pub) and await quiet(sub, 0.05)
+        assert broker.metrics.value("mqtt_publish_error") == errors + 1
+        assert psess.awaiting_rel == {}
+        held.fail = None
+        await pub.send(q_publish(0, qos=qos, dup=True))
+        ack = (await pub.read_frames(2))[1]
+        assert type(ack).__name__ == ("Puback" if qos == 1 else "Pubrec")
+        assert (await sub.read_frames(3))[2].payload == b"q0000"
+        sub.close()
+        pub.close()
+    finally:
+        await broker.stop()
+        await server.stop()
+
+
+@pytest.mark.asyncio
+async def test_batched_v5_no_subscriber_reason_code():
+    from vernemq_tpu.protocol.types import RC_NO_MATCHING_SUBSCRIBERS
+
+    broker, server = await boot_batched()
+    try:
+        sub = await Raw.connect(server.port, "nmsub")
+        await sub.send(codec_v4.serialise(Subscribe(
+            packet_id=1, topics=[("q/yes", SubOpts(qos=0))])))
+        await sub.read_frames(2)
+        pub = await Raw5.connect(server.port, "nmpub")
+        base = fastpath.fastpath_pubs_qos
+        await pub.send(b"".join(
+            codec_v5.serialise(Publish(topic=t, payload=b"x", qos=1,
+                                       packet_id=i + 1))
+            for i, t in enumerate(("q/none", "q/yes"))))
+        none, yes = await pub.recv5(2)
+        assert (none.packet_id, none.reason_code) == \
+            (1, RC_NO_MATCHING_SUBSCRIBERS)
+        assert (yes.packet_id, yes.reason_code) == (2, 0)
+        assert fastpath.fastpath_pubs_qos - base == 2
+        sub.close()
+        pub.close()
+    finally:
+        await broker.stop()
+        await server.stop()
+
+
+@pytest.mark.asyncio
+async def test_batched_reader_parks_at_the_run_bound():
+    """One connection has at most FRAME_RUN publishes out with the
+    collector: the next one parks the reader until they are back."""
+    from vernemq_tpu.broker.server import FRAME_RUN
+
+    broker, server = await boot_batched()
+    try:
+        pub = await Raw.connect(server.port, "cappub")
+        held = HeldFold(broker)
+        psess = session_of(broker, "cappub")
+        col = broker.batch_collector()
+        n = FRAME_RUN + 6
+        await pub.send(b"".join(q_publish(i) for i in range(n)))
+        await until(lambda: psess.wire_inflight == FRAME_RUN)
+        assert await quiet(pub)
+        assert psess.wire_inflight == FRAME_RUN
+        assert len(col._order) == FRAME_RUN and not col._pending
+        held.release()
+        acks = (await pub.read_frames(1 + n))[1:]
+        assert [a.packet_id for a in acks] == list(range(1, n + 1))
+        assert psess.wire_inflight == 0
+        pub.close()
+    finally:
+        await broker.stop()
+        await server.stop()
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("edge", ["retain", "dup", "hook", "governor"])
+async def test_batched_gate_leaves_edges_to_the_classic_path(edge):
+    """What the gate cannot prove simple keeps the classic handler
+    under the batched view too, and the two counters say which path a
+    publish took."""
+    broker, server = await boot_batched()
+    try:
+        sub = await Raw.connect(server.port, "edsub")
+        await sub.send(codec_v4.serialise(Subscribe(
+            packet_id=1, topics=[("q/#", SubOpts(qos=0))])))
+        await sub.read_frames(2)
+        pub = await Raw.connect(server.port, "edpub")
+        kw = {}
+        if edge == "retain":
+            kw["retain"] = True
+        elif edge == "dup":
+            kw["dup"] = True
+        elif edge == "hook":
+            broker.hooks.register("on_publish", lambda *a: None)
+        else:
+            broker.overload.pin(1)
+        fast, classic = fastpath.fastpath_pubs_qos, \
+            fastpath.classic_pubs_qos
+        await pub.send(q_publish(0, **kw))
+        ack = (await pub.read_frames(2))[1]
+        assert type(ack).__name__ == "Puback"
+        assert (await sub.read_frames(3))[2].payload == b"q0000"
+        assert fastpath.fastpath_pubs_qos == fast
+        assert fastpath.classic_pubs_qos == classic + 1
+        if edge == "governor":
+            broker.overload.pin(None)
+        sub.close()
+        pub.close()
+    finally:
+        await broker.stop()
+        await server.stop()
+
+
+@pytest.mark.asyncio
+async def test_batched_closed_session_is_routed_not_acknowledged():
+    """A publisher gone while its publish is with the collector: the
+    publish is still delivered, no acknowledgement is written."""
+    broker, server = await boot_batched()
+    try:
+        sub = await Raw.connect(server.port, "clsub")
+        await sub.send(codec_v4.serialise(Subscribe(
+            packet_id=1, topics=[("q/#", SubOpts(qos=0))])))
+        await sub.read_frames(2)
+        pub = await Raw.connect(server.port, "clpub")
+        held = HeldFold(broker)
+        psess = session_of(broker, "clpub")
+        await pub.send(q_publish(0))
+        await until(lambda: held.calls)
+        pub.close()
+        await until(lambda: psess.closed)
+        acked = broker.metrics.value("mqtt_puback_sent")
+        held.release()
+        assert (await sub.read_frames(3))[2].payload == b"q0000"
+        await until(lambda: psess.wire_inflight == 0)
+        assert broker.metrics.value("mqtt_puback_sent") == acked
+        sub.close()
+    finally:
+        await broker.stop()
+        await server.stop()

@@ -385,6 +385,13 @@ class Broker:
                                       "through the wire fast path (pid "
                                       "stamped from the frame-table "
                                       "span, no inbound frame object).",
+            "wire_classic_pubs_qos": "QoS1/2 publishes that took the "
+                                     "classic handler (retained, dup, "
+                                     "a hook, a tracer, a rate limit, "
+                                     "a raised governor, a protocol "
+                                     "edge): with wire_fastpath_pubs_"
+                                     "qos, the share the wire plane "
+                                     "admitted.",
             "wire_fastpath_acks": "Ack-family frames (PUBACK/PUBREC/"
                                   "PUBREL/PUBCOMP) resolved straight "
                                   "from the frame table with no frame "

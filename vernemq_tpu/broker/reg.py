@@ -698,24 +698,21 @@ class Registry:
         ``trace`` finishes here, after route_rows — the record's route
         stage covers the fanout work too."""
         msg = self._pre_publish(msg)
-        fut = self.broker.batch_collector().submit(
-            msg.mountpoint, msg.topic, trace, feat=self._filters_feat(msg))
 
-        def _done(f: "asyncio.Future") -> None:
-            exc = f.exception()
+        def _routed(rows, exc) -> None:
             if exc is not None:
                 self.broker.metrics.incr("mqtt_publish_error")
                 return
             tok = _route_begin(trace)
             try:
-                self.route_rows(msg, f.result(), from_sid, trace=trace)
+                self.route_rows(msg, rows, from_sid, trace=trace)
             finally:
                 obs.span_end("stage_route_ms", tok)
-            if trace is not None:
-                trace.stamp("route")
-                self.broker.recorder.finish(trace)
+            self._route_finish(trace)
 
-        fut.add_done_callback(_done)
+        self.broker.batch_collector().submit(
+            msg.mountpoint, msg.topic, trace, feat=self._filters_feat(msg),
+            cont=_routed)
         return 0
 
     def publish_wire_qos0(self, mountpoint: str,
@@ -741,60 +738,85 @@ class Registry:
         ``wire_frame[payload_skip:]`` and is sliced out lazily only by
         the branches that need it."""
         if self.batched_view_active():
-            fut = self.broker.batch_collector().submit(
-                mountpoint, words, trace, feat=None)
-
-            def _done(f: "asyncio.Future") -> None:
-                exc = f.exception()
+            def _routed(rows, exc) -> None:
                 if exc is not None:
                     self.broker.metrics.incr("mqtt_publish_error")
                     return
                 tok = _route_begin(trace)
                 try:
                     self._wire_route(mountpoint, words, topic_str, payload,
-                                     f.result(), from_sid, wire_frame,
-                                     payload_skip)
+                                     rows, from_sid, wire_frame,
+                                     payload_skip, trace=trace)
                 finally:
                     obs.span_end("stage_route_ms", tok)
-                if trace is not None:
-                    trace.stamp("route")
-                    self.broker.recorder.finish(trace)
+                self._route_finish(trace)
 
-            fut.add_done_callback(_done)
+            self.broker.batch_collector().submit(
+                mountpoint, words, trace, cont=_routed)
             return 0
         n = self._wire_route(mountpoint, words, topic_str, payload,
                              self.trie(mountpoint).match(list(words)),
-                             from_sid, wire_frame, payload_skip)
-        if trace is not None:
-            trace.stamp("route")
-            self.broker.recorder.finish(trace)
+                             from_sid, wire_frame, payload_skip,
+                             trace=trace)
+        self._route_finish(trace)
         return n
 
     def publish_wire(self, mountpoint: str, words: Tuple[str, ...],
                      topic_str: str, payload: bytes,
                      from_sid: Optional[SubscriberId], qos: int,
-                     trace=None) -> int:
+                     trace=None, *, done) -> Optional[int]:
         """The wire-plane QoS1/2 publish: like
         :meth:`publish_wire_qos0` but the fanout stamps each QoS≥1
         recipient's packet id into its in-flight window and
         batch-encodes all recipients' headers in ONE native call
-        (``fastpath.publish_headers_batch``). Synchronous only — the
-        session needs the match count for the PUBACK/PUBREC reason
-        code, so callers pre-gate ``batched_view_active()`` and keep
-        the classic async path there."""
+        (``fastpath.publish_headers_batch``). The session needs the
+        match count for the PUBACK/PUBREC reason code: the trie view
+        folds here and returns it; with the batched view active the
+        match rides the collector, None is returned, and
+        ``done(matches, exc)`` is called from the collector's release —
+        in submission order, inline, AFTER the route returned (every
+        recipient enqueued or written), ``exc`` being what the fold or
+        the route raised. No future, no task: the caller's reader goes
+        on to its next record meanwhile."""
+        if self.batched_view_active():
+            def _routed(rows, exc) -> None:
+                n = 0
+                if exc is None:
+                    tok = _route_begin(trace)
+                    try:
+                        n = self._wire_route(mountpoint, words, topic_str,
+                                             payload, rows, from_sid,
+                                             qos=qos, trace=trace)
+                    except Exception as e:
+                        exc = e
+                    finally:
+                        obs.span_end("stage_route_ms", tok)
+                if exc is None:
+                    self._route_finish(trace)
+                done(n, exc)
+
+            self.broker.batch_collector().submit(
+                mountpoint, words, trace, cont=_routed)
+            return None
         n = self._wire_route(mountpoint, words, topic_str, payload,
                              self.trie(mountpoint).match(list(words)),
-                             from_sid, qos=qos)
+                             from_sid, qos=qos, trace=trace)
+        self._route_finish(trace)
+        return n
+
+    def _route_finish(self, trace) -> None:
+        """A sampled publish's routing returned: the flight recorder's
+        ``route`` stamp and its record."""
         if trace is not None:
             trace.stamp("route")
             self.broker.recorder.finish(trace)
-        return n
 
     def _wire_route(self, mountpoint: str, words: Tuple[str, ...],
                     topic_str: str, payload: Optional[bytes], rows,
                     from_sid: Optional[SubscriberId],
                     wire_frame: Optional[bytes] = None,
-                    payload_skip: int = 0, qos: int = 0) -> int:
+                    payload_skip: int = 0, qos: int = 0,
+                    trace=None) -> int:
         """Classify the fold result: if EVERY matched row is the plain
         fast shape, write the shared wire bytes to each recipient's
         transport (verbatim inbound span for v4 QoS0 publishers, one
@@ -875,7 +897,7 @@ class Registry:
         msg = Msg(topic=tuple(words), payload=payload, qos=qos,
                   mountpoint=mountpoint)
         return self.route_rows(msg, self._filter_rows_host(msg, rows),
-                               from_sid)
+                               from_sid, trace=trace)
 
     def _wire_fanout(self, mountpoint: str, words: Tuple[str, ...],
                      topic_str: str, payload: Optional[bytes],

@@ -30,6 +30,10 @@ from .websocket import WsError
 log = logging.getLogger("vernemq_tpu.server")
 
 CONNECT_TIMEOUT = 10.0
+#: records one connection's reader handles before it yields to the loop,
+#: and the publishes it may have out with the collector (wire plane,
+#: batched view) before it waits for them to come back
+FRAME_RUN = 64
 MAX_FRAME_SIZE = 268435455
 
 
@@ -268,15 +272,36 @@ async def mqtt_connection(
                                 # QoS1/2, no retain, no dup: the dup
                                 # retransmit and retained forms keep
                                 # the classic path (dedup/store edges)
-                                if session.wire_publish_qos(buf, rec):
+                                if session.wire_inflight >= FRAME_RUN:
+                                    # under the batched view admitted
+                                    # publishes are out with the
+                                    # collector while the reader runs
+                                    # on: at the run bound it waits for
+                                    # them, then re-passes the gate
+                                    await session.wire_drain()
+                                    if session.closed:
+                                        break
+                                    fast_gate = session.wire_fast_ready()
+                                if fast_gate \
+                                        and session.wire_publish_qos(
+                                            buf, rec):
                                     fast_qpubs += 1
                                     handled = True
                             elif kind == fastpath.K_ACK:
-                                # always resolves (invalid pids count
+                                # resolves (invalid pids count
                                 # *_invalid_error exactly like classic)
-                                session.wire_ack(rec)
-                                handled = True
+                                # but for a PUBREL behind a publish
+                                # still with the collector
+                                handled = session.wire_ack(rec)
                         if not handled:
+                            if session.wire_inflight:
+                                # what the classic handler runs must
+                                # see every earlier publish routed and
+                                # acknowledged, as when the task
+                                # awaited each one
+                                await session.wire_drain()
+                                if session.closed:
+                                    break
                             try:
                                 frame = fastpath.materialize(
                                     codec, buf, rec, max_frame_size)
@@ -306,7 +331,7 @@ async def mqtt_connection(
                             fast_gate = (fast_gate
                                          and session.wire_fast_ready())
                         frames_run += 1
-                        if frames_run >= 64:
+                        if frames_run >= FRAME_RUN:
                             # bound the synchronous run per read chunk:
                             # a 64KB chunk can hold ~700 small
                             # PUBLISHes, and a handler that never truly

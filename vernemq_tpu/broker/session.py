@@ -24,6 +24,7 @@ connection task), with queue deliveries arriving as callbacks:
 from __future__ import annotations
 
 import asyncio
+import functools
 import logging
 import time
 from collections import OrderedDict
@@ -150,6 +151,13 @@ class Session:
         # telemetry stream repeating a handful of topics validates each
         # once and admits the rest with zero frame/Msg objects
         self._wire_topic_cache: Dict[bytes, Tuple[Tuple[str, ...], str]] = {}
+        # QoS1/2 publishes admitted under the batched view whose rows
+        # are still with the collector (wire_publish_qos): the reader
+        # runs on while they are out, so every record that is not a
+        # fast publish or a fast ack first waits for this to reach 0
+        # (wire_drain), as does the reader itself at its run bound
+        self.wire_inflight = 0
+        self._wire_drained: Optional[asyncio.Future] = None
 
     # ------------------------------------------------------------------ IO
 
@@ -521,6 +529,10 @@ class Session:
         # record with per-stage deltas (observability/recorder.py)
         trace = self.broker.recorder.admit(self.client_id or "",
                                            f.topic, f.qos)
+        if f.qos:
+            # the sibling of fastpath_pubs_qos: a QoS1/2 publish that
+            # the wire plane's gate left to this handler
+            fastpath.classic_pubs_qos += 1
         # NOTE max_message_size is enforced at the PARSER as a frame cap
         # for every packet type (vmq_parser.erl semantics; server.py
         # steady-state loop incrs mqtt_invalid_msg_size_error and sends
@@ -855,17 +867,16 @@ class Session:
         and the PUBACK/PUBREC reply is sent without materialising a
         Publish or Msg on the inbound side (the fanout builds ONE Msg
         lazily only for QoS≥1 recipients that must track it in
-        waiting_acks). Returns False when the frame needs the exact
-        classic path: receive-max exceeded, invalid topic/alias — each
-        raises or disconnects with the canonical reason there."""
+        waiting_acks). Under the trie view the route runs here; under
+        the batched view the publish is handed to the collector with a
+        continuation (``_wire_routed``) and the reader goes on: the
+        acknowledgement leaves from the continuation, after the route.
+        Returns False when the frame needs the exact classic path:
+        receive-max exceeded, invalid topic/alias — each raises or
+        disconnects with the canonical reason there."""
         _k, b0, pid, f_off, f_end, t_off, t_len, p_off = rec
         qos = (b0 >> 1) & 0x03
         b = self.broker
-        # QoS≥1 acks need the synchronous match count for the reason
-        # code; the batched (collector) view routes asynchronously, so
-        # the classic await path serves it
-        if b.registry.batched_view_active():
-            return False
         # v5 incoming flow control: at the announced receive maximum
         # the next QoS>0 publish is a protocol error — the classic
         # path serves the RECEIVE_MAX_EXCEEDED disconnect canonically
@@ -873,61 +884,109 @@ class Session:
                 and len(self.awaiting_rel) >= self._recv_max_announced
                 and not (qos == 2 and pid in self.awaiting_rel)):
             return False
+        dup_arrival = qos == 2 and pid in self.awaiting_rel
+        if dup_arrival and self.wire_inflight:
+            # the first arrival's PUBREC may not have left yet: the
+            # classic path waits for it (wire_drain), then dedups
+            return False
+        # the sampled admission is timed from here to the collector's
+        # submit (stage_pub_admit_ms); the topic is filled in below
+        trace = b.recorder.admit(self.client_id, "", qos)
         ent = self._wire_topic(buf, rec)
         if ent is None:
+            b.recorder.discard(trace)  # the classic handler admits it
             return False
         words, topic_str = ent
-        trace = b.recorder.admit(self.client_id, topic_str, qos)
         if trace is not None:
+            trace.info = (self.client_id, topic_str, qos)
             trace.stamp("admit")
-        if qos == 2 and pid in self.awaiting_rel:
+        if dup_arrival:
             # duplicate arrival of an unreleased pid: dedup (no
             # re-route), refresh the PUBREC (classic parity)
+            b.recorder.discard(trace)
             self.send(Pubrec(packet_id=pid))
             b.metrics.incr("mqtt_pubrec_sent")
             return True
         payload = bytes(buf[p_off:f_end])
         if qos == 2:
             self._qos2_hold(pid)
+        self.wire_inflight += 1
         try:
             matches = b.registry.publish_wire(
                 self.mountpoint, words, topic_str, payload, self.sid,
-                qos, trace=trace)
-        except RuntimeError as e:
-            b.metrics.incr("mqtt_publish_error")
-            if e.args != ("not_ready",):
-                log.exception("wire publish routing failed for %s",
-                              self.sid)
-            # withhold the ack so the client's DUP retry re-routes;
-            # the QoS2 receive credit must not leak meanwhile
-            if qos == 2:
-                self.awaiting_rel.pop(pid, None)
+                qos, trace=trace,
+                done=functools.partial(self._wire_routed, pid, qos))
+        except Exception as e:
+            self._wire_routed(pid, qos, 0, e)
             return True
-        except Exception:
-            b.metrics.incr("mqtt_publish_error")
-            log.exception("wire publish routing failed for %s", self.sid)
-            if qos == 2:
-                self.awaiting_rel.pop(pid, None)
-            return True
-        if qos == 1:
-            ack = Puback(packet_id=pid)
-            if self.proto_ver == PROTO_5 and not matches:
-                ack.reason_code = RC_NO_MATCHING_SUBSCRIBERS
-            self.send(ack)
-            b.metrics.incr("mqtt_puback_sent")
-        else:
-            self.send(Pubrec(packet_id=pid))
-            b.metrics.incr("mqtt_pubrec_sent")
+        if matches is not None:  # the trie view routed it here
+            self._wire_routed(pid, qos, matches, None)
         return True
 
-    def wire_ack(self, rec) -> None:
+    def _wire_routed(self, pid: int, qos: int, matches: int,
+                     exc: Optional[BaseException]) -> None:
+        """The route of a wire-admitted QoS1/2 publish returned (every
+        recipient enqueued or written) or failed: send the
+        acknowledgement — never before this point — and wake a reader
+        that waits for the drain. A session closed meanwhile has had
+        its publish routed all the same and gets no acknowledgement."""
+        b = self.broker
+        try:
+            if exc is not None:
+                b.metrics.incr("mqtt_publish_error")
+                if exc.args != ("not_ready",):
+                    log.error("wire publish routing failed for %s",
+                              self.sid, exc_info=exc)
+                # withhold the ack so the client's DUP retry re-routes;
+                # the QoS2 receive credit must not leak meanwhile
+                if qos == 2:
+                    self.awaiting_rel.pop(pid, None)
+            elif self.closed:
+                pass  # routed all the same; nobody is left to acknowledge
+            elif qos == 1:
+                ack = Puback(packet_id=pid)
+                if self.proto_ver == PROTO_5 and not matches:
+                    ack.reason_code = RC_NO_MATCHING_SUBSCRIBERS
+                self.send(ack)
+                b.metrics.incr("mqtt_puback_sent")
+            else:
+                self.send(Pubrec(packet_id=pid))
+                b.metrics.incr("mqtt_pubrec_sent")
+        finally:
+            # whatever the acknowledgement met, the reader must not
+            # wait for this publish any longer
+            self.wire_inflight -= 1
+            waiter = self._wire_drained
+            if waiter is not None and not self.wire_inflight:
+                self._wire_drained = None
+                if not waiter.done():  # the reader's task was cancelled
+                    waiter.set_result(None)
+
+    async def wire_drain(self) -> None:
+        """Wait until every wire-admitted publish of this session has
+        left the collector and been routed and acknowledged: what a
+        record behind them (SUBSCRIBE, DISCONNECT, PUBREL, a classic
+        publish) must see done first, as it did when the connection's
+        task awaited each publish."""
+        while self.wire_inflight:
+            if self._wire_drained is None:
+                self._wire_drained = \
+                    asyncio.get_event_loop().create_future()
+            await self._wire_drained
+
+    def wire_ack(self, rec) -> bool:
         """Resolve one 2-byte ack-family frame straight from the frame
         table: the pid checks against the waiting_acks / awaiting_rel
         bookkeeping with no frame object. The table only classifies
         the no-property rc=0 shape as K_ACK, so the v5 reason-code
-        forms stay on the classic codec path."""
+        forms stay on the classic codec path. False for the one ack
+        that must not overtake a publish still with the collector: a
+        PUBREL, whose PUBREC may not have left yet — the caller drains
+        first and the classic handler serves it."""
         ptype = rec[1] >> 4
         pid = rec[2]
+        if ptype == PUBREL_T and self.wire_inflight:
+            return False
         m = self.broker.metrics
         self.last_activity = time.monotonic()
         if ptype == PUBACK_T:
@@ -957,6 +1016,7 @@ class Session:
             m.incr("mqtt_pubcomp_received")
             self._ack_in(pid, "pubcomp", "mqtt_pubcomp_invalid_error")
         fastpath.fastpath_acks += 1
+        return True
 
     def wire_take_qos(self, msg: Msg) -> Optional[int]:
         """Register a wire-plane QoS≥1 delivery in the in-flight

@@ -1879,6 +1879,24 @@ class TpuRegView:
             m.close()
 
 
+class _Release:
+    """One submission in the collector's release queue: who gets the
+    rows — a future (an awaiting caller) or a continuation
+    ``cont(rows, exc)`` that ``_release`` calls inline — and, once
+    settled, the result held until every earlier submission left."""
+
+    __slots__ = ("fut", "cont", "trace", "ready", "res", "exc", "t")
+
+    def __init__(self, fut, cont, trace):
+        self.fut = fut
+        self.cont = cont
+        self.trace = trace  # the collector stamps its settling
+        self.ready = False
+        self.res = None
+        self.exc = None
+        self.t = 0.0  # settled at
+
+
 class BatchCollector:
     """Coalesce concurrent publishes into one device call.
 
@@ -1956,15 +1974,15 @@ class BatchCollector:
         self.rebuild_host_pubs = 0  # served by the trie during a rebuild
         self.busy_host_pubs = 0  # served by the trie past the lock bound
         self.degraded_host_pubs = 0  # trie-served while the breaker is open
-        self._pending: List[Tuple[str, Tuple[str, ...], asyncio.Future]] = []
+        self._pending: List[tuple] = []  # (mp, topic, _Release, exp, t_sub, trace, feat)
         self._flush_handle: Optional[asyncio.TimerHandle] = None
         self._inflight = 0
-        # submission-order release queue: a future's caller sees its
-        # result only after every EARLIER submission settled, so
-        # publish_nowait's routing callbacks fire in submission order —
-        # the per-publisher ordering contract (reg.py publish_nowait)
-        # holds even with two device batches racing in the pipeline or
-        # results coming from the host shed path
+        # submission-order release queue: a caller (a future's awaiter
+        # or a continuation) sees its result only after every EARLIER
+        # submission settled, so routing runs in submission order — the
+        # per-publisher ordering contract (reg.py publish_nowait,
+        # publish_wire) holds even with two device batches racing in
+        # the pipeline or results coming from the host shed path
         import collections as _collections
 
         self._order: "_collections.deque" = _collections.deque()
@@ -2029,78 +2047,80 @@ class BatchCollector:
         except Exception:
             return False
 
-    def _enqueue_fut(self, loop) -> asyncio.Future:
-        fut = loop.create_future()
-        fut._vmq_ready = False  # type: ignore[attr-defined]
-        fut._vmq_res = None  # type: ignore[attr-defined]
-        fut._vmq_exc = None  # type: ignore[attr-defined]
-        fut._vmq_t = 0.0  # type: ignore[attr-defined]  # settled at
-        fut._vmq_trace = None  # type: ignore[attr-defined]
-        self._order.append(fut)
-        return fut
-
-    #: futures released per loop callback. Releasing a future runs its
-    #: caller's routing (publish_nowait's done-callback): the whole
-    #: fanout of that publish, on the loop. A flush settles thousands at
-    #: once — released in one callback, a full window at a fanout of ~60
-    #: is seconds of routing in which no socket is read and no timer
-    #: fires (measured on the v5e at 1M subscriptions: 1-5 s, which the
-    #: overload governor answers by disconnecting the publishers).
+    #: submissions released per loop callback. Releasing one runs its
+    #: caller's routing (a continuation inline, an awaiting task on its
+    #: next step): the whole fanout of that publish, on the loop. A
+    #: flush settles thousands at once — released in one callback, a
+    #: full window at a fanout of ~60 is seconds of routing in which no
+    #: socket is read and no timer fires (measured on the v5e at 1M
+    #: subscriptions: 1-5 s, which the overload governor answers by
+    #: disconnecting the publishers).
     _RELEASE_CHUNK = 64
 
-    def _settle(self, fut, res=None, exc=None) -> None:
-        """Record a future's result. Settled futures are released to
+    def _settle(self, ent, res=None, exc=None) -> None:
+        """Record a submission's result. Settled entries are released to
         their callers in submission order, ``_RELEASE_CHUNK`` per loop
         callback (``_release``), so the delivery fan-out of a big flush
         yields to the loop's IO and timers between chunks."""
-        fut._vmq_ready = True
-        fut._vmq_res = res
-        fut._vmq_exc = exc
+        ent.ready = True
+        ent.res = res
+        ent.exc = exc
         if obs.enabled():
-            fut._vmq_t = time.monotonic()
-            if fut._vmq_trace is not None:
-                fut._vmq_trace.stamp("settle")
+            ent.t = time.monotonic()
+            if ent.trace is not None:
+                ent.trace.stamp("settle")
         if (not self._releasing and self._order
-                and self._order[0]._vmq_ready):
+                and self._order[0].ready):
             self._releasing = True
             asyncio.get_event_loop().call_soon(self._release)
 
     def _release(self) -> None:
         order = self._order
         budget = self._RELEASE_CHUNK
-        while order and order[0]._vmq_ready and budget:
-            f = order.popleft()
-            if f.done():  # cancelled by the caller
+        while order and order[0].ready and budget:
+            ent = order.popleft()
+            fut = ent.fut
+            if fut is not None and fut.done():  # cancelled by the caller
                 continue
-            if budget == self._RELEASE_CHUNK and f._vmq_t:
+            if budget == self._RELEASE_CHUNK and ent.t:
                 # how long this chunk's head stood settled while the
                 # chunks before it were released and routed: a wait, so
                 # a histogram family and no span
                 obs.observe("stage_release_wait_ms",
-                            (time.monotonic() - f._vmq_t) * 1e3)
+                            (time.monotonic() - ent.t) * 1e3)
             budget -= 1
-            if f._vmq_exc is not None:
-                f.set_exception(f._vmq_exc)
+            if fut is None:
+                # a continuation runs HERE, inline: the publish's route
+                # and its acknowledgement, no future and no task step.
+                # One that raises must not take the queue behind it down
+                try:
+                    ent.cont(ent.res, ent.exc)
+                except Exception:
+                    import logging
+
+                    logging.getLogger(__name__).exception(
+                        "collector continuation failed")
+            elif ent.exc is not None:
+                fut.set_exception(ent.exc)
             else:
-                f.set_result(f._vmq_res)
-            f._vmq_res = f._vmq_exc = None
-        if order and order[0]._vmq_ready:
+                fut.set_result(ent.res)
+        if order and order[0].ready:
             asyncio.get_event_loop().call_soon(self._release)
         else:
             self._releasing = False
 
-    def _settle_via_trie(self, mp: str, topic, fut,
+    def _settle_via_trie(self, mp: str, topic, ent,
                          fallback_exc: Optional[BaseException] = None,
                          feat=None) -> None:
         """Serve one publish from the host trie (the correctness oracle)
-        and settle its future; without a registry the original cause —
+        and settle its entry; without a registry the original cause —
         not a misleading AttributeError — reaches the caller. The
         payload-predicate phase applies here too (exact host evaluator):
         a shed/degraded publish must deliver the same filtered fanout
         as the device path."""
         reg = getattr(self.view, "registry", None)
         if reg is None:
-            self._settle(fut, exc=fallback_exc
+            self._settle(ent, exc=fallback_exc
                          or RuntimeError("no registry for trie fallback"))
             return
         try:
@@ -2108,13 +2128,21 @@ class BatchCollector:
             eng = self.filter_engine
             if eng is not None and eng.wants(mp):
                 rows = eng.filter_single(mp, topic, feat, list(rows))
-            self._settle(fut, res=rows)
+            self._settle(ent, res=rows)
         except Exception as e:
-            self._settle(fut, exc=e)
+            self._settle(ent, exc=e)
 
     def submit(self, mountpoint: str, topic: Sequence[str],
-               trace=None, feat=None) -> asyncio.Future:
-        """``trace`` — an optional flight-recorder PublishTrace
+               trace=None, feat=None,
+               cont=None) -> Optional[asyncio.Future]:
+        """Queue one publish for the next flush. The rows come back
+        through the future returned or, with ``cont``, through
+        ``cont(rows, exc)`` called inline by ``_release`` (no future is
+        made, None is returned): both forms leave in ONE submission
+        order, whichever path — device, trie shed, expiry — served
+        them.
+
+        ``trace`` — an optional flight-recorder PublishTrace
         (observability/recorder.py): the sampled-at-admission context
         rides the pending item into the flush, where the collector
         stamps dequeue/match/settle and, in worker mode, attaches the
@@ -2123,8 +2151,9 @@ class BatchCollector:
         encode) riding the same staging into the predicate phase; None
         for unfiltered mountpoints (zero-cost)."""
         loop = asyncio.get_event_loop()
-        fut = self._enqueue_fut(loop)
-        fut._vmq_trace = trace  # the collector stamps its settling
+        fut = loop.create_future() if cont is None else None
+        ent = _Release(fut, cont, trace)
+        self._order.append(ent)
         if (self._inflight >= self.MAX_INFLIGHT
                 and len(self._pending) >= self.max_batch
                 and len(self._pending) >= self.max_batch * (
@@ -2143,14 +2172,14 @@ class BatchCollector:
             # device needs to catch back up.
             if getattr(self.view, "registry", None) is not None:
                 self.overload_host_pubs += 1
-                self._settle_via_trie(mountpoint, topic, fut, feat=feat)
+                self._settle_via_trie(mountpoint, topic, ent, feat=feat)
                 return fut
         now_sub = time.monotonic()
         expiry = self._expiry_s()
         exp = now_sub + expiry if expiry > 0 else None
         if trace is not None:
             trace.stamp("submit")
-        self._pending.append((mountpoint, tuple(topic), fut, exp,
+        self._pending.append((mountpoint, tuple(topic), ent, exp,
                               now_sub, trace, feat))
         if exp is not None and self._expiry_handle is None:
             # expiry sweep: fires even when no flush can (both pipeline
@@ -2202,11 +2231,11 @@ class BatchCollector:
         settled = 0
         keep = []
         for item in self._pending:
-            mp, topic, fut, exp = item[:4]
+            mp, topic, ent, exp = item[:4]
             if (exp is not None and now >= exp
                     and settled < self._EXPIRE_CHUNK):
                 self.expired_host_pubs += 1
-                self._settle_via_trie(mp, topic, fut, feat=item[6])
+                self._settle_via_trie(mp, topic, ent, feat=item[6])
                 settled += 1
             else:
                 keep.append(item)
@@ -2225,8 +2254,8 @@ class BatchCollector:
         if len(self._pending) <= self.host_threshold and reg is not None:
             pending, self._pending = self._pending, []
             self.host_hybrid_pubs += len(pending)
-            for mp, topic, fut, _exp, _t_sub, _trace, feat in pending:
-                self._settle_via_trie(mp, topic, fut, feat=feat)
+            for mp, topic, ent, _exp, _t_sub, _trace, feat in pending:
+                self._settle_via_trie(mp, topic, ent, feat=feat)
             return
         if self._inflight >= self.MAX_INFLIGHT:
             # both slots busy: DON'T queue a third task — leave the
@@ -2283,17 +2312,17 @@ class BatchCollector:
         # the exact host trie instead of riding — and lengthening — a
         # device dispatch they already waited too long for
         now = time.monotonic()
-        by_mp: Dict[str, List[Tuple[Tuple[str, ...], asyncio.Future,
+        by_mp: Dict[str, List[Tuple[Tuple[str, ...], _Release,
                                     Any]]] = {}
         traces_mp: Dict[str, list] = {}
-        expired: List[Tuple[str, Tuple[str, ...], asyncio.Future,
+        expired: List[Tuple[str, Tuple[str, ...], _Release,
                             Any]] = []
         oldest_sub = None
-        for mp, topic, fut, exp, t_sub, trace, feat in pending:
+        for mp, topic, ent, exp, t_sub, trace, feat in pending:
             if exp is not None and now >= exp:
-                expired.append((mp, topic, fut, feat))
+                expired.append((mp, topic, ent, feat))
             else:
-                by_mp.setdefault(mp, []).append((topic, fut, feat))
+                by_mp.setdefault(mp, []).append((topic, ent, feat))
                 if oldest_sub is None or t_sub < oldest_sub:
                     oldest_sub = t_sub
                 if trace is not None:
@@ -2305,9 +2334,9 @@ class BatchCollector:
             # per dispatch keeps the seam cost flat at any batch size)
             obs.observe("stage_collector_wait_ms",
                         (now - oldest_sub) * 1e3)
-        for i, (mp, t_, fut, feat) in enumerate(expired):
+        for i, (mp, t_, ent, feat) in enumerate(expired):
             self.expired_host_pubs += 1
-            self._settle_via_trie(mp, t_, fut, feat=feat)
+            self._settle_via_trie(mp, t_, ent, feat=feat)
             if (i + 1) % 64 == 0:
                 await asyncio.sleep(0)
         for mp, items in by_mp.items():
@@ -2390,8 +2419,8 @@ class BatchCollector:
                      if hasattr(self.view, "matcher") else None)
                 if m is not None and hasattr(m, "record_stall"):
                     m.record_stall(sa)
-                for i, (t_, fut, feat) in enumerate(items):
-                    self._settle_via_trie(mp, t_, fut, fallback_exc=sa,
+                for i, (t_, ent, feat) in enumerate(items):
+                    self._settle_via_trie(mp, t_, ent, fallback_exc=sa,
                                           feat=feat)
                     if (i + 1) % 64 == 0:
                         await asyncio.sleep(0)
@@ -2430,15 +2459,15 @@ class BatchCollector:
                             m.ensure_warm(len(items))
                 else:
                     self.rebuild_host_pubs += len(items)
-                for i, (t_, fut, feat) in enumerate(items):
-                    self._settle_via_trie(mp, t_, fut, fallback_exc=rb,
+                for i, (t_, ent, feat) in enumerate(items):
+                    self._settle_via_trie(mp, t_, ent, fallback_exc=rb,
                                           feat=feat)
                     if (i + 1) % 64 == 0:
                         await asyncio.sleep(0)
                 continue
-            except Exception as e:  # settle futures with the error
-                for _, fut, _feat in items:
-                    self._settle(fut, exc=e)
+            except Exception as e:  # settle every entry with the error
+                for _, ent, _feat in items:
+                    self._settle(ent, exc=e)
                 continue
             # payload-predicate phase (vernemq_tpu/filters/): the second
             # device dispatch chained behind topic match — skipped at
@@ -2451,7 +2480,7 @@ class BatchCollector:
                 if not eng.wants(mp):
                     eng.note_skip()
                 else:
-                    tf = [(t, feat) for t, _fut, feat in items]
+                    tf = [(t, feat) for t, _ent, feat in items]
                     try:
                         if sacrificial:
                             results = await wd.dispatch_async(
@@ -2472,8 +2501,8 @@ class BatchCollector:
                     tr.stamp("match")
                     if meta_box:
                         tr.meta = meta_box
-            for (_, fut, _feat), rows in zip(items, results):
-                self._settle(fut, res=rows)
+            for (_, ent, _feat), rows in zip(items, results):
+                self._settle(ent, res=rows)
         # overload-signal EWMA: whole-flush service time (shed/degraded
         # paths included — a slow fallback is pressure too)
         from ..robustness.overload import fold_latency_ewma

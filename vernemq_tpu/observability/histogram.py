@@ -154,20 +154,25 @@ STAGE_FAMILIES: List[Tuple[str, str]] = [
      "ids to entry rows, up to the fold's return."),
     ("stage_release_wait_ms",
      "Collector release-queue wait per release chunk: from the "
-     "settling of a chunk's head future to its set_result (futures "
-     "are released in submission order, 64 per loop callback, each "
-     "waking a session that routes before the next chunk)."),
+     "settling of a chunk's head submission to its release "
+     "(submissions leave in submission order, 64 per loop callback: a "
+     "continuation routes and acknowledges inline, a future wakes "
+     "the session that awaits it)."),
     ("stage_route_ms",
-     "Publish routing per publish under the batched view: route_rows "
-     "after the collector's rows arrived (queue enqueue, session "
-     "deliver, PUBLISH encode and socket write of every recipient)."),
+     "Publish routing per publish under the batched view, after the "
+     "collector's rows arrived: the wire plane's fanout (in-flight "
+     "window entry, batched header encode, socket write) or "
+     "route_rows (queue enqueue, session deliver, PUBLISH encode and "
+     "socket write of every recipient)."),
     ("stage_ack_in_ms",
      "Inbound PUBACK/PUBCOMP handling per ack: in-flight window "
      "bookkeeping, pending pump and queue notify_ready."),
     ("stage_pub_admit_ms",
-     "Sampled publish admission: frame handling start to the "
-     "collector submit stamp (rate/governor gates, topic validation, "
-     "auth, pre-publish, collector submit; flight-recorder samples)."),
+     "Sampled publish admission: admission start to the collector "
+     "submit stamp (wire plane: receive-maximum gate, topic cache, "
+     "payload slice, submit; classic handler: rate/governor gates, "
+     "topic validation, auth, pre-publish, submit; flight-recorder "
+     "samples)."),
 ]
 
 _ENABLED = True

@@ -201,6 +201,13 @@ class FlightRecorder:
         self.sampled += 1
         return PublishTrace((client_id, topic, qos))
 
+    def discard(self, trace: Optional[PublishTrace]) -> None:
+        """An admission whose publish will not be routed from here
+        (handed on to another admission point, or a duplicate that is
+        only acknowledged): no record will come of it."""
+        if trace is not None:
+            self.sampled -= 1
+
     def resume(self, ctx: Dict[str, Any],
                origin: str) -> Optional[PublishTrace]:
         """Resume a trace whose sample decision was made on the ORIGIN

@@ -99,6 +99,7 @@ native_errors = 0       #: native calls that failed (fed the breaker)
 degraded_batches = 0    #: batches served pure while the breaker was open
 fastpath_pubs = 0       #: QoS0 publishes admitted object-free
 fastpath_pubs_qos = 0   #: QoS1/2 publishes admitted object-free
+classic_pubs_qos = 0    #: QoS1/2 publishes the gate left to the classic handler
 fastpath_acks = 0       #: ack frames resolved object-free
 fanout_batches = 0      #: batched fanout header encodes (one per fanout)
 
@@ -151,6 +152,7 @@ def stats():
         "wire_degraded_batches": float(degraded_batches),
         "wire_fastpath_pubs": float(fastpath_pubs),
         "wire_fastpath_pubs_qos": float(fastpath_pubs_qos),
+        "wire_classic_pubs_qos": float(classic_pubs_qos),
         "wire_fastpath_acks": float(fastpath_acks),
         "wire_fanout_batches": float(fanout_batches),
         "wire_breaker_state": float(breaker.state),
