@@ -49,10 +49,12 @@ class Raw:
         self.buf = b""
 
     @classmethod
-    async def connect(cls, port, client_id):
-        r, w = await asyncio.open_connection("127.0.0.1", port)
+    async def connect(cls, port, client_id, ssl=None, preamble=b""):
+        """``preamble``: what the listener reads before MQTT (a PROXY
+        header)."""
+        r, w = await asyncio.open_connection("127.0.0.1", port, ssl=ssl)
         self = cls(r, w)
-        await self.send(codec_v4.serialise(Connect(
+        await self.send(preamble + codec_v4.serialise(Connect(
             client_id=client_id, keepalive=0, clean_start=True)))
         await self.read_frames(1)  # CONNACK
         return self
@@ -164,14 +166,33 @@ async def test_qos0_fast_path_delivers_with_zero_frame_objects():
         await server.stop()
 
 
-async def _conversation(port):
+#: the two forms a connection is read by: the mqtt listener's
+#: protocol-level reader (``MqttProtocol``: fast records run off the
+#: task) and a ``read_chunk`` listener (PROXY protocol: the
+#: connection's task runs them) — one record function, two callers
+READ_FORMS = ["protocol", "read_chunk"]
+
+
+async def listen(broker, server, form):
+    """``(port, connect keywords)`` of a listener read by ``form``."""
+    if form == "protocol":
+        return server.port, {}
+    from vernemq_tpu.broker import proxy_proto
+
+    proxied = await broker.listeners.start_listener(
+        "mqtt", "127.0.0.1", 0, {"proxy_protocol": True})
+    return proxied.port, {"preamble": proxy_proto.build_v1(
+        ("192.0.2.7", 4321), ("10.0.0.1", 1883))}
+
+
+async def _conversation(port, **kw):
     """One scripted v4 conversation; returns (pub_stream, sub_stream)
     byte captures."""
-    sub = await Raw.connect(port, "csub")
+    sub = await Raw.connect(port, "csub", **kw)
     await sub.send(codec_v4.serialise(Subscribe(
         packet_id=1, topics=[("t/#", SubOpts(qos=1))])))
     await sub.read_frames(2)
-    pub = await Raw.connect(port, "cpub")
+    pub = await Raw.connect(port, "cpub", **kw)
     script = (
         codec_v4.serialise(Publish(topic="t/a", payload=b"one", qos=0))
         + codec_v4.serialise(Publish(topic="t/b", payload=b"two",
@@ -189,48 +210,60 @@ async def _conversation(port):
     return pub_bytes, sub_bytes
 
 
+async def _conversation_on(form, **cfg):
+    """``_conversation`` against a fresh broker's ``form`` listener."""
+    broker, server = await boot(**cfg)
+    try:
+        port, kw = await listen(broker, server, form)
+        return await _conversation(port, **kw)
+    finally:
+        await broker.stop()
+        await server.stop()
+
+
 @pytest.mark.asyncio
-async def test_wire_identical_with_native_forcibly_absent():
+@pytest.mark.parametrize("form", READ_FORMS)
+async def test_wire_identical_with_native_forcibly_absent(form):
     """The PR 7 byte-identity guarantee extended to the codec seam:
     the same conversation yields the identical byte streams whether the
     native codec serves or the pure-Python plane does (fast path ON in
     both — the table walk itself is bit-identical)."""
-    broker, server = await boot()
-    try:
-        native_run = await _conversation(server.port)
-    finally:
-        await broker.stop()
-        await server.stop()
+    native_run = await _conversation_on(form)
     with pure_mode():
-        broker, server = await boot()
-        try:
-            pure_run = await _conversation(server.port)
-        finally:
-            await broker.stop()
-            await server.stop()
+        pure_run = await _conversation_on(form)
     assert native_run == pure_run
 
 
 @pytest.mark.asyncio
-async def test_wire_identical_with_fastpath_disabled():
+@pytest.mark.parametrize("form", READ_FORMS)
+async def test_wire_identical_with_fastpath_disabled(form):
     """wire_fastpath_enabled=off (every frame through the classic
     handler) produces the same bytes as the fast path — and admits
     nothing through it."""
-    broker, server = await boot()
-    try:
-        fast_run = await _conversation(server.port)
-    finally:
-        await broker.stop()
-        await server.stop()
+    fast_run = await _conversation_on(form)
     base = fastpath.fastpath_pubs
-    broker, server = await boot(wire_fastpath_enabled=False)
-    try:
-        classic_run = await _conversation(server.port)
-        assert fastpath.fastpath_pubs == base  # nothing fast-admitted
-    finally:
-        await broker.stop()
-        await server.stop()
+    classic_run = await _conversation_on(form, wire_fastpath_enabled=False)
+    assert fastpath.fastpath_pubs == base  # nothing fast-admitted
     assert fast_run == classic_run
+
+
+@pytest.mark.asyncio
+async def test_wire_identical_across_read_forms():
+    """The record function's two callers write the same bytes: the
+    conversation read by the protocol (its fast records run off the
+    task) and read through a ``read_chunk`` by the task."""
+    def chunks():
+        return fastpath.inline_chunks + fastpath.task_chunks
+
+    base = chunks()
+    by_protocol = await _conversation_on("protocol")
+    # the script's chunk: three publishes served by the protocol, the
+    # PINGREQ behind them handed to the task
+    assert chunks() > base
+    base = chunks()
+    by_task = await _conversation_on("read_chunk")
+    assert chunks() == base  # that form has no inline run to count
+    assert by_protocol == by_task
 
 
 @pytest.mark.asyncio
@@ -395,6 +428,8 @@ async def test_wire_metrics_and_stage_families_exposed():
         assert "# HELP stage_wire_encode_ms " in text
         assert "# HELP wire_fastpath_pubs " in text
         assert "# HELP wire_native_batches " in text
+        assert "# HELP wire_inline_chunks " in text
+        assert "# HELP wire_task_chunks " in text
         snap = broker.metrics.histogram_snapshot()
         assert snap["stage_wire_parse_ms"][2] > 0  # observations landed
         assert snap["stage_wire_encode_ms"][2] > 0
@@ -795,6 +830,8 @@ async def test_batched_qos1_builds_no_frame_no_msg_no_future():
         stats = broker.registry.stats()
         assert stats["wire_fastpath_pubs_qos"] >= n
         assert "wire_classic_pubs_qos" in stats
+        assert "wire_inline_chunks" in stats
+        assert stats["wire_task_chunks"] >= 1  # the run bound's remainder
         sub.close()
         pub.close()
     finally:
@@ -852,17 +889,19 @@ async def test_batched_puback_leaves_after_the_route():
 
 
 @pytest.mark.asyncio
-async def test_batched_puback_order_is_arrival_order():
+@pytest.mark.parametrize("form", READ_FORMS)
+async def test_batched_puback_order_is_arrival_order(form):
     """Two device flushes with a trie-served one between them, the
     first held until the others have settled: the PUBACKs still leave
     in the order the publishes arrived, and so do the deliveries."""
     broker, server = await boot_batched(tpu_host_batch_threshold=2)
     try:
-        sub = await Raw.connect(server.port, "orsub")
+        port, kw = await listen(broker, server, form)
+        sub = await Raw.connect(port, "orsub", **kw)
         await sub.send(codec_v4.serialise(Subscribe(
             packet_id=1, topics=[("q/#", SubOpts(qos=0))])))
         await sub.read_frames(2)
-        pub = await Raw.connect(server.port, "orpub")
+        pub = await Raw.connect(port, "orpub", **kw)
         held = HeldFold(broker)
         col = broker.batch_collector()
         await pub.send(b"".join(q_publish(i) for i in range(5)))
@@ -1084,6 +1123,392 @@ async def test_batched_closed_session_is_routed_not_acknowledged():
         await until(lambda: psess.wire_inflight == 0)
         assert broker.metrics.value("mqtt_puback_sent") == acked
         sub.close()
+    finally:
+        await broker.stop()
+        await server.stop()
+
+
+# ------------------------------------------------------------------
+# The protocol-level reader (broker/server.py:MqttProtocol): while the
+# connection's task is parked at its steady-state read, the protocol
+# runs a chunk's fast records itself (listed by data_received, served by
+# the listener's per-turn callback); the first record it cannot serve
+# goes to the task with every byte behind it.
+
+
+def proto_of(broker, client_id):
+    """The MqttProtocol of ``client_id``'s connection."""
+    return session_of(broker, client_id).transport._transport.get_protocol()
+
+
+async def parked(proto):
+    """The connection's task is at its steady-state read; returns the
+    future it waits on (still pending later = the task took no step)."""
+    await until(lambda: proto._session is not None)
+    return proto._waiter
+
+
+def chunk_counts():
+    return fastpath.inline_chunks, fastpath.task_chunks
+
+
+@contextlib.contextmanager
+def counted_runs():
+    """The records each ``wire_run`` call served, in call order."""
+    from vernemq_tpu.broker import server as server_mod
+
+    runs = []
+    orig = server_mod.wire_run
+
+    def counting_run(session, buf, table, off, end, budget):
+        ran = orig(session, buf, table, off, end, budget)
+        runs.append((ran - off) // fastpath.REC_SIZE)
+        return ran
+
+    server_mod.wire_run = counting_run
+    try:
+        yield runs
+    finally:
+        server_mod.wire_run = orig
+
+
+@pytest.mark.asyncio
+async def test_inline_chunk_takes_no_task_step_and_acks_after_the_route():
+    """(a) A chunk of one QoS1 PUBLISH, and the subscriber's PUBACK of
+    its delivery: each counted in inline_chunks, none in task_chunks,
+    neither connection's task woken — and the publisher's PUBACK still
+    leaves only after the route (the fold is held meanwhile)."""
+    broker, server = await boot_batched()
+    try:
+        sub = await Raw.connect(server.port, "insub")
+        await sub.send(codec_v4.serialise(Subscribe(
+            packet_id=1, topics=[("q/#", SubOpts(qos=1))])))
+        await sub.read_frames(2)
+        pub = await Raw.connect(server.port, "inpub")
+        pproto, sproto = proto_of(broker, "inpub"), proto_of(broker, "insub")
+        pwait, swait = await parked(pproto), await parked(sproto)
+        held = HeldFold(broker)
+        inline, task = chunk_counts()
+        await pub.send(q_publish(0))
+        await until(lambda: held.calls)
+        assert await quiet(pub) and await quiet(sub, 0.05)
+        assert chunk_counts() == (inline + 1, task)
+        assert session_of(broker, "inpub").wire_inflight == 1
+        held.release()
+        delivered = (await sub.read_frames(3))[2]
+        assert delivered.payload == b"q0000" and delivered.qos == 1
+        ack = (await pub.read_frames(2))[1]
+        assert type(ack).__name__ == "Puback" and ack.packet_id == 1
+        ssess = session_of(broker, "insub")
+        assert len(ssess.waiting_acks) == 1
+        await sub.send(b"\x40\x02" + delivered.packet_id.to_bytes(2, "big"))
+        await until(lambda: not ssess.waiting_acks)
+        assert chunk_counts() == (inline + 2, task)
+        # both tasks still wait on the future they parked with
+        assert pproto._waiter is pwait and not pwait.done()
+        assert sproto._waiter is swait and not swait.done()
+        sub.close()
+        pub.close()
+    finally:
+        await broker.stop()
+        await server.stop()
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("view", ["trie", "batched"])
+async def test_inline_run_splits_the_chunk_at_a_classic_record(view):
+    """(b) Fast records, a SUBSCRIBE, fast records again, ONE chunk:
+    the protocol serves the first stretch, the task the rest; every
+    PUBACK of the earlier publishes is on the wire before the SUBACK,
+    the later ones after it."""
+    broker, server = await (boot_batched() if view == "batched" else boot())
+    try:
+        pub = await Raw.connect(server.port, "sppub")
+        proto = proto_of(broker, "sppub")
+        await parked(proto)
+        inline, task = chunk_counts()
+        with counted_runs() as runs:
+            await pub.send(
+                b"".join(q_publish(i) for i in range(3))
+                + codec_v4.serialise(Subscribe(
+                    packet_id=9, topics=[("q/#", SubOpts(qos=0))]))
+                + b"".join(q_publish(i) for i in range(3, 5)))
+            # CONNACK, 3 PUBACKs, SUBACK, 2 PUBACKs, 2 own deliveries
+            frames = (await pub.read_frames(1 + 3 + 1 + 2 + 2))[1:]
+        names = [type(f).__name__ for f in frames]
+        assert names[:4] == ["Puback", "Puback", "Puback", "Suback"]
+        assert [f.packet_id for f in frames[:3]] == [1, 2, 3]
+        assert sorted(f.packet_id for f in frames[4:]
+                      if type(f).__name__ == "Puback") == [4, 5]
+        # the subscription saw only what came after it
+        assert [f.payload for f in frames[4:]
+                if type(f).__name__ == "Publish"] == [b"q0003", b"q0004"]
+        assert runs[0] == 3 and sum(runs) == 5  # inline run, then the task's
+        assert chunk_counts() == (inline, task + 1)
+        pub.close()
+    finally:
+        await broker.stop()
+        await server.stop()
+
+
+@pytest.mark.asyncio
+async def test_inline_frame_cut_across_chunks_and_at_eof():
+    """(c) A frame cut across two chunks waits in the protocol's buffer
+    (no task step for either half); a chunk that ends mid-frame at EOF
+    closes the connection as a clean EOF always did."""
+    broker, server = await boot()
+    try:
+        pub = await Raw.connect(server.port, "cutpub")
+        proto = proto_of(broker, "cutpub")
+        waiter = await parked(proto)
+        frame = q_publish(0)
+        inline, task = chunk_counts()
+        await pub.send(frame[:5])
+        await until(lambda: proto._tail == frame[:5])
+        assert await quiet(pub, 0.1)
+        await pub.send(frame[5:])
+        ack = (await pub.read_frames(2))[1]
+        assert type(ack).__name__ == "Puback" and ack.packet_id == 1
+        assert chunk_counts() == (inline + 2, task)
+        assert proto._tail == b"" and proto._waiter is waiter
+        errors = broker.metrics.value("socket_error")
+        closes = broker.metrics.value("socket_close")
+        psess = session_of(broker, "cutpub")
+        await pub.send(q_publish(1) + q_publish(2)[:7])
+        pub.writer.write_eof()
+        await until(lambda: psess.closed)
+        await until(
+            lambda: broker.metrics.value("socket_close") == closes + 1)
+        assert broker.metrics.value("socket_error") == errors
+        assert psess.close_reason == "connection_lost"
+        # the whole frame before the cut was served and acknowledged
+        assert (await pub.read_frames(3))[2].packet_id == 2
+        pub.close()
+    finally:
+        await broker.stop()
+        await server.stop()
+
+
+@pytest.mark.asyncio
+async def test_inline_run_stops_at_the_run_bound():
+    """(d) 200 QoS0 publishes in one chunk: the protocol serves
+    FRAME_RUN of them, the task the rest in runs no longer than that;
+    all 200 arrive, in order."""
+    from vernemq_tpu.broker import server as server_mod
+
+    broker, server = await boot()
+    try:
+        sub = await Raw.connect(server.port, "rbsub")
+        await sub.send(codec_v4.serialise(Subscribe(
+            packet_id=1, topics=[("t/#", SubOpts(qos=0))])))
+        await sub.read_frames(2)
+        pub = await Raw.connect(server.port, "rbpub")
+        await parked(proto_of(broker, "rbpub"))
+        n = 200
+        blob = b"".join(
+            codec_v4.serialise(Publish(topic="t/x", payload=b"p%04d" % i,
+                                       qos=0)) for i in range(n))
+        task = fastpath.task_chunks
+        fast = fastpath.fastpath_pubs
+        with counted_runs() as runs:
+            await pub.send(blob)
+            frames = (await sub.read_frames(2 + n))[2:]
+        assert [f.payload for f in frames] == [b"p%04d" % i for i in range(n)]
+        assert fastpath.fastpath_pubs - fast == n
+        # the 2.6 KB blob is one recv chunk on loopback: the protocol's
+        # run is the first, the task's follow, each bounded
+        assert runs[0] == min(server_mod.FRAME_RUN, n)
+        assert max(runs) <= server_mod.FRAME_RUN and sum(runs) == n
+        assert fastpath.task_chunks > task  # the remainder went to the task
+        sub.close()
+        pub.close()
+    finally:
+        await broker.stop()
+        await server.stop()
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("edge", ["tracer", "governor"])
+async def test_inline_run_stays_off_behind_a_closed_gate(edge):
+    """(e) Gate closed — a tracer, the governor at level 1 — and
+    the protocol serves nothing: every chunk goes to the task, and the
+    conversation's bytes are those of the open gate."""
+    open_gate = await _conversation_on("protocol")
+    broker, server = await boot()
+    try:
+        if edge == "tracer":
+            broker.start_trace("nobody-by-this-name")
+        else:
+            broker.overload.pin(1)
+        inline, task = chunk_counts()
+        closed_gate = await _conversation(server.port)
+        assert fastpath.inline_chunks == inline
+        assert fastpath.task_chunks > task
+        if edge == "governor":
+            broker.overload.pin(None)
+    finally:
+        await broker.stop()
+        await server.stop()
+    assert closed_gate == open_gate
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("form", READ_FORMS)
+async def test_malformed_frame_after_admitted_publishes(form):
+    """(f) Three publishes and then a frame no codec accepts, one chunk:
+    the publishes are delivered and booked, the connection is closed
+    with socket_error — the same by either read form."""
+    broker, server = await boot()
+    try:
+        port, kw = await listen(broker, server, form)
+        sub = await Raw.connect(port, "mfsub", **kw)
+        await sub.send(codec_v4.serialise(Subscribe(
+            packet_id=1, topics=[("t/#", SubOpts(qos=0))])))
+        await sub.read_frames(2)
+        pub = await Raw.connect(port, "mfpub", **kw)
+        psess = session_of(broker, "mfpub")
+        m = broker.metrics
+        errors, received, closes = (m.value("socket_error"),
+                                    m.value("mqtt_publish_received"),
+                                    m.value("socket_close"))
+        await pub.send(b"".join(
+            codec_v4.serialise(Publish(topic="t/x", payload=b"m%d" % i,
+                                       qos=0)) for i in range(3))
+            + b"\xf0\x00")  # reserved packet type 15
+        await until(lambda: psess.closed)
+        await until(lambda: m.value("socket_close") == closes + 1)
+        assert m.value("socket_error") == errors + 1
+        assert m.value("mqtt_publish_received") == received + 3
+        frames = (await sub.read_frames(2 + 3))[2:]
+        assert [f.payload for f in frames] == [b"m0", b"m1", b"m2"]
+        assert await pub.reader.read(65536) == b""  # closed by the broker
+        sub.close()
+        pub.close()
+    finally:
+        await broker.stop()
+        await server.stop()
+
+
+@pytest.mark.asyncio
+async def test_reading_pauses_while_the_task_is_busy_and_resumes():
+    """(g) While the task waits (a SUBSCRIBE behind a publish whose fold
+    is held) data_received only appends, and past READ_HIGH it pauses
+    the socket's reading; the task's next read resumes it and nothing
+    is lost or reordered."""
+    from vernemq_tpu.broker.server import READ_HIGH
+
+    broker, server = await boot_batched()
+    try:
+        pub = await Raw.connect(server.port, "pzpub")
+        proto = proto_of(broker, "pzpub")
+        await parked(proto)
+        held = HeldFold(broker)
+        await pub.send(q_publish(0) + codec_v4.serialise(Subscribe(
+            packet_id=9, topics=[("big/#", SubOpts(qos=0))])))
+        await until(lambda: held.calls)
+        assert proto._session is None  # the task is in wire_drain
+        n, size = 24, 16 * 1024
+        inline = fastpath.inline_chunks
+        pub.writer.write(b"".join(
+            codec_v4.serialise(Publish(topic="big/x", qos=0,
+                                       payload=bytes([65 + i]) * size))
+            for i in range(n)))
+        await until(lambda: proto._paused)
+        assert len(proto._buf) > READ_HIGH
+        held_bytes = len(proto._buf)
+        await asyncio.sleep(0.1)
+        assert len(proto._buf) == held_bytes  # nothing read while paused
+        assert fastpath.inline_chunks == inline
+        held.release()
+        frames = (await pub.read_frames(1 + 2 + n, timeout=20.0))[1:]
+        assert [type(f).__name__ for f in frames[:2]] == ["Puback", "Suback"]
+        assert [f.payload[:1] for f in frames[2:]] == \
+            [bytes([65 + i]) for i in range(n)]
+        assert all(len(f.payload) == size for f in frames[2:])
+        await until(lambda: proto._session is not None)
+        assert not proto._paused and proto._buf == b""
+        pub.close()
+    finally:
+        await broker.stop()
+        await server.stop()
+
+
+@pytest.mark.asyncio
+async def test_mqtts_listener_is_byte_identical():
+    """(h) The same conversation over an mqtts listener (the protocol
+    under asyncio's TLS transport) reads and writes the plain
+    listener's bytes, its fast records run off the task too."""
+    import os
+    import ssl
+
+    ssl_dir = os.path.join(os.path.dirname(__file__), "ssl")
+    plain = await _conversation_on("protocol")
+    broker, server = await boot()
+    try:
+        tls = await broker.listeners.start_listener(
+            "mqtts", "127.0.0.1", 0, {
+                "certfile": os.path.join(ssl_dir, "server.crt"),
+                "keyfile": os.path.join(ssl_dir, "server.key")})
+        ctx = ssl.SSLContext(ssl.PROTOCOL_TLS_CLIENT)
+        ctx.load_verify_locations(os.path.join(ssl_dir, "ca.crt"))
+        ctx.check_hostname = False
+        task = fastpath.task_chunks
+        over_tls = await _conversation(tls.port, ssl=ctx)
+        assert fastpath.task_chunks > task  # three publishes, a PINGREQ
+        await until(lambda: tls.connection_count == 0)
+    finally:
+        await broker.stop()
+        await server.stop()
+    assert over_tls == plain
+
+
+@pytest.mark.asyncio
+async def test_a_turns_chunks_are_served_together_by_one_callback():
+    """The reads of a loop turn only list their connections; the
+    listener's one callback then serves all the chunks, in arrival
+    order, ahead of anything the next turn brings — and a connection
+    that saw EOF in the same turn keeps its chunk for its task."""
+    broker, server = await boot()
+    try:
+        sub = await Raw.connect(server.port, "tpsub")
+        await sub.send(codec_v4.serialise(Subscribe(
+            packet_id=1, topics=[("q/#", SubOpts(qos=0))])))
+        await sub.read_frames(2)
+        pubs = [await Raw.connect(server.port, "tppub%d" % i)
+                for i in range(4)]
+        protos = [proto_of(broker, "tppub%d" % i) for i in range(4)]
+        for p in protos:
+            await parked(p)
+        last = session_of(broker, "tppub3")
+        calls = []
+        serve_inbox = server._serve_inbox
+        server._serve_inbox = lambda: (calls.append(len(server._inbox)),
+                                       serve_inbox())
+        inline, task = chunk_counts()
+        fast = fastpath.fastpath_pubs_qos
+        # one turn's reads, as the selector would deliver them
+        for i, p in enumerate(protos):
+            p.data_received(q_publish(i))
+        protos[3].eof_received()
+        assert server._inbox == protos and calls == []
+        assert fastpath.fastpath_pubs_qos == fast  # nothing served yet
+        await asyncio.sleep(0)
+        assert calls == [4] and server._inbox == []
+        # three by the callback; the fourth's task may have run as well
+        assert fastpath.fastpath_pubs_qos >= fast + 3
+        assert chunk_counts() == (inline + 3, task)
+        frames = (await sub.read_frames(2 + 4))[2:]
+        assert [f.payload for f in frames[:3]] == \
+            [b"q%04d" % i for i in range(3)]
+        # the fourth went to its task with the EOF behind it: served,
+        # acknowledged, then closed
+        assert frames[3].payload == b"q0003"
+        ack = (await pubs[3].read_frames(2))[1]
+        assert type(ack).__name__ == "Puback" and ack.packet_id == 4
+        await until(lambda: last.closed)
+        del server._serve_inbox
+        for r in pubs + [sub]:
+            r.close()
     finally:
         await broker.stop()
         await server.stop()

@@ -396,6 +396,20 @@ class Broker:
                                   "PUBREL/PUBCOMP) resolved straight "
                                   "from the frame table with no frame "
                                   "object.",
+            "wire_inline_chunks": "Recv chunks of a protocol-level "
+                                  "(mqtt/mqtts) listener that held only "
+                                  "wire-plane records and were parsed, "
+                                  "admitted and acknowledged by the "
+                                  "connection's protocol (the listener's "
+                                  "per-turn callback): no stream reader, "
+                                  "no task step.",
+            "wire_task_chunks": "Recv chunks, or the remainder of one "
+                                "from its first record on, that such a "
+                                "listener handed to the connection's "
+                                "parked task: gate closed, a classic "
+                                "record, a run bound. With "
+                                "wire_inline_chunks, how often the "
+                                "inline run engages.",
             "wire_fanout_batches": "One-call batched fanout header "
                                    "encodes (publish_headers_batch): "
                                    "each emitted N per-recipient "

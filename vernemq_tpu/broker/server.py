@@ -6,6 +6,22 @@ of incoming bytes driving the session FSM (``vmq_ranch.erl:167-251``),
 write coalescing per event-loop tick (the MSS flush-threshold batching of
 ``vmq_ranch.erl:253-262``), and protocol detection on the first CONNECT
 frame choosing the v4 or v5 FSM (``vmq_mqtt_pre_init.erl:58-70``).
+
+What runs where. An ``mqtt`` / ``mqtts`` listener reads its sockets at
+the protocol level (``MqttProtocol``): the connection's task runs
+everything that awaits — the CONNECT / enhanced-AUTH exchange, every
+classic frame (``Session.handle_frame``), the waits at the run bounds,
+the close — and, while it is parked at its steady-state read, the
+protocol runs a chunk's wire-plane records (admitted PUBLISHes, the
+2-byte ack family) itself: no stream reader, no future, no task step
+for a chunk that holds nothing else. In two phases: ``data_received``
+only notes the chunk, so a loop turn's socket reads run back to back,
+and ONE callback of the listener (``MQTTServer._serve_inbox``), first
+in the next turn, serves all of them. The first record it cannot serve
+goes to the task with every byte behind it. WebSocket and
+PROXY-protocol listeners need a reader of their own for their first
+bytes and hand the task a ``read_chunk``; both forms walk a frame
+table's fast stretch through the one ``wire_run``.
 """
 
 from __future__ import annotations
@@ -38,7 +54,7 @@ MAX_FRAME_SIZE = 268435455
 
 
 class StreamTransport(Transport):
-    """Write-coalescing wrapper over an asyncio StreamWriter: session
+    """Write-coalescing wrapper over an asyncio transport: session
     writes within one loop tick collect into ONE iovec (a chunk list)
     that the flush hands to ``writelines`` — one C-level join + one
     syscall-bound send per loop iteration, however many small
@@ -50,8 +66,8 @@ class StreamTransport(Transport):
     swap-not-copy behaviour whether or not the native encoder is
     present."""
 
-    def __init__(self, writer: asyncio.StreamWriter):
-        self._writer = writer
+    def __init__(self, transport: asyncio.WriteTransport):
+        self._transport = transport
         self._chunks: list = []
         self._flush_scheduled = False
         self.closed = False
@@ -82,9 +98,9 @@ class StreamTransport(Transport):
         chunks, self._chunks = self._chunks, []
         try:
             if len(chunks) == 1:
-                self._writer.write(chunks[0])
+                self._transport.write(chunks[0])
             else:
-                self._writer.writelines(chunks)
+                self._transport.writelines(chunks)
         except Exception:
             self.closed = True
 
@@ -94,7 +110,7 @@ class StreamTransport(Transport):
         self._flush()
         self.closed = True
         try:
-            self._writer.close()
+            self._transport.close()
         except Exception:
             pass
 
@@ -110,8 +126,8 @@ def parse_nodelay_option(raw: str) -> Optional[bool]:
     return "{nodelay,true}" in raw.replace(" ", "")
 
 
-def _apply_nodelay(writer: asyncio.StreamWriter, want: bool) -> None:
-    sock = writer.get_extra_info("socket")
+def _apply_nodelay(transport: asyncio.BaseTransport, want: bool) -> None:
+    sock = transport.get_extra_info("socket")
     if sock is not None:
         import socket as _socket
 
@@ -131,6 +147,58 @@ def sniff_proto_ver(body: bytes) -> int:
     return body[pos] & 0x7F
 
 
+REC_SIZE = fastpath.REC_SIZE
+_unpack_rec = fastpath.REC.unpack_from
+#: first bytes of the QoS1/2 PUBLISH the wire plane admits: no retain, no
+#: dup — the dup retransmit and retained forms keep the classic path
+#: (dedup/store edges)
+_FAST_QOS_FLAGS = (0x32, 0x34)
+
+
+def wire_run(session: Session, buf, table, off: int, end: int,
+             budget: int) -> int:
+    """The wire plane's record loop: serve the records of the frame
+    ``table`` over ``buf`` from ``off`` on, at most ``budget`` of them,
+    for as long as each is one the session takes straight from the
+    table — a plain QoS0 PUBLISH, a QoS1/2 PUBLISH with fewer than
+    ``FRAME_RUN`` already out with the collector, a 2-byte ack — and
+    book what was admitted (``wire_fast_done``, also when a record
+    raises). Returns the offset of the first record NOT served (``end``
+    if none is left): another kind, one the session declined, or the
+    bound. Never awaits and passes no gate: the caller has seen
+    ``wire_fast_ready()`` — the connection's task between classic
+    records, ``MqttProtocol._serve`` while that task is parked."""
+    stop = min(end, off + budget * REC_SIZE)
+    pubs = qpubs = 0
+    try:
+        while off < stop:
+            rec = _unpack_rec(table, off)
+            kind = rec[0]
+            if kind == fastpath.K_PUB0:
+                if rec[1] != 0x30 \
+                        or not session.wire_publish_qos0(buf, rec):
+                    break
+                pubs += 1
+            elif kind == fastpath.K_PUB:
+                if rec[1] not in _FAST_QOS_FLAGS \
+                        or session.wire_inflight >= FRAME_RUN \
+                        or not session.wire_publish_qos(buf, rec):
+                    break
+                qpubs += 1
+            # an ack resolves (invalid pids count *_invalid_error
+            # exactly like classic) but for a PUBREL behind a publish
+            # still with the collector
+            elif kind != fastpath.K_ACK or not session.wire_ack(rec):
+                break
+            off += REC_SIZE
+    finally:
+        # a mid-run error must not lose the bookkeeping for fast-path
+        # messages already routed and delivered
+        if pubs or qpubs:
+            session.wire_fast_done(pubs, qpubs)
+    return off
+
+
 async def mqtt_connection(
     broker: Broker,
     read_chunk,
@@ -146,7 +214,11 @@ async def mqtt_connection(
     is an awaitable returning the next bytes (b"" on EOF), ``transport``
     writes outbound frames. TCP, TLS, WebSocket and PROXY-wrapped listeners
     all drive their sockets through this one loop (the reference funnels all
-    transports into the same FSM contract, vmq_ranch.erl:167-251).
+    transports into the same FSM contract, vmq_ranch.erl:167-251). A
+    ``read_chunk`` that is the connection's ``MqttProtocol`` is that
+    awaitable and more: at the steady-state read this task parks in it
+    (``MqttProtocol.park``) and the protocol runs wire-plane records where
+    their bytes arrive.
     ``preauth_user`` overrides the CONNECT username (TLS client-cert CN or
     PROXY identity, vmq_ranch.erl:59-72); ``mountpoint`` is the listener's
     multitenancy prefix (per-listener mountpoint config)."""
@@ -154,6 +226,8 @@ async def mqtt_connection(
     metrics.incr("socket_open")
     session: Optional[Session] = None
     buf = initial
+    # a protocol-level reader counts its bytes where they arrive
+    inline = read_chunk if isinstance(read_chunk, MqttProtocol) else None
     try:
         # ---- pre-init: wait for CONNECT, pick protocol ----------------
         first = wire.split_frame(buf, max_frame_size) if buf else None
@@ -167,7 +241,8 @@ async def mqtt_connection(
                 chunk = await read_chunk()
                 if not chunk:
                     return None
-                metrics.incr("bytes_received", len(chunk))
+                if inline is None:
+                    metrics.incr("bytes_received", len(chunk))
                 buf += chunk
                 f = wire.split_frame(buf, max_frame_size)
             return f
@@ -234,17 +309,16 @@ async def mqtt_connection(
         # codec when built, bit-identical pure-Python twin otherwise).
         # Admitted PUBLISHes — QoS0 AND QoS1/2 — flow from the table
         # straight into the routing fanout without materialising
-        # frame/Msg objects (session.wire_publish_qos0/_qos), and the
-        # 2-byte ack family resolves its pid against the in-flight
-        # bookkeeping the same way (session.wire_ack); every other
+        # frame/Msg objects, and the 2-byte ack family resolves its pid
+        # against the in-flight bookkeeping the same way: ``wire_run``,
+        # synchronous, which a protocol-level listener also calls from
+        # ``data_received`` while this task is parked. Every other
         # record — reason-code acks, retained/dup publishes, protocol
         # edges, malformed input — materialises its frame object and
-        # takes the classic handler unchanged.
+        # takes the classic handler here, unchanged.
         buf = bytes(rest)
         frames_run = 0
         v5 = codec is codec_v5
-        rec_size = fastpath.REC_SIZE
-        unpack_rec = fastpath.REC.unpack_from
         while not session.closed:
             if buf:
                 tok = obs.span_begin("stage_wire_parse_ms")
@@ -253,110 +327,93 @@ async def mqtt_connection(
                         buf, max_frame_size, v5)
                 finally:
                     obs.span_end("stage_wire_parse_ms", tok)
+                end = nrec * REC_SIZE
+                off = 0
                 fast_gate = nrec > 0 and session.wire_fast_ready()
-                fast_pubs = 0
-                fast_qpubs = 0
-                try:
-                    for off in range(0, nrec * rec_size, rec_size):
-                        rec = unpack_rec(table, off)
-                        handled = False
-                        if fast_gate:
-                            kind = rec[0]
-                            if kind == fastpath.K_PUB0 \
-                                    and rec[1] == 0x30:
-                                if session.wire_publish_qos0(buf, rec):
-                                    fast_pubs += 1
-                                    handled = True
-                            elif kind == fastpath.K_PUB \
-                                    and rec[1] in (0x32, 0x34):
-                                # QoS1/2, no retain, no dup: the dup
-                                # retransmit and retained forms keep
-                                # the classic path (dedup/store edges)
-                                if session.wire_inflight >= FRAME_RUN:
-                                    # under the batched view admitted
-                                    # publishes are out with the
-                                    # collector while the reader runs
-                                    # on: at the run bound it waits for
-                                    # them, then re-passes the gate
-                                    await session.wire_drain()
-                                    if session.closed:
-                                        break
-                                    fast_gate = session.wire_fast_ready()
-                                if fast_gate \
-                                        and session.wire_publish_qos(
-                                            buf, rec):
-                                    fast_qpubs += 1
-                                    handled = True
-                            elif kind == fastpath.K_ACK:
-                                # resolves (invalid pids count
-                                # *_invalid_error exactly like classic)
-                                # but for a PUBREL behind a publish
-                                # still with the collector
-                                handled = session.wire_ack(rec)
-                        if not handled:
-                            if session.wire_inflight:
-                                # what the classic handler runs must
-                                # see every earlier publish routed and
-                                # acknowledged, as when the task
-                                # awaited each one
-                                await session.wire_drain()
-                                if session.closed:
-                                    break
-                            try:
-                                frame = fastpath.materialize(
-                                    codec, buf, rec, max_frame_size)
-                            except ParseError as e:
-                                if e.reason == "frame_too_large":
-                                    # the metric monitoring keys on,
-                                    # now that the parser (not the
-                                    # session payload check) is the
-                                    # enforcement point
-                                    metrics.incr(
-                                        "mqtt_invalid_msg_size_error")
-                                    if session.proto_ver == PROTO_5 \
-                                            and not session.closed:
-                                        # tell a v5 client WHY before
-                                        # dropping the socket (MQTT5
-                                        # 3.2.2.3.6 / DISCONNECT 0x95)
-                                        await session._disconnect_v5(
-                                            RC_PACKET_TOO_LARGE)
-                                raise
-                            await session.handle_frame(frame)
+                while off < end:
+                    if fast_gate:
+                        ran = wire_run(session, buf, table, off, end,
+                                       FRAME_RUN - frames_run)
+                        frames_run += (ran - off) // REC_SIZE
+                        off = ran
+                    if off < end and frames_run < FRAME_RUN:
+                        # the record the fast run stopped at
+                        rec = _unpack_rec(table, off)
+                        if (fast_gate and rec[0] == fastpath.K_PUB
+                                and rec[1] in _FAST_QOS_FLAGS
+                                and session.wire_inflight >= FRAME_RUN):
+                            # under the batched view admitted publishes
+                            # are out with the collector while the
+                            # reader runs on: at the run bound it waits
+                            # for them, then re-passes the gate and
+                            # offers the record to the fast run again
+                            await session.wire_drain()
                             if session.closed:
                                 break
-                            # every classic frame is an await — policy
-                            # (governor level, hooks, tracer) may have
-                            # moved while we yielded, so the remaining
-                            # fast records must re-pass the gate
-                            fast_gate = (fast_gate
-                                         and session.wire_fast_ready())
-                        frames_run += 1
-                        if frames_run >= FRAME_RUN:
-                            # bound the synchronous run per read chunk:
-                            # a 64KB chunk can hold ~700 small
-                            # PUBLISHes, and a handler that never truly
-                            # awaits would process them all in ONE loop
-                            # callback — a flood connection must not
-                            # stall every other session's IO (and the
-                            # sysmon sampler) for the whole chunk
-                            frames_run = 0
-                            await asyncio.sleep(0)
-                            if session.closed:  # closed while yielded
+                            fast_gate = session.wire_fast_ready()
+                            continue
+                        if session.wire_inflight:
+                            # what the classic handler runs must see
+                            # every earlier publish routed and
+                            # acknowledged, as when the task awaited
+                            # each one
+                            await session.wire_drain()
+                            if session.closed:
                                 break
-                            # re-check the batch gate after yielding:
-                            # the governor/hooks may have moved while
-                            # we slept
-                            fast_gate = (fast_gate
-                                         and session.wire_fast_ready())
-                finally:
-                    # a mid-batch error (malformed frame after admitted
-                    # publishes) must not lose the bookkeeping for
-                    # fast-path messages already routed and delivered
-                    if fast_pubs or fast_qpubs:
-                        session.wire_fast_done(fast_pubs, fast_qpubs)
+                        try:
+                            frame = fastpath.materialize(
+                                codec, buf, rec, max_frame_size)
+                        except ParseError as e:
+                            if e.reason == "frame_too_large":
+                                # the metric monitoring keys on, now
+                                # that the parser (not the session
+                                # payload check) is the enforcement
+                                # point
+                                metrics.incr("mqtt_invalid_msg_size_error")
+                                if session.proto_ver == PROTO_5 \
+                                        and not session.closed:
+                                    # tell a v5 client WHY before
+                                    # dropping the socket (MQTT5
+                                    # 3.2.2.3.6 / DISCONNECT 0x95)
+                                    await session._disconnect_v5(
+                                        RC_PACKET_TOO_LARGE)
+                            raise
+                        await session.handle_frame(frame)
+                        if session.closed:
+                            break
+                        # every classic frame is an await — policy
+                        # (governor level, hooks, tracer) may have moved
+                        # while we yielded, so the remaining fast
+                        # records must re-pass the gate
+                        fast_gate = fast_gate and session.wire_fast_ready()
+                        off += REC_SIZE
+                        frames_run += 1
+                    if frames_run >= FRAME_RUN:
+                        # bound the synchronous run per read chunk: a
+                        # 64KB chunk can hold ~700 small PUBLISHes, and
+                        # a handler that never truly awaits would
+                        # process them all in ONE loop callback — a
+                        # flood connection must not stall every other
+                        # session's IO (and the sysmon sampler) for the
+                        # whole chunk
+                        frames_run = 0
+                        await asyncio.sleep(0)
+                        if session.closed:  # closed while yielded
+                            break
+                        # re-check the batch gate after yielding: the
+                        # governor/hooks may have moved while we slept
+                        fast_gate = fast_gate and session.wire_fast_ready()
                 if session.closed:
                     break
                 buf = buf[consumed:] if consumed else buf
+            if inline is not None and session.connected:
+                # parked here, the protocol serves whole chunks of
+                # wire-plane records itself; it comes back with the
+                # bytes from the first record that needs this task
+                buf = await inline.park(session, buf)
+                if not buf:
+                    break
+                continue
             if session.connected:
                 chunk = await read_chunk()
             else:
@@ -366,7 +423,8 @@ async def mqtt_connection(
                 chunk = await asyncio.wait_for(read_chunk(), CONNECT_TIMEOUT)
             if not chunk:
                 break
-            metrics.incr("bytes_received", len(chunk))
+            if inline is None:
+                metrics.incr("bytes_received", len(chunk))
             buf += chunk
     except (asyncio.TimeoutError, TimeoutError):
         pass
@@ -386,6 +444,191 @@ async def mqtt_connection(
             await session.close("connection_lost")
         transport.close()
         metrics.incr("socket_close")
+
+
+#: unread bytes a connection may hold for its task before the socket's
+#: reading pauses (what asyncio's StreamReader allows: twice its 64 KiB
+#: limit); it resumes when the task takes them
+READ_HIGH = 2 * 65536
+
+
+class MqttProtocol(asyncio.Protocol):
+    """One ``mqtt`` / ``mqtts`` connection read at the protocol level. It
+    owns the inbound buffer and is the ``read_chunk`` of its connection's
+    task (``await proto()``: the next bytes, b"" on EOF, the socket's
+    error raised). While that task is parked at its steady-state read
+    (``park``) a chunk's wire-plane records run HERE (``_serve``), not in
+    the task: ``data_received`` lists the connection in its listener's
+    inbox and the listener's one callback serves the whole turn's chunks
+    first thing in the next turn — a turn's ``recv`` calls stay back to
+    back, which is what they cost least at on a sandboxed kernel. The
+    task is woken only for what awaits — the first record ``wire_run``
+    cannot serve and every byte behind it, a closed gate, EOF, any
+    exception of the inline run. While the task runs, or has bytes
+    waiting, ``data_received`` only appends: one connection's records
+    run in byte order, never on two sides at once."""
+
+    __slots__ = ("server", "transport", "_metrics", "_buf", "_tail",
+                 "_session", "_waiter", "_eof", "_exc", "_paused", "_task",
+                 "_closed")
+
+    def __init__(self, server: "MQTTServer"):
+        self.server = server
+        self.transport: Optional[asyncio.Transport] = None
+        self._metrics = server.broker.metrics
+        self._buf = b""    # bytes the task has not seen yet
+        self._tail = b""   # the parked task's incomplete frame
+        self._session: Optional[Session] = None  # set while parked
+        self._waiter: Optional[asyncio.Future] = None
+        self._eof = False
+        self._exc: Optional[BaseException] = None
+        self._paused = False
+        self._task: Optional[asyncio.Task] = None
+        self._closed: Optional[asyncio.Future] = None
+
+    # ------------------------------------------------- asyncio's side
+
+    def connection_made(self, transport) -> None:
+        self.transport = transport
+        if not self.server._admit(self):
+            transport.close()
+            return
+        loop = asyncio.get_running_loop()
+        self._closed = loop.create_future()
+        self._task = loop.create_task(self._run())
+
+    def data_received(self, data: bytes) -> None:
+        self._metrics.incr("bytes_received", len(data))
+        if self._session is not None:
+            # parked: the listener serves this turn's chunks together
+            if self._buf:
+                self._buf += data  # already listed (TLS may call twice)
+                return
+            srv = self.server
+            if not srv._inbox:
+                srv._loop.call_soon(srv._serve_inbox)
+            srv._inbox.append(self)
+            self._buf = data
+            return
+        self._buf += data
+        self._wake()
+        if not self._paused and len(self._buf) > READ_HIGH:
+            self._paused = True
+            self.transport.pause_reading()
+
+    def eof_received(self) -> bool:
+        self._eof = True
+        self._wake()
+        # keep a plain socket open for what the task still writes (it
+        # closes on its way out); TLS has no half-close
+        return self.transport.get_extra_info("sslcontext") is None
+
+    def connection_lost(self, exc) -> None:
+        self._eof = True
+        if exc is not None and self._exc is None:
+            self._exc = exc
+        self._wake()
+        if self._closed is not None and not self._closed.done():
+            self._closed.set_result(None)
+
+    # ------------------------------------------------ the inline run
+
+    def _serve(self, session: Session, data: bytes) -> None:
+        """One recv chunk while the task is parked (from the listener's
+        ``_serve_inbox``): the per-chunk gate, the batch parse, the fast
+        records. Served whole, nobody is woken; else the task gets the
+        bytes from the first record this could not serve (a closed
+        gate: all of them)."""
+        buf = self._tail + data if self._tail else data
+        try:
+            if session.wire_fast_ready():
+                tok = obs.span_begin("stage_wire_parse_ms")
+                try:
+                    table, nrec, consumed = fastpath.parse_batch(
+                        buf, self.server.max_frame_size,
+                        session.proto_ver == PROTO_5)
+                finally:
+                    obs.span_end("stage_wire_parse_ms", tok)
+                end = nrec * REC_SIZE
+                off = wire_run(session, buf, table, 0, end, FRAME_RUN)
+                if off == end:
+                    # an incomplete frame at the tail waits here for
+                    # the next chunk
+                    self._tail = buf[consumed:]
+                    fastpath.inline_chunks += 1
+                    return
+                buf = buf[_unpack_rec(table, off)[3]:]
+        except Exception as e:
+            # the task ends the connection through its own handlers and
+            # counters; nothing is left to asyncio's "Fatal error:
+            # protocol.data_received() call failed"
+            self._exc = e
+        self._tail = b""
+        self._buf = buf
+        fastpath.task_chunks += 1
+        self._wake()
+
+    def _wake(self) -> None:
+        self._session = None
+        waiter = self._waiter
+        if waiter is not None and not waiter.done():
+            waiter.set_result(None)
+
+    # ------------------------------------------------- the task's side
+
+    async def park(self, session: Optional[Session] = None,
+                   tail: bytes = b"") -> bytes:
+        """The task's read: ``tail`` (the incomplete frame it holds)
+        plus the bytes that came since, b"" on EOF. With ``session`` —
+        the steady-state read — chunks are served by the protocol
+        (``_serve``) for as long as this waits."""
+        if not self._buf and not self._eof and self._exc is None:
+            self._tail = tail
+            self._waiter = asyncio.get_running_loop().create_future()
+            self._session = session
+            try:
+                await self._waiter
+            finally:
+                self._waiter = self._session = None
+            tail, self._tail = self._tail, b""
+        if self._exc is not None:
+            raise self._exc
+        data, self._buf = self._buf, b""
+        if not data:
+            return b""  # EOF: an incomplete frame goes with the socket
+        if self._paused:
+            self._paused = False
+            self.transport.resume_reading()
+        return tail + data if tail else data
+
+    __call__ = park
+
+    async def _run(self) -> None:
+        srv = self.server
+        transport = self.transport
+        try:
+            if srv._nodelay is not None:
+                _apply_nodelay(transport, srv._nodelay)
+            from .ssl_util import preauth_from_cert
+
+            ok, preauth = preauth_from_cert(
+                transport, srv.use_identity_as_username, srv.ssl_context)
+            if not ok:
+                transport.close()  # cert required for identity mapping
+                return
+            await mqtt_connection(
+                srv.broker, self, StreamTransport(transport),
+                transport.get_extra_info("peername") or ("", 0),
+                srv.max_frame_size, preauth_user=preauth,
+                mountpoint=srv.mountpoint,
+                allowed_protocol_versions=srv.allowed_protocol_versions)
+        finally:
+            if not transport.is_closing():
+                transport.close()
+            try:
+                await self._closed
+            finally:
+                srv._release(self)
 
 
 class MQTTServer:
@@ -426,13 +669,24 @@ class MQTTServer:
         self._nodelay = parse_nodelay_option(
             str(broker.config.get("tcp_listen_options", "") or ""))
         self._server: Optional[asyncio.AbstractServer] = None
-        self._writers: set = set()  # live accepted connections
+        # live accepted connections, each with the ``.transport`` that
+        # close_server aborts: protocols, or a PROXY listener's writers
+        self._writers: set = set()
+        # parked connections that received a chunk this loop turn
+        self._inbox: list = []
+        self._loop: Optional[asyncio.AbstractEventLoop] = None
 
     async def start(self) -> None:
-        self._server = await asyncio.start_server(
-            self._handle_conn, self.host, self.port, ssl=self.ssl_context,
-            reuse_port=self.reuse_port or None,
-        )
+        self._loop = asyncio.get_running_loop()
+        if self.proxy_protocol:
+            # the PROXY header is read through a stream reader first
+            self._server = await asyncio.start_server(
+                self._handle_conn, self.host, self.port,
+                ssl=self.ssl_context, reuse_port=self.reuse_port or None)
+        else:
+            self._server = await self._loop.create_server(
+                lambda: MqttProtocol(self), self.host, self.port,
+                ssl=self.ssl_context, reuse_port=self.reuse_port or None)
         if self.port == 0:
             self.port = self._server.sockets[0].getsockname()[1]
         self.broker._servers.append(self._server)
@@ -440,64 +694,79 @@ class MQTTServer:
     async def stop(self) -> None:
         await close_server(self._server, self._writers)
 
+    def _serve_inbox(self) -> None:
+        """The second phase of a loop turn's reads: every chunk that
+        reached a parked connection in that turn, served in arrival
+        order by this one callback — scheduled by the first of them, so
+        it runs ahead of the next turn's reads and timers. A connection
+        woken meanwhile (EOF, a lost socket) keeps its bytes for its
+        task."""
+        inbox, self._inbox = self._inbox, []
+        for proto in inbox:
+            session = proto._session
+            if session is not None:
+                data, proto._buf = proto._buf, b""
+                proto._serve(session, data)
+
+    def _admit(self, conn) -> bool:
+        """Count an accepted connection in, unless the listener is at
+        its cap (listener.*.max_connections): the caller then refuses
+        it at accept like ranch's max_connections."""
+        if (self.max_connections
+                and self.connection_count >= self.max_connections):
+            self.broker.metrics.incr("socket_error")
+            return False
+        self.connection_count += 1
+        self._writers.add(conn)
+        return True
+
+    def _release(self, conn) -> None:
+        self._writers.discard(conn)
+        self.connection_count -= 1
+
     async def _handle_conn(
         self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter
     ) -> None:
-        if (self.max_connections
-                and self.connection_count >= self.max_connections):
-            # listener connection cap (listener.*.max_connections): refuse
-            # at accept like ranch's max_connections
-            self.broker.metrics.incr("socket_error")
+        """A PROXY-protocol listener's connection: the header through
+        the stream reader, then the shared loop over ``reader.read``."""
+        if not self._admit(writer):
             writer.close()
             return
-        self.connection_count += 1
-        self._writers.add(writer)
         try:
             await self._handle_conn_inner(reader, writer)
         finally:
-            self._writers.discard(writer)
-            self.connection_count -= 1
+            self._release(writer)
 
     async def _handle_conn_inner(
         self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter
     ) -> None:
+        from .proxy_proto import ProxyProtoError, read_proxy_header
+
         peer = writer.get_extra_info("peername") or ("", 0)
         if self._nodelay is not None:
-            _apply_nodelay(writer, self._nodelay)
-        initial = b""
+            _apply_nodelay(writer.transport, self._nodelay)
         preauth: Optional[str] = None
-        if self.proxy_protocol:
-            from .proxy_proto import ProxyProtoError, read_proxy_header
-
-            try:
-                info = await asyncio.wait_for(read_proxy_header(reader),
-                                              CONNECT_TIMEOUT)
-            except (ProxyProtoError, asyncio.TimeoutError, ConnectionError,
-                    asyncio.IncompleteReadError, asyncio.LimitOverrunError):
+        try:
+            info = await asyncio.wait_for(read_proxy_header(reader),
+                                          CONNECT_TIMEOUT)
+        except (ProxyProtoError, asyncio.TimeoutError, ConnectionError,
+                asyncio.IncompleteReadError, asyncio.LimitOverrunError):
+            writer.close()
+            return
+        if info.src is not None:
+            peer = info.src
+        if self.use_identity_as_username:
+            if not info.cn:
+                # identity mapping requires the PP2 SSL CN TLV — same
+                # policy as the TLS path (no silent fall-through)
                 writer.close()
                 return
-            if info.src is not None:
-                peer = info.src
-            if self.use_identity_as_username:
-                if not info.cn:
-                    # identity mapping requires the PP2 SSL CN TLV — same
-                    # policy as the TLS path (no silent fall-through)
-                    writer.close()
-                    return
-                preauth = info.cn
-        else:
-            from .ssl_util import preauth_from_cert
-
-            ok, preauth = preauth_from_cert(
-                writer, self.use_identity_as_username, self.ssl_context)
-            if not ok:
-                writer.close()  # cert required for identity mapping
-                return
-        transport = StreamTransport(writer)
+            preauth = info.cn
         try:
             await mqtt_connection(
-                self.broker, lambda: reader.read(65536), transport, peer,
-                self.max_frame_size, initial=initial, preauth_user=preauth,
+                self.broker, lambda: reader.read(65536),
+                StreamTransport(writer.transport), peer,
+                self.max_frame_size, preauth_user=preauth,
                 mountpoint=self.mountpoint,
                 allowed_protocol_versions=self.allowed_protocol_versions)
         finally:

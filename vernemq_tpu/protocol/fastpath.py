@@ -101,6 +101,8 @@ fastpath_pubs = 0       #: QoS0 publishes admitted object-free
 fastpath_pubs_qos = 0   #: QoS1/2 publishes admitted object-free
 classic_pubs_qos = 0    #: QoS1/2 publishes the gate left to the classic handler
 fastpath_acks = 0       #: ack frames resolved object-free
+inline_chunks = 0       #: recv chunks served whole by the connection's protocol
+task_chunks = 0         #: chunks, or remainders, handed to the connection's task
 fanout_batches = 0      #: batched fanout header encodes (one per fanout)
 
 
@@ -154,6 +156,8 @@ def stats():
         "wire_fastpath_pubs_qos": float(fastpath_pubs_qos),
         "wire_classic_pubs_qos": float(classic_pubs_qos),
         "wire_fastpath_acks": float(fastpath_acks),
+        "wire_inline_chunks": float(inline_chunks),
+        "wire_task_chunks": float(task_chunks),
         "wire_fanout_batches": float(fanout_batches),
         "wire_breaker_state": float(breaker.state),
     }
