@@ -7,7 +7,7 @@ on ONE TPU chip at a size its users would call real:
   MQTT bytes in on a TCP listener -> session -> Registry -> BatchCollector
   -> TpuMatcher device dispatch -> resolve -> queue -> bytes out
 
-with 1,000,000 resident subscriptions (the ``bench.build_corpus`` mix of
+with 1,000,000 resident subscriptions (``build_corpus``'s mix of
 exact / ``+`` / ``#`` filters, made from ``--seed``) loaded through
 ``Registry.subscribe``, a few real MQTT clients over TCP, publish bursts
 large enough to be device-served, and every device result compared with
@@ -123,8 +123,39 @@ class Checks:
 
 # ---------------------------------------------------------------- corpus
 
+def build_corpus(rng: random.Random, n_subs: int, table):
+    """Mixed subscription corpus over a 3-level topic tree: 64 x 256 x 64
+    words, 60% exact filters, 20% ``w/+/w``, 10% ``+/w/w``, 10% ``w/w/#``.
+    Writes into anything with ``add`` and returns the three word pools."""
+    l0 = [f"region{i}" for i in range(64)]
+    l1 = [f"dev{i}" for i in range(256)]
+    l2 = [f"metric{i}" for i in range(64)]
+    for i in range(n_subs):
+        r = rng.random()
+        w0, w1, w2 = rng.choice(l0), rng.choice(l1), rng.choice(l2)
+        if r < 0.60:
+            f = [w0, w1, w2]              # exact
+        elif r < 0.80:
+            f = [w0, "+", w2]             # single-level wildcard
+        elif r < 0.90:
+            f = ["+", w1, w2]
+        else:
+            f = [w0, w1, "#"]             # multi-level
+        table.add(f, i, None)
+    return l0, l1, l2
+
+
+def zipf_topics(rng: random.Random, pools, n: int) -> List[Tuple[str, ...]]:
+    """``n`` publish topics over the corpus's pools, Zipf-skewed."""
+    def pick(pool):
+        z = min(int(rng.paretovariate(1.2)) - 1, len(pool) - 1)
+        return pool[z]
+    l0, l1, l2 = pools
+    return [(pick(l0), pick(l1), pick(l2)) for _ in range(n)]
+
+
 class _Rows:
-    """``bench.build_corpus`` writes into anything with ``add``."""
+    """``build_corpus`` writes into anything with ``add``."""
 
     def __init__(self) -> None:
         self.rows: List[Tuple[List[str], int]] = []
@@ -134,8 +165,6 @@ class _Rows:
 
 
 def make_corpus(seed: int, n: int):
-    from bench import build_corpus
-
     rows = _Rows()
     pools = build_corpus(random.Random(seed), n, rows)
     return rows.rows, pools
@@ -407,12 +436,6 @@ def delta_rungs(max_delta: int) -> int:
 
 
 # ------------------------------------------------------------- the burst
-
-def zipf_burst(rng: random.Random, pools, n: int) -> List[Tuple[str, ...]]:
-    from bench import zipf_topics
-
-    return zipf_topics(rng, pools, n)
-
 
 def write_coalesced(client, topics, base: int) -> None:
     """QoS0 PUBLISH frames for ``topics`` as ONE write to the client's
@@ -817,8 +840,6 @@ async def boot_and_warm(args, rows, tag: str, specs, mesh: str = "") -> Rig:
     # the mesh seat warms its scatter on demand: no delta rungs to wait on
     rungs = 0 if mesh else delta_rungs(cfg.get("tpu_delta_warm_max", 128))
     t0 = time.monotonic()
-    uploaded = await wait_for(lambda: matcher._dev_arrays is not None,
-                              WARM_BOUND_S)
     complete, shapes = await wait_ladder(matcher, rungs, WARM_BOUND_S)
     ladder_s = time.monotonic() - t0
     # the same ladder again, every executable now resident in this
@@ -828,7 +849,6 @@ async def boot_and_warm(args, rows, tag: str, specs, mesh: str = "") -> Rig:
     await asyncio.get_running_loop().run_in_executor(
         None, matcher.warm_ladder)
     emit(phase=f"{tag}:warm", table_rows=int(matcher.table.cap),
-         first_upload_s=None if uploaded is None else round(uploaded, 2),
          ladder_s=round(ladder_s, 2), ladder_complete=complete,
          ladder_again_resident_s=round(time.monotonic() - t1, 2),
          warm_signatures=len(matcher._warm_sigs),
@@ -849,7 +869,7 @@ def _sig_bpad(sig) -> int:
     first = sig[0]
     if first == "sharded":       # ("sharded", Bpad, T, seg_max, ...)
         return int(sig[1])
-    if isinstance(first, tuple):  # (arg shapes, statics, pallas, packed)
+    if isinstance(first, tuple):  # (arg shapes, statics, pallas)
         return int(first[0][0])
     return 0                      # ("many", K, ...) / ("simple", ...)
 
@@ -917,7 +937,7 @@ async def one_chip(args, jax, chk: Checks) -> None:
     t0 = time.monotonic()
     rows, pools = make_corpus(args.seed, args.subs)
     emit(phase="corpus", subscriptions=len(rows), seed=args.seed,
-         mix="bench.build_corpus: 60% exact, 20% w/+/w, 10% +/w/w, 10% w/w/#",
+         mix="build_corpus: 60% exact, 20% w/+/w, 10% +/w/w, 10% w/w/#",
          build_s=round(time.monotonic() - t0, 2))
     specs = smoke_specs(pools, 4)
     rig = await boot_and_warm(args, rows, "boot1", specs)
@@ -952,23 +972,23 @@ async def one_chip(args, jax, chk: Checks) -> None:
         # host threshold of 8), a mid window, one full window
         for n in (9, 300, MAX_BATCH):
             await asserted_burst(rig, chk, f"single_batch_{n}",
-                                 lambda n=n: zipf_burst(rng, pools, n))
+                                 lambda n=n: zipf_topics(rng, pools, n))
         # one connection carrying a whole window alone (~180 KB of
         # frames): the broker reads it in 64 KB chunks
         await asserted_burst(rig, chk, "fat_connection",
-                             lambda: zipf_burst(rng, pools, MAX_BATCH),
+                             lambda: zipf_topics(rng, pools, MAX_BATCH),
                              per_connection=MAX_BATCH)
         # more than one collector window queued at once, so a flush
         # rides ONE fold_many dispatch
         await asserted_burst(
             rig, chk, "super_batch",
-            lambda: zipf_burst(rng, pools, rig.super_burst),
+            lambda: zipf_topics(rng, pools, rig.super_burst),
             expect_super=True)
         await delta_phase(rig, chk, pools)
-        emit(phase="retained_replay", status="not run",
-             why="ops/reverse_kernel has no step-1 compile case yet")
-        emit(phase="payload_predicate", status="not run",
-             why="ops/predicate_kernel has no step-1 compile case yet")
+        why = ("compiles for the v5e (tests/test_tpu_compile.py); no phase "
+               "here drives it yet (ROADMAP S6)")
+        emit(phase="retained_replay", status="not run", why=why)
+        emit(phase="payload_predicate", status="not run", why=why)
         emit(phase="counters", **counters(matcher, rig.collector),
              breaker=(matcher.breaker.state_name
                       if matcher.breaker is not None else None),
@@ -1040,7 +1060,7 @@ async def four_chips(args, jax, chk: Checks) -> None:
         # a publish whose bucket straddles a slice cut is served by the
         # exact per-publish host fallback BY DESIGN (counted), and that
         # fallback is a linear scan: keep the mesh burst small
-        topics = zipf_burst(rng, pools, MESH_BURST)
+        topics = zipf_topics(rng, pools, MESH_BURST)
         await asserted_burst(rig, chk, "mesh_single_batch", lambda: topics,
                              fallback_share=MESH_FALLBACK_SHARE)
         # what it is compared with: a single-device matcher over the

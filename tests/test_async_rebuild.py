@@ -177,52 +177,46 @@ def test_deltas_after_install_apply():
 def test_delta_flush_is_single_fused_scatter(monkeypatch):
     """A delta sync must coalesce the whole dirty set into ONE packed
     upload + ONE fused scatter call — not per-array eager updates
-    (each a separate executable launch and host↔device round trip).
-    Covers both transports (packed_io on/off) and checks correctness
-    of the scattered slots afterwards."""
+    (each a separate executable launch and host↔device round trip) —
+    and the scattered slots must match afterwards."""
     import vernemq_tpu.ops.match_kernel as K
 
-    for packed_io, fused_name in ((True, "apply_delta_fused"),
-                                  (False, "apply_delta_fused_nometa")):
-        rng = random.Random(11)
-        m = TpuMatcher(max_levels=8, initial_capacity=16384,
-                       packed_io=packed_io)
-        assert m.table.bucketed
-        trie = SubscriptionTrie()
-        fill(m, trie, 3000, "a", rng)
-        topics = [(f"r{rng.randrange(8)}", f"d{rng.randrange(16)}",
-                   f"a{rng.randrange(3000)}") for _ in range(8)]
-        check_device(m, trie, topics)  # first full build
+    rng = random.Random(11)
+    m = TpuMatcher(max_levels=8, initial_capacity=16384)
+    assert m.table.bucketed
+    trie = SubscriptionTrie()
+    fill(m, trie, 3000, "a", rng)
+    topics = [(f"r{rng.randrange(8)}", f"d{rng.randrange(16)}",
+               f"a{rng.randrange(3000)}") for _ in range(8)]
+    check_device(m, trie, topics)  # first full build
 
-        calls = {"fused": 0, "unfused": 0}
-        fused_real = getattr(K, fused_name)
+    calls = {"fused": 0, "unfused": 0}
+    fused_real = K.apply_delta_fused
 
-        def counting_fused(*a, _real=fused_real, **kw):
-            calls["fused"] += 1
-            return _real(*a, **kw)
+    def counting_fused(*a, **kw):
+        calls["fused"] += 1
+        return fused_real(*a, **kw)
 
-        def forbidden(name):
-            def _f(*a, **kw):
-                calls["unfused"] += 1
-                raise AssertionError(
-                    f"per-array delta path {name} used — the flush must "
-                    f"be ONE fused scatter")
-            return _f
+    def forbidden(name):
+        def _f(*a, **kw):
+            calls["unfused"] += 1
+            raise AssertionError(
+                f"per-array delta path {name} used — the flush must "
+                f"be ONE fused scatter")
+        return _f
 
-        monkeypatch.setattr(K, fused_name, counting_fused)
-        for name in ("apply_delta", "apply_delta_copy",
-                     "apply_delta_operands", "apply_delta_operands_copy",
-                     "apply_delta_meta", "apply_delta_meta_copy"):
-            monkeypatch.setattr(K, name, forbidden(name))
-        # a delta flush: adds only, no resize
-        fill(m, trie, 200, "d", rng)
-        assert not m.table.resized
-        probe = [(f"r{rng.randrange(8)}", f"d{rng.randrange(16)}",
-                  f"d{rng.randrange(200)}") for _ in range(8)]
-        check_device(m, trie, probe + topics)
-        assert calls["fused"] == 1, calls  # ONE fused scatter per flush
-        assert calls["unfused"] == 0
-        monkeypatch.undo()
+    monkeypatch.setattr(K, "apply_delta_fused", counting_fused)
+    for name in ("apply_delta", "apply_delta_copy",
+                 "apply_delta_meta", "apply_delta_meta_copy"):
+        monkeypatch.setattr(K, name, forbidden(name))
+    # a delta flush: adds only, no resize
+    fill(m, trie, 200, "d", rng)
+    assert not m.table.resized
+    probe = [(f"r{rng.randrange(8)}", f"d{rng.randrange(16)}",
+              f"d{rng.randrange(200)}") for _ in range(8)]
+    check_device(m, trie, probe + topics)
+    assert calls["fused"] == 1, calls  # ONE fused scatter per flush
+    assert calls["unfused"] == 0
 
 
 @pytest.mark.asyncio

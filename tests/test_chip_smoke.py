@@ -264,16 +264,6 @@ def test_compile_cache_dir_default_is_fixed_in_checkout(monkeypatch):
         jax.config.update("jax_compilation_cache_dir", before)
 
 
-def test_bench_refuses_cpu_unless_asked(monkeypatch, tmp_path):
-    import bench
-
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
-    with pytest.raises(SystemExit, match="no accelerator"):
-        bench.init_backend()
-    _jax, devices = bench.init_backend("cpu")
-    assert devices[0].platform == "cpu"
-
-
 @pytest.mark.parametrize("kw,match", [
     (dict(default_reg_view="tpu"), "each open the accelerator"),
     (dict(match_service=True, match_view="tpu"),

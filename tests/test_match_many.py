@@ -5,10 +5,8 @@ Covers the ISSUE-1 tentpole contract end to end: oracle equivalence vs
 the host trie for K ∈ {1, 4, 8} with mixed +/# filters, bit-identical
 results vs K independent match_batch calls, byte-identical kernel
 output vs per-batch packed calls, BatchCollector super-batches
-(per-future ordering + error propagation when a super-batch fails), the
-sharded seat's pipelined match_many, and a fast smoke of the bench
-dispatch-amortization probe so tier-1 exercises the path without
-hardware."""
+(per-future ordering + error propagation when a super-batch fails) and
+the sharded seat's pipelined match_many."""
 
 import asyncio
 import random
@@ -20,7 +18,7 @@ import pytest
 from vernemq_tpu.models.tpu_matcher import BatchCollector, TpuMatcher
 from vernemq_tpu.models.trie import SubscriptionTrie
 
-from tests.test_tpu_match import corpus_filter, norm
+from tests.test_tpu_match import corpus_filter, norm, spy_kernel_call
 
 
 def _corpus(seed: int, n: int = 8000):
@@ -96,29 +94,22 @@ def test_match_many_single_batch_falls_back(corpus):
         assert norm(rows) == norm(trie.match(list(t))), t
 
 
-def test_match_many_kernel_byte_identical_to_packed_calls(corpus):
-    """ops.match_kernel.match_many (scan + donated staging) returns
-    byte-identical result vectors to K separate packed calls — the
-    multi-batch pipeline loses nothing vs the per-batch transport."""
+def test_match_many_kernel_byte_identical_to_packed_calls(corpus,
+                                                          monkeypatch):
+    """What ``TpuMatcher.match_many`` dispatches —
+    ops.match_kernel.match_many (scan + donated staging) through
+    ``call_match_many`` — returns byte-identical result vectors to K
+    separate packed calls on the same operands: the multi-batch pipeline
+    loses nothing vs the per-batch transport."""
     from vernemq_tpu.ops import match_kernel as K
 
     m, _, rng = corpus
-    with m.lock:
-        m.sync()
-    S = int(m._dev_arrays[0].shape[0])
-    preps, singles, statics = [], [], None
-    for _ in range(3):
-        topics = _topics(rng, 64)
-        pw, pl, pd, pb, gb = m._encode_batch_ex(topics)
-        args, statics, left = m._flat_prep(
-            m._reg_start, m._reg_end, m._glob_pad, m._ops_bits, S,
-            pw, pl, pd, pb, gb, len(topics))
-        assert not left
-        preps.append(args)
-        singles.append(np.asarray(K.call_packed(
-            m._operands[0], m._operands[1], m._meta, args, statics)))
-    stacked = np.asarray(K.call_match_many(
-        m._operands[0], m._operands[1], m._meta, preps, statics))
+    calls = spy_kernel_call(monkeypatch, "call_match_many")
+    m.match_many([_topics(rng, 64) for _ in range(3)])
+    ((F_t, t1, meta, preps, statics), _kw, stacked), = calls
+    stacked = np.asarray(stacked)
+    singles = [np.asarray(K.call_packed(F_t, t1, meta, args, statics))
+               for args in preps]
     assert stacked.shape == (3,) + singles[0].shape
     for i, single in enumerate(singles):
         np.testing.assert_array_equal(stacked[i], single)
@@ -257,31 +248,3 @@ def test_sharded_seat_match_many_parity():
             assert norm(r1) == norm(r2), t
 
 
-# ---------------------------------------------------------------------------
-# Probe path smoke (tier-1 exercises the bench/roofline probe on CPU)
-# ---------------------------------------------------------------------------
-
-def test_match_many_probe_smoke():
-    """bench.match_many_probe runs at smoke scale and emits the
-    amortization ladder: per-dispatch overhead amortizes as
-    dispatch/K (monotone in K by construction of the fit)."""
-    import random as _random
-
-    import jax
-
-    from bench import WindowedBench, build_corpus, match_many_probe
-    from vernemq_tpu.models.tpu_table import SubscriptionTable
-
-    rng = _random.Random(5)
-    table = SubscriptionTable(max_levels=8, initial_capacity=16384)
-    pools = build_corpus(rng, 6000, table)
-    wb = WindowedBench(jax, table, pools, rng, batch=64, max_fanout=64)
-    out = match_many_probe(wb, ks=(1, 2), reps=1, probe_batch=64)
-    assert out["ks"] == [1, 2]
-    assert set(out["super_batch_ms"]) == {"1", "2"}
-    assert all(v > 0 for v in out["super_batch_ms"].values())
-    a = out["amortized_dispatch_ms"]
-    # dispatch/K amortization: two batches per dispatch must cost far
-    # less than two dispatches. reps=1, so allow scheduler jitter — an
-    # exact t2 <= t1 bound flakes by microseconds under suite load.
-    assert a["2"] <= a["1"] / 2 * 1.25

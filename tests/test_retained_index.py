@@ -4,8 +4,8 @@
 set/delete maintenance, growth rebuilds, per-filter host-fallback
 contracts, fault-injection/breaker degradation, the replay batch
 collector, retained-replay semantics through the broker (retain_handling
-1/2, RAP, shared-subscription exclusion, MQTT-4.7.2-1), and a smoke of
-bench config 8. Runs on the CPU backend (conftest forces it)."""
+1/2, RAP, shared-subscription exclusion, MQTT-4.7.2-1). Runs on the CPU
+backend (conftest forces it)."""
 
 import asyncio
 import random
@@ -504,25 +504,6 @@ def test_property_reverse_match_parity(topics, filters):
            for fw, hash_suffix in filters]
     for fw, rows in zip(fls, exact(store, idx, fls)):
         assert norm(rows) == norm(store.match_filter("", list(fw))), fw
-
-
-# ------------------------------------------------------------- bench smoke
-
-def test_bench_config8_smoke():
-    """bench config 8 runs at tiny scale and emits its metric keys
-    (tier-1 exercises the storm path without the full corpus)."""
-    import random as _random
-
-    from bench import config8_retained_storm
-
-    out = config8_retained_storm(_random.Random(0), smoke=True,
-                                 n_retained=3000, batch=128, iters=2,
-                                 n_host=40)
-    assert out["parity_ok"] is True
-    assert out["retained_replay_subscribes_per_sec"] > 0
-    assert out["host_replay_subscribes_per_sec"] > 0
-    assert out["dispatches"] >= 1
-    assert out["breaker_state_during_storm"] == "open"
 
 
 def test_encode_cache_survives_region_remap():

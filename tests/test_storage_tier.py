@@ -843,18 +843,6 @@ def test_spool_legacy_file_journal_migrates(tmp_path):
     sp2.close()
 
 
-def test_bench_reconnect_storm_smoke():
-    import bench
-
-    r = bench.config14_reconnect_storm(True, sessions=250)
-    assert r["parity_ok"] is True
-    assert r["batched"]["sessions_resumed"] == 250
-    assert r["batched"]["journal_engine"] in ("segment", "native")
-    assert r["read_all_baseline"]["resume"] is None
-    assert r["speedup_vs_read_all"] > 0
-    assert r["batched"]["replay_ms_p99"] is not None
-
-
 # ------------------------------------------- TTL sweep + bucket index
 
 

@@ -15,7 +15,7 @@ live in this one file; the persistent compile cache is off around them
 (a described-device executable can be written to it but not read back).
 
 Geometry is the broker's own: the table a ``TpuRegView`` matcher builds
-for the ``bench.build_corpus`` mix at 1,000,000 subscriptions with
+for ``chip_smoke.build_corpus``'s mix at 1,000,000 subscriptions with
 ``tpu_initial_capacity=1<<20`` (warm-loaded in trie order: 3,219,456
 rows), shapes and statics straight from ``TpuMatcher._flat_prep`` — what
 ``chip_smoke.py`` dispatches.
@@ -66,7 +66,7 @@ def one_chip(topo):
 def matcher():
     """The 1M-subscription table, built once on the CPU backend with the
     knobs the registry hands a TpuRegView matcher (config defaults)."""
-    from bench import build_corpus
+    from chip_smoke import build_corpus
     from vernemq_tpu.broker.config import DEFAULTS
     from vernemq_tpu.models.tpu_matcher import TpuMatcher
 
@@ -138,7 +138,7 @@ def _compile_packed(m, one_chip, n):
 
 @pytest.mark.parametrize("n", [4096, 9], ids=["B4096", "Bmin"])
 def test_packed_match_compiles(matcher, one_chip, n):
-    """The default path (``tpu_packed_io``): what ``K.call_packed`` runs,
+    """The default path: what ``K.call_packed`` runs,
     at the collector's full window and at the smallest flush the device
     serves (``tpu_host_batch_threshold=8`` → 9 pubs → Bpad 16)."""
     compiled = _compile_packed(matcher, one_chip, n)
@@ -164,20 +164,27 @@ def test_match_many_compiles(matcher, one_chip):
     assert _total_bytes(compiled) < HBM_BYTES
 
 
+D_TOP = 128  # the top of the pre-warmed delta ladder (tpu_delta_warm_max)
+
+
+def _delta_rows(m):
+    """Host operands of a Dpad=128 delta in ``delta_pack_args``' order:
+    slots, words [D, L], eff_len, and the three flag vectors."""
+    z, zb = np.zeros(D_TOP, np.int32), np.zeros(D_TOP, bool)
+    return (z, np.zeros((D_TOP, m.table.words.shape[1]), np.int32), z,
+            zb, zb, zb)
+
+
 def _compile_delta(m, one_chip):
     from vernemq_tpu.ops import match_kernel as K
 
     if "delta" not in _COMPILED:
-        D = 128
-        L = m.table.words.shape[1]
-        z = np.zeros(D, np.int32)
-        zb = np.zeros(D, bool)
-        packed = K.delta_pack_args(z, np.zeros((D, L), np.int32), z,
-                                   zb, zb, zb)
+        packed = K.delta_pack_args(*_delta_rows(m))
         _COMPILED["delta"] = K.apply_delta_fused.lower(
             *(_sds(a, one_chip) for a in m._dev_arrays),
             *_table_sds(m, one_chip), _sds(packed, one_chip),
-            D=D, L=L, id_bits=m._ops_bits).compile()
+            D=D_TOP, L=m.table.words.shape[1],
+            id_bits=m._ops_bits).compile()
     return _COMPILED["delta"]
 
 
@@ -269,4 +276,173 @@ def test_mesh_match_compiles_sharded(matcher, topo):
                       for a in full.values())
     per_device = compiled.memory_analysis().argument_size_in_bytes
     assert per_device < 0.5 * table_bytes, (per_device, table_bytes)
+    assert _total_bytes(compiled) < HBM_BYTES
+
+
+# ------------------------------------------------------------------------
+# The other programs the broker can dispatch. Each case DRIVES the seat
+# that dispatches the program once on the CPU backend, at the knobs the
+# broker hands it, records the call (argument shapes, dtypes, static
+# keywords), and asks the v5e's compiler for exactly that signature.
+
+def _record_call(monkeypatch, module, name, drive):
+    """``(args, kwargs)`` of the call ``drive()`` makes to
+    ``module.name`` that moved most bytes (a seat may compile a small
+    shape first)."""
+    from tests.test_tpu_match import spy_kernel_call
+
+    calls = spy_kernel_call(monkeypatch, name, module)
+    try:
+        drive()
+    finally:
+        monkeypatch.undo()
+    assert calls, f"{name} was not dispatched"
+    args, kwargs, _out = max(calls, key=lambda c: sum(
+        int(np.prod(np.shape(a))) for a in c[0]))
+    return module, args, kwargs
+
+
+def _unbucketed_call(monkeypatch, _m, name):
+    """The full-scan path of a table under the bucketing size
+    (``TpuMatcher._match_batch_phased``, ``bucketed`` false) at the
+    collector's full window: the VPU scan at the default
+    ``tpu_initial_capacity``, the MXU scan at the largest unbucketed
+    table."""
+    from vernemq_tpu.broker.config import DEFAULTS
+    from vernemq_tpu.models.tpu_matcher import TpuMatcher
+    from vernemq_tpu.ops import match_kernel as K
+
+    capacity = {"match_extract": DEFAULTS["tpu_initial_capacity"],
+                "match_extract_mxu": 4096}[name]
+    m = TpuMatcher(initial_capacity=capacity,
+                   max_fanout=DEFAULTS["tpu_max_fanout"],
+                   flat_avg=DEFAULTS["tpu_flat_avg"])
+    assert not m.table.bucketed
+    for i in range(64):
+        m.table.add(["fleet", f"dev{i}", "+"], i, None)
+    topics = [("fleet", f"dev{i % 64}", "temp") for i in range(4096)]
+    return _record_call(monkeypatch, K, name, lambda: m.match_batch(topics))
+
+
+def _unfused_delta_call(_monkeypatch, m, name):
+    """The delta of a table without coded operands (``id_bits`` 0, a
+    vocabulary past 24 bits: ``_apply_delta_device_inner``'s last
+    branch) at the 1M table's geometry, Dpad=128."""
+    from vernemq_tpu.ops import match_kernel as K
+
+    slots, words, eff, hh, fw, ac = _delta_rows(m)
+    if name == "apply_delta":
+        return K, (*m._dev_arrays, slots, words, eff, hh, fw, ac), {}
+    return K, (m._meta, slots, eff, hh, fw, ac), {}
+
+
+def _retained_call(monkeypatch, _m, name):
+    """``RetainedIndex`` at the ``tpu_retained_*`` defaults over 20,000
+    retained topics, a full replay batch of filters, in the posture it
+    takes off the CPU (coded dense phase on the device, ``k`` =
+    max_fanout)."""
+    from vernemq_tpu.broker.config import DEFAULTS
+    from vernemq_tpu.broker.retain import RetainStore
+    from vernemq_tpu.ops import reverse_kernel as RK
+    from vernemq_tpu.retained.index import RetainedIndex
+
+    holder = {}
+    store = RetainStore(
+        on_dirty=lambda mp, t, v: holder["idx"].on_retain(t, v))
+    idx = holder["idx"] = RetainedIndex(
+        store, initial_capacity=DEFAULTS["tpu_retained_initial_capacity"],
+        max_fanout=DEFAULTS["tpu_retained_max_fanout"])
+    idx.async_rebuild = False
+    idx.dense_policy, idx.dense_mode = "device", "coded"
+    idx.extract_k = idx.max_fanout
+    rng = random.Random(3)
+    for i in range(20_000):
+        store.insert("", (f"site{rng.randrange(64)}",
+                          f"dev{rng.randrange(512)}", f"m{i % 16}"), i)
+    n = DEFAULTS["tpu_retained_max_batch"]
+    filters = [(f"site{i % 64}", "+", f"m{i % 16}") if i % 4
+               else ("+", f"dev{i % 512}", "#") for i in range(n)]
+    return _record_call(monkeypatch, RK, name,
+                        lambda: idx.match_filters(filters))
+
+
+def _predicate_call(monkeypatch, _m, name):
+    """``FilterEngine._dispatch`` over a collector window of 4,096
+    publishes against sixteen predicate subscriptions (and, for
+    ``predicate_phase``, a windowed aggregate)."""
+    import json
+
+    from vernemq_tpu.cluster.metadata import MetadataStore
+    from vernemq_tpu.filters.engine import FilterEngine
+    from vernemq_tpu.filters.schema_registry import SchemaRegistry
+    from vernemq_tpu.ops import predicate_kernel as PK
+    from vernemq_tpu.protocol.types import SubOpts
+
+    reg = SchemaRegistry(MetadataStore("n1"), "n1")
+    reg.set_schema("", "s/+/t", "value:number,unit:enum(c|f)")
+    eng = FilterEngine(reg, device_gate=lambda: True)
+    eng.emit = lambda *a: None
+    exprs = [f"$gt(value,{10 * i})" for i in range(8)] + \
+            [f"$range(value,{i},{i + 50})" for i in range(8)]
+    if name == "predicate_phase":
+        exprs.append("$avg(value,64)")
+    rows = []
+    for i, expr in enumerate(exprs):
+        o = SubOpts()
+        o.filter_expr = expr
+        eng.on_sub_delta("add", "", o)
+        rows.append((("s", "+", "t"), ("", f"c{i}"), o))
+    topic = ("s", "a", "t")
+    items = [(topic, eng.encode("", topic, json.dumps(
+        {"value": i % 100, "unit": "c"}).encode())) for i in range(4096)]
+    results = [list(rows) for _ in items]
+    return _record_call(monkeypatch, PK, name,
+                        lambda: eng.filter_batch("", items, results))
+
+
+@pytest.mark.parametrize("name,build", [
+    ("match_extract", _unbucketed_call),
+    ("match_extract_mxu", _unbucketed_call),
+    ("apply_delta", _unfused_delta_call),
+    ("apply_delta_meta", _unfused_delta_call),
+    ("reverse_match", _retained_call),
+    ("eval_pairs", _predicate_call),
+    ("predicate_phase", _predicate_call),
+], ids=lambda v: v if isinstance(v, str) else "")
+def test_dispatchable_program_compiles(matcher, one_chip, monkeypatch,
+                                       name, build):
+    """Every program the broker can dispatch beside the main path's
+    compiles for the v5e at the signature its seat builds, and fits."""
+    module, args, kwargs = build(monkeypatch, matcher, name)
+    with warnings.catch_warnings():
+        warnings.filterwarnings(
+            "ignore", message="Some donated buffers were not usable")
+        compiled = getattr(module, name).lower(
+            *(_sds(a, one_chip) for a in args), **kwargs).compile()
+    assert _total_bytes(compiled) < HBM_BYTES
+
+
+def test_sharded_delta_scatter_compiles(matcher, topo):
+    """``apply_delta_windowed_fused`` — the sharded seats' ONE fused
+    delta scatter (``ShardedWindowedMatcher._sync_delta``) — over the
+    four described devices with the shardings ``ShardedTpuMatcher.
+    _build_device`` places: operands and metadata split over 'sub', the
+    dense g-zone mirrors replicated; Dpad=128."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from vernemq_tpu.ops import match_kernel as K
+    from vernemq_tpu.parallel.mesh import make_mesh
+
+    m = matcher
+    mesh = make_mesh(topo.devices, batch=1)
+    sF, s1 = NamedSharding(mesh, P(None, "sub")), NamedSharding(mesh, P("sub"))
+    rep2, rep1 = NamedSharding(mesh, P(None, None)), NamedSharding(mesh, P(None))
+    glob = m.table.gb_end
+    full = tuple(m._operands) + tuple(m._dev_arrays[1:5])
+    state = [_sds(a, sF if a.ndim == 2 else s1) for a in full] + [
+        _sds(a[:, :glob] if a.ndim == 2 else a[:glob],
+             rep2 if a.ndim == 2 else rep1) for a in full]
+    compiled = K.apply_delta_windowed_fused.lower(
+        *state, _sds(K.delta_pack_args(*_delta_rows(m)), rep1), D=D_TOP,
+        L=m.table.words.shape[1], id_bits=m._ops_bits, glob=glob).compile()
     assert _total_bytes(compiled) < HBM_BYTES

@@ -646,51 +646,33 @@ def test_flat_overflow_property_parity():
     run()
 
 
-def test_rows_variant_matches_flat_kernel():
-    """match_extract_windowed_rows (gather-merge, no scatter) returns the
-    same per-pub slot sets as the production flat kernel on a bucketed
-    corpus — the A/B candidate for hardware where scatters dominate."""
-    import numpy as np
+def spy_kernel_call(monkeypatch, name, module=None):
+    """Record every call the program makes to ``<module>.<name>``
+    (``ops.match_kernel`` unless given) as ``(positional args, keyword
+    args, result)``: a test can then hold what was dispatched to another
+    program on exactly the operands the seat built, through no private
+    of the seat."""
+    if module is None:
+        from vernemq_tpu.ops import match_kernel as module
 
-    from vernemq_tpu.ops import match_kernel as K
+    calls = []
+    real = getattr(module, name)
 
-    rng = random.Random(21)
-    m = _bucketed_matcher(max_fanout=64)
-    for i in range(10000):
-        m.table.add(corpus_filter(rng), i, None)
-    topics = [(f"r{rng.randrange(16)}", f"d{rng.randrange(40)}",
-               f"m{rng.randrange(16)}") for _ in range(64)]
-    with m.lock:
-        m.sync()
-    pw, pl, pd, pb, gb = m._encode_batch_ex(topics)
-    S = int(m._dev_arrays[0].shape[0])
-    args, statics, left = m._flat_prep(
-        m._reg_start, m._reg_end, m._glob_pad, m._ops_bits, S,
-        pw, pl, pd, pb, gb, len(topics))
-    head = (m._operands[0], m._operands[1], m._dev_arrays[1],
-            m._dev_arrays[2], m._dev_arrays[3], m._dev_arrays[4])
-    flat, pre, total, ovf = (np.asarray(x) for x in
-                             K.match_extract_windowed_flat(
-                                 *head, *args, **statics))
-    st = dict(statics)
-    st["kf"] = st.pop("C") // pw.shape[0]
-    rows, rtotal, rovf = (np.asarray(x) for x in
-                          K.match_extract_windowed_rows(
-                              *head, *args, **st))
-    assert not left
-    np.testing.assert_array_equal(total[:64], rtotal[:64])
-    np.testing.assert_array_equal(ovf[:64], rovf[:64])
-    for i in range(64):
-        if ovf[i]:
-            continue
-        a = sorted(flat[pre[i]:pre[i] + total[i]])
-        b = sorted(rows[i, :rtotal[i]])
-        assert a == b, (i, topics[i])
+    def spy(*args, **kw):
+        out = real(*args, **kw)
+        calls.append((args, kw, out))
+        return out
 
-def test_packed_variant_matches_flat_kernel():
-    """match_extract_windowed_flat_packed (single-vector transport) parses
-    back to exactly the unpacked kernel's (flat, pre, total, overflow) —
-    guards the flat_pack_args/unpack layout against drift."""
+    monkeypatch.setattr(module, name, spy)
+    return calls
+
+
+def test_packed_variant_matches_flat_kernel(monkeypatch):
+    """What ``match_batch`` dispatches —
+    match_extract_windowed_flat_packed through ``call_packed``, the
+    single-vector transport — parses back to exactly the plain kernel's
+    (flat, pre, total, overflow) on the same operands: guards the
+    flat_pack_args / pack_meta / unpack layout against drift."""
     import numpy as np
 
     from vernemq_tpu.ops import match_kernel as K
@@ -701,165 +683,24 @@ def test_packed_variant_matches_flat_kernel():
         m.table.add(corpus_filter(rng), i, None)
     topics = [(f"r{rng.randrange(16)}", f"d{rng.randrange(40)}",
                f"m{rng.randrange(16)}") for _ in range(64)]
-    with m.lock:
-        m.sync()
-    pw, pl, pd, pb, gb = m._encode_batch_ex(topics)
-    S = int(m._dev_arrays[0].shape[0])
-    args, statics, left = m._flat_prep(
-        m._reg_start, m._reg_end, m._glob_pad, m._ops_bits, S,
-        pw, pl, pd, pb, gb, len(topics))
-    head = (m._operands[0], m._operands[1], m._dev_arrays[1],
-            m._dev_arrays[2], m._dev_arrays[3], m._dev_arrays[4])
+    calls = spy_kernel_call(monkeypatch, "call_packed")
+    m.match_batch(topics)
+    ((F_t, t1, _meta, args, statics), _kw, out), = calls
+    out = np.asarray(out)
+    t = m.table
     flat, pre, total, ovf = (np.asarray(x) for x in
                              K.match_extract_windowed_flat(
-                                 *head, *args, **statics))
+                                 F_t, t1, t.eff_len, t.has_hash,
+                                 t.first_wild, t.active, *args, **statics))
     Bpad = args[0].shape[0]
-    out = np.asarray(K.call_packed(
-        m._operands[0], m._operands[1], m._meta, args, statics))
     C = statics["C"]
     assert out.shape == (C + 3 * Bpad,)
     pflat, ppre, ptotal, povf = K.unpack_flat_result(out, Bpad, C)
+    assert int(total[:64].sum()) > 0
     np.testing.assert_array_equal(pflat, flat)
     np.testing.assert_array_equal(ppre, pre)
     np.testing.assert_array_equal(ptotal, total)
     np.testing.assert_array_equal(povf, ovf)
-
-
-def test_packed_io_off_parity():
-    """packed_io=False (the unpacked per-array transport) still serves
-    match_batch with oracle parity — the knob must stay a pure transport
-    choice with zero semantic effect."""
-    rng = random.Random(23)
-    m = TpuMatcher(max_levels=8, initial_capacity=16384, packed_io=False)
-    assert m.table.bucketed and m._meta is None
-    trie = SubscriptionTrie()
-    for i in range(8000):
-        f = corpus_filter(rng)
-        m.table.add(f, i, None)
-        trie.add(list(f), i, None)
-    topics = [(f"r{rng.randrange(16)}", f"d{rng.randrange(40)}",
-               f"m{rng.randrange(16)}") for _ in range(100)]
-    for topic, rows in zip(topics, m.match_batch(topics)):
-        assert norm(rows) == norm(trie.match(list(topic))), topic
-    assert m._meta is None
-
-
-def test_packed_scan_totals_match_individual_calls():
-    """match_packed_scan (device-resident throughput probe) sums the same
-    match totals as individual packed calls over the same staged
-    batches — the probe must measure real matching, not a degenerate
-    graph."""
-    import numpy as np
-
-    from vernemq_tpu.ops import match_kernel as K
-
-    rng = random.Random(31)
-    m = _bucketed_matcher(max_fanout=64)
-    for i in range(8000):
-        m.table.add(corpus_filter(rng), i, None)
-    with m.lock:
-        m.sync()
-    S = int(m._dev_arrays[0].shape[0])
-    stacks, want_tot = [], 0
-    statics = None
-    geom = None
-    for b in range(3):
-        topics = [(f"r{rng.randrange(16)}", f"d{rng.randrange(40)}",
-                   f"m{rng.randrange(16)}") for _ in range(64)]
-        pw, pl, pd, pb, gb = m._encode_batch_ex(topics)
-        args, statics, left = m._flat_prep(
-            m._reg_start, m._reg_end, m._glob_pad, m._ops_bits, S,
-            pw, pl, pd, pb, gb, len(topics))
-        assert not left
-        out = np.asarray(K.call_packed(
-            m._operands[0], m._operands[1], m._meta, args, statics))
-        Bp = args[0].shape[0]
-        _, _, total, _ = K.unpack_flat_result(out, Bp, statics["C"])
-        want_tot += int(total.sum())
-        geom = dict(B=Bp, L=args[0].shape[1], T=args[4].shape[0],
-                    TP=args[4].shape[1], T2=args[6].shape[0])
-        stacks.append(K.flat_pack_args(args))
-    import jax
-
-    stack = jax.device_put(np.stack(stacks), m.device)
-    chk, tot = K.match_packed_scan(
-        m._operands[0], m._operands[1], m._meta, stack, **geom, **statics)
-    assert int(np.asarray(tot)) == want_tot
-
-
-def test_packed_stack_results_match_individual_calls():
-    """call_packed_stack (stacked transport: N batches per executable,
-    ONE result pull) returns byte-identical result vectors to N separate
-    packed calls — stacking loses nothing."""
-    import numpy as np
-
-    from vernemq_tpu.ops import match_kernel as K
-
-    rng = random.Random(37)
-    m = _bucketed_matcher(max_fanout=64)
-    for i in range(8000):
-        m.table.add(corpus_filter(rng), i, None)
-    with m.lock:
-        m.sync()
-    S = int(m._dev_arrays[0].shape[0])
-    preps, singles = [], []
-    statics = None
-    for b in range(3):
-        topics = [(f"r{rng.randrange(16)}", f"d{rng.randrange(40)}",
-                   f"m{rng.randrange(16)}") for _ in range(64)]
-        pw, pl, pd, pb, gb = m._encode_batch_ex(topics)
-        args, statics, left = m._flat_prep(
-            m._reg_start, m._reg_end, m._glob_pad, m._ops_bits, S,
-            pw, pl, pd, pb, gb, len(topics))
-        assert not left
-        preps.append(args)
-        singles.append(np.asarray(K.call_packed(
-            m._operands[0], m._operands[1], m._meta, args, statics)))
-    stacked = np.asarray(K.call_packed_stack(
-        m._operands[0], m._operands[1], m._meta, preps, statics))
-    assert stacked.shape == (3,) + singles[0].shape
-    for i, single in enumerate(singles):
-        np.testing.assert_array_equal(stacked[i], single)
-
-
-def test_packed_rows_variant_matches_flat_kernel():
-    """match_extract_windowed_rows_packed returns the same per-pub slot
-    sets as the flat kernel (same contract as the unpacked rows A/B)."""
-    import numpy as np
-
-    from vernemq_tpu.ops import match_kernel as K
-
-    rng = random.Random(33)
-    m = _bucketed_matcher(max_fanout=64)
-    for i in range(10000):
-        m.table.add(corpus_filter(rng), i, None)
-    topics = [(f"r{rng.randrange(16)}", f"d{rng.randrange(40)}",
-               f"m{rng.randrange(16)}") for _ in range(64)]
-    with m.lock:
-        m.sync()
-    pw, pl, pd, pb, gb = m._encode_batch_ex(topics)
-    S = int(m._dev_arrays[0].shape[0])
-    args, statics, left = m._flat_prep(
-        m._reg_start, m._reg_end, m._glob_pad, m._ops_bits, S,
-        pw, pl, pd, pb, gb, len(topics))
-    assert not left
-    head = (m._operands[0], m._operands[1], m._dev_arrays[1],
-            m._dev_arrays[2], m._dev_arrays[3], m._dev_arrays[4])
-    flat, pre, total, ovf = (np.asarray(x) for x in
-                             K.match_extract_windowed_flat(
-                                 *head, *args, **statics))
-    Bpad = args[0].shape[0]
-    out = np.asarray(K.call_packed_rows(
-        m._operands[0], m._operands[1], m._meta, args, statics))
-    kf = statics["C"] // Bpad
-    rows, rtotal, rovf = K.unpack_rows_result(out, Bpad, kf)
-    np.testing.assert_array_equal(total[:64], rtotal[:64])
-    np.testing.assert_array_equal(ovf[:64], rovf[:64])
-    for i in range(64):
-        if ovf[i]:
-            continue
-        assert sorted(flat[pre[i]:pre[i] + total[i]]) == \
-            sorted(rows[i, :rtotal[i]]), (i, topics[i])
 
 
 @pytest.mark.asyncio

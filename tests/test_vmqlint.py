@@ -424,21 +424,45 @@ def test_blocking_legacy_marker_still_honored(base_files):
     assert not any("time.sleep" in f.message for f in found)
 
 
-def test_blocking_scans_tools_and_bench(base_files):
-    """The scan roots include the harnesses (the old lint hardcoded
-    vernemq_tpu/) — a seeded defect in tools/ is caught, and the real
-    annotated site in tools/collector_latency.py flips red when its
-    marker is stripped."""
+def test_blocking_scans_tools(base_files):
+    """The scan roots include ``tools/`` (the old lint hardcoded
+    vernemq_tpu/) — a seeded defect there is caught, and the marker
+    suppresses it there as it does in the product tree."""
     rel = "tools/_vmqlint_fixture.py"
     found, _ = core.run(passes=["blocking"], files=base_files,
                         overrides={rel: BLOCKING_SNIPPET}, paths=[rel])
-    assert any(f.rel == rel for f in found)
-    lat = "tools/collector_latency.py"
-    stripped = base_files[lat].text.replace(
-        "vmqlint: allow(blocking)", "marker stripped")
-    found = run_pass("blocking", base_files, overrides={lat: stripped},
-                     paths=[lat])
-    assert any(f.rel == lat and "open" in f.message for f in found)
+    assert any(f.rel == rel and "time.sleep" in f.message for f in found)
+    marked = BLOCKING_SNIPPET.replace(
+        "time.sleep(0.1)",
+        "time.sleep(0.1)  # vmqlint: allow(blocking): fixture")
+    found = run_pass("blocking", base_files, overrides={rel: marked},
+                     paths=[rel])
+    assert not any("time.sleep" in f.message for f in found)
+    assert any(f.rel == rel and "open" in f.message for f in found)
+
+
+def test_shared_scan_roots_reach_chip_smoke(base_files):
+    """ONE tuple of scan roots (``core.SCAN_ROOTS``) serves the file
+    collection and both whole-program passes, and ``chip_smoke.py`` is
+    in it: a defect seeded into the real file is caught by each."""
+    from tools.vmqlint.passes import blocking, events_registry
+
+    assert "chip_smoke.py" in core.SCAN_ROOTS
+    assert blocking.BlockingPass.roots is core.SCAN_ROOTS
+    assert events_registry.EventsRegistryPass.roots is core.SCAN_ROOTS
+    rel = "chip_smoke.py"
+    assert run_pass("blocking", base_files, paths=[rel]) == []
+    seeded = base_files[rel].text + BLOCKING_SNIPPET
+    found = run_pass("blocking", base_files, overrides={rel: seeded},
+                     paths=[rel])
+    assert any(f.rel == rel and "time.sleep" in f.message for f in found)
+    seeded = base_files[rel].text + (
+        "\nfrom vernemq_tpu.observability import events\n"
+        "events.emit('no_such_event_code_xyz')\n")
+    found = run_pass("events-registry", base_files,
+                     overrides={rel: seeded})
+    assert any(f.rel == rel and "no_such_event_code_xyz" in f.message
+               for f in found)
 
 
 # ---------------------------------------------------------- metrics corpus

@@ -33,11 +33,12 @@ from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 REPO_ROOT = os.path.abspath(
     os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", ".."))
 
-#: scan roots, repo-relative. ``vernemq_tpu`` is the product tree;
-#: ``tools`` and ``bench.py`` carry the loadtest/soak/bench harnesses
-#: whose async bodies run the same event-loop rules (the old
-#: lint_blocking hardcoded the package dir and missed them).
-SCAN_ROOTS: Tuple[str, ...] = ("vernemq_tpu", "tools", "bench.py")
+#: scan roots, repo-relative, shared by every pass that walks the whole
+#: program. ``vernemq_tpu`` is the product tree; ``tools`` and
+#: ``chip_smoke.py`` drive it from async bodies that run under the same
+#: event-loop rules (the old lint_blocking hardcoded the package dir
+#: and missed them).
+SCAN_ROOTS: Tuple[str, ...] = ("vernemq_tpu", "tools", "chip_smoke.py")
 
 ALLOW_RE = re.compile(
     r"#\s*vmqlint:\s*allow\(\s*([a-z0-9*][a-z0-9*,\- ]*)\)"

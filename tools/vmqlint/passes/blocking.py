@@ -7,8 +7,8 @@ cross-thread wait, a sleep-poll ring helper, a process-wide mesh
 barrier) sitting on the event loop inside an async path, freezing every
 session's IO for its duration.  See the original module docstring —
 the rules are unchanged; what changed is the scan scope (now also
-``tools/`` and ``bench.py``: the loadtest/soak/bench harnesses run the
-same event-loop rules) and the suppression idiom
+``tools/`` and ``chip_smoke.py``, ``core.SCAN_ROOTS``: what drives the
+broker runs under the same event-loop rules) and the suppression idiom
 (``# vmqlint: allow(blocking): <reason>``; the legacy
 ``# lint: allow-blocking`` marker still works).
 """
@@ -18,7 +18,7 @@ from __future__ import annotations
 import ast
 from typing import List
 
-from ..core import Context, Finding, Pass, SourceFile
+from ..core import SCAN_ROOTS, Context, Finding, Pass, SourceFile
 
 #: call spellings that block the event loop. Attribute calls match on
 #: the LAST TWO components, so ``jax.distributed.initialize`` and a
@@ -134,7 +134,7 @@ class BlockingPass(Pass):
                 "bodies")
     defect = ("a synchronous stall on the event loop freezes every "
               "session's IO (the old fixed-sleep load shedder)")
-    roots = ("vernemq_tpu", "tools", "bench.py")
+    roots = SCAN_ROOTS
 
     def run(self, ctx: Context) -> List[Finding]:
         findings: List[Finding] = []

@@ -28,7 +28,7 @@ from __future__ import annotations
 import ast
 from typing import Dict, List, Optional, Set, Tuple
 
-from ..core import Context, Finding, Pass, const_str
+from ..core import SCAN_ROOTS, Context, Finding, Pass, const_str
 
 _EVENTS_FILE = "vernemq_tpu/observability/events.py"
 
@@ -84,7 +84,7 @@ class EventsRegistryPass(Pass):
               "journal must work); a site-less registry entry is a "
               "black-box signal that can never appear")
     tree_scoped = True
-    roots = ("vernemq_tpu", "tools", "bench.py")
+    roots = SCAN_ROOTS
 
     def run(self, ctx: Context) -> List[Finding]:
         findings: List[Finding] = []

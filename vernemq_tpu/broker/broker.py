@@ -805,7 +805,7 @@ class Broker:
             out.update(spool.stats())
         if self.worker_stats is not None:
             # scrape-point aggregation: every worker writes only its own
-            # slot; any worker's scrape (and the parent's bench reads)
+            # slot; any worker's scrape (and the parent's reads)
             # fuse the block into one node-level view
             try:
                 slots = self.worker_stats.read_all()
@@ -1509,7 +1509,7 @@ class Broker:
                 log.exception("store maintenance tick failed")
 
     def store_status(self) -> Dict[str, Any]:
-        """`vmq-admin store show` / bench introspection."""
+        """`vmq-admin store show` introspection."""
         engines = []
         for eng in self._store_engines():
             st = {"kind": getattr(eng, "kind", "?")}
@@ -1686,7 +1686,7 @@ class Broker:
                     admitted=self.metrics.value("mqtt_publish_received"))
                 # publish this worker's stage histograms into its slot:
                 # the scrape-point aggregation reads every live slot so
-                # ANY worker's /metrics (and the parent's bench read)
+                # ANY worker's /metrics (and the parent's read)
                 # shows the node-level merged families
                 ws.write_hist(idx, _hist.pack_all())
                 ws.write_events(idx, _events.journal().pack())
@@ -1782,8 +1782,8 @@ class Broker:
         self._log_handlers: List[Any] = []
         self._setup_logging()
         # observability master switch: off reduces every histogram/
-        # profiler seam to one module-global boolean test (the bench
-        # overhead guard measures exactly this difference). The flag is
+        # profiler seam to one module-global boolean test (PERF.md
+        # §6, PR 25, has what the seams cost when on). The flag is
         # process-global like the registries it gates.
         from ..observability import histogram as _hist
         from ..observability import profiler as _profiler

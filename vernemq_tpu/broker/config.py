@@ -159,14 +159,11 @@ DEFAULTS: Dict[str, Any] = {
     # check a state out instead of serialising on one interpreter
     "diversity_num_states": 4,
     # fused Pallas tile matcher for the probe phases (ops/pallas_match.py);
-    # off by default until an on-chip A/B (tools/tune_windowed.py
-    # --pallas) shows a win; a lowering failure is a device failure
-    # (breaker), never a silent switch back to the XLA kernel
+    # off by default until an on-chip A/B shows a win (a perf_opt PR
+    # judged by the benchmark, ROADMAP S8); a lowering failure is a
+    # device failure (breaker), never a silent switch back to the XLA
+    # kernel
     "tpu_use_pallas": False,
-    # packed transport for the windowed kernel: ONE int32 upload vector
-    # and ONE result vector per batch instead of 12 args + 4 pulls
-    # (fewer host↔device transfers per batch)
-    "tpu_packed_io": True,
     # flushes this small are matched on the host trie instead of paying a
     # device round trip (hybrid dispatch, SURVEY.md §7.2); 0 disables
     "tpu_host_batch_threshold": 8,
@@ -374,7 +371,7 @@ DEFAULTS: Dict[str, Any] = {
     # buckets + QoS0 fanout shedding + retained-replay deferral, L3
     # connect refusal (CONNACK 0x97 / server unavailable) + top-talker
     # disconnects (Server busy). "binary" keeps the legacy posture (the
-    # sysmon flag + fixed 0.1s sleep) for A/B runs — bench config 9.
+    # sysmon flag + fixed 0.1s sleep) for A/B runs.
     "overload_mode": "governor",  # governor | binary
     "overload_tick_ms": 250,
     "overload_hold_s": 5.0,       # per-level hysteresis hold window
@@ -425,7 +422,7 @@ DEFAULTS: Dict[str, Any] = {
     # observability (vernemq_tpu/observability/): stage latency
     # histograms + publish-path flight recorder + device dispatch
     # profiler. Off reduces every instrumented seam to one boolean test
-    # (the bench overhead guard measures the difference).
+    # (PERF.md §6, PR 25: what the spans cost when on).
     "observability_enabled": True,
     # flight recorder: every Nth admitted publish carries a stage-
     # stamped trace through the whole path (0 disables sampling)

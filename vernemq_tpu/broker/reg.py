@@ -193,7 +193,6 @@ class Registry:
             self, max_fanout=cfg.tpu_max_fanout,
             flat_avg=cfg.tpu_flat_avg,
             use_pallas=cfg.tpu_use_pallas,
-            packed_io=cfg.tpu_packed_io,
             breaker_enabled=cfg.get("tpu_breaker_enabled", True),
             breaker_failure_threshold=cfg.get(
                 "tpu_breaker_failure_threshold", 3),

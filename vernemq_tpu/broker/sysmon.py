@@ -170,7 +170,7 @@ class Sysmon:
             if ws is not None:
                 # multi-process front end: every lag sample also lands
                 # in this worker's shared slot — the per-worker
-                # loop-lag p99 bench config 11 and `workers show` read
+                # loop-lag p99 `workers show` reads
                 try:
                     ws.push_lag(self.broker.worker_index, lag)
                 except Exception:

@@ -226,8 +226,8 @@ class WorkerGroup:
             self._stats = None
 
     def stats_block(self):
-        """The parent's handle on the shared stats table (bench /
-        supervision reads)."""
+        """The parent's handle on the shared stats table
+        (supervision reads)."""
         return self._stats
 
     def _worker_overrides(self, idx: int) -> Dict[str, Any]:

@@ -158,8 +158,7 @@ class ClusterSpool:
     @property
     def engine_kind(self) -> str:
         """Which engine serves the journal — ``native`` / ``segment`` /
-        ``memory`` (recorded in the bench partition-storm artifact so
-        replay numbers are comparable across boxes)."""
+        ``memory``."""
         return getattr(self._kv, "kind", "unknown")
 
     def _load(self) -> None:

@@ -310,7 +310,7 @@ class _FoldPhases:
 class TpuMatcher:
     def __init__(self, max_levels: int = 16, initial_capacity: int = 1024,
                  max_fanout: int = 256, device=None, flat_avg: int = 128,
-                 use_pallas: bool = False, packed_io: bool = True):
+                 use_pallas: bool = False):
         import threading
 
         import jax
@@ -322,11 +322,10 @@ class TpuMatcher:
         # a lowering error is a dispatch failure like any other (it
         # propagates to the breaker), never a silent switch of kernel
         self.use_pallas = use_pallas
-        # packed transport: ship all per-batch host args as ONE int32
-        # vector and pull all results as ONE int32 vector (fewer
-        # host↔device transfers per batch than 12-in/4-out)
-        self.packed_io = packed_io
-        self._meta = None  # int32 [S] pack_meta word per slot
+        # packed transport: all per-batch host args go up as ONE int32
+        # vector and all results come back as ONE; the per-slot
+        # metadata rides in one device-resident int32 [S] pack_meta word
+        self._meta = None
         # flat-compaction capacity per pub AVERAGED over the batch (the
         # [C = Bpad*flat_avg] device result buffer); a batch whose total
         # fanout exceeds it degrades per-pub to the host path, it never
@@ -367,7 +366,7 @@ class TpuMatcher:
         # thread while callers shed to the host trie (RebuildInProgress),
         # so the publish pipeline never stops. The FIRST build stays
         # synchronous (there is no old state to serve). Default OFF for
-        # bare matchers (kernel tests/bench time the inline path);
+        # bare matchers (kernel tests take the inline path);
         # TpuRegView — the production seat, where a trie stands by —
         # turns it on.
         self.async_rebuild = False
@@ -466,7 +465,7 @@ class TpuMatcher:
         # forces this full rebuild path too
         operands = (K.build_operands(dev[0], dev[1], state["bits"])
                     if state["bits"] else None)
-        meta = K.pack_meta(*dev[1:5]) if self.packed_io else None
+        meta = K.pack_meta(*dev[1:5])
         done = time.monotonic()
         # a watchdog-abandoned build's straggler must not record its
         # wedge-inflated duration: stage_rebuild_ms is the tuning base
@@ -768,7 +767,7 @@ class TpuMatcher:
         t.dirty.clear()
         # pad the delta to a pow2 ladder: a distinct slot COUNT is a
         # distinct scatter shape, and uncapped counts recompile every sync
-        # (bench: 450ms p99 delta applies — all compile time). Duplicate
+        # (each a compile of its own). Duplicate
         # last-slot writes are idempotent (same value).
         Dpad = _pow2ceil(len(slots))
         if Dpad != len(slots):
@@ -833,7 +832,7 @@ class TpuMatcher:
         # otherwise copies ~500MB of HBM, ~300ms measured); fall back to
         # the copying variants while a dispatched match still holds refs
         donate = self._inflight == 0
-        if self._meta is not None and self._operands is not None:
+        if self._operands is not None:
             # fused transport: ONE packed upload + ONE call updates base
             # arrays, coded operands and the meta word together (the
             # unfused path takes 6 uploads + 2 dispatches per delta)
@@ -846,22 +845,8 @@ class TpuMatcher:
                 sw, el, hh, fw, ac, *self._operands, self._meta,
                 self._jax.device_put(packed, self.device),
                 D=len(slots), L=t.words.shape[1], id_bits=self._ops_bits)
-        elif self._operands is not None:
-            # packed_io=False but coded operands present: same ONE-upload
-            # ONE-fused-scatter flush as the meta path — the unfused
-            # fallback used to ship six arrays and dispatch three
-            # scatters per delta (each a separate executable launch and
-            # host↔device round trip)
-            packed = K.delta_pack_args(
-                slots, t.words[slots], t.eff_len[slots],
-                t.has_hash[slots], t.first_wild[slots], t.active[slots])
-            fusedn = (K.apply_delta_fused_nometa if donate
-                      else K.apply_delta_fused_nometa_copy)
-            self._dev_arrays, self._operands = fusedn(
-                sw, el, hh, fw, ac, *self._operands,
-                self._jax.device_put(packed, self.device),
-                D=len(slots), L=t.words.shape[1], id_bits=self._ops_bits)
         else:
+            # no coded operands (id_bits 0: a vocabulary past 24 bits)
             slots_dev = self._jax.device_put(slots, self.device)
             w_dev = self._jax.device_put(t.words[slots], self.device)
             e_dev = self._jax.device_put(t.eff_len[slots], self.device)
@@ -873,11 +858,9 @@ class TpuMatcher:
                 sw, el, hh, fw, ac, slots_dev, w_dev, e_dev,
                 hh_dev, fw_dev, ac_dev,
             )
-            if self.packed_io and self._meta is not None:
-                dm = (K.apply_delta_meta if donate
-                      else K.apply_delta_meta_copy)
-                self._meta = dm(self._meta, slots_dev, e_dev, hh_dev,
-                                fw_dev, ac_dev)
+            dm = K.apply_delta_meta if donate else K.apply_delta_meta_copy
+            self._meta = dm(self._meta, slots_dev, e_dev, hh_dev,
+                            fw_dev, ac_dev)
 
     def warm_delta_ladder(self, max_delta: int = 128) -> int:
         """Pre-compile the delta-scatter shape ladder (Dpad = 2..pow2 ≤
@@ -904,8 +887,7 @@ class TpuMatcher:
             op_shapes = ([(a.shape, np.dtype(a.dtype))
                           for a in self._operands]
                          if self._operands is not None else None)
-            meta_shape = ((self._meta.shape, np.dtype(self._meta.dtype))
-                          if self._meta is not None else None)
+            meta_shape = (self._meta.shape, np.dtype(self._meta.dtype))
             bits = self._ops_bits
             L = self.table.words.shape[1]
         put = lambda a: self._jax.device_put(a, self.device)
@@ -929,25 +911,17 @@ class TpuMatcher:
             # separate jitted program
             if op_shapes is not None:
                 packed = put(K.delta_pack_args(slots, zw, zi, zb, zb, zb))
-                if meta_shape is not None:
-                    for fn in (K.apply_delta_fused,
-                               K.apply_delta_fused_copy):
-                        fn(*zeros(shapes), *zeros(op_shapes),
-                           *zeros([meta_shape]), packed,
-                           D=d, L=L, id_bits=bits)
-                else:
-                    for fn in (K.apply_delta_fused_nometa,
-                               K.apply_delta_fused_nometa_copy):
-                        fn(*zeros(shapes), *zeros(op_shapes), packed,
-                           D=d, L=L, id_bits=bits)
+                for fn in (K.apply_delta_fused, K.apply_delta_fused_copy):
+                    fn(*zeros(shapes), *zeros(op_shapes),
+                       *zeros([meta_shape]), packed,
+                       D=d, L=L, id_bits=bits)
             else:
                 for fn in (K.apply_delta, K.apply_delta_copy):
                     fn(*zeros(shapes), put(slots), put(zw),
                        put(zi), put(zb), put(zb), put(zb))
-                if meta_shape is not None:
-                    for fn in (K.apply_delta_meta, K.apply_delta_meta_copy):
-                        fn(*zeros([meta_shape]), put(slots),
-                           put(zi), put(zb), put(zb), put(zb))
+                for fn in (K.apply_delta_meta, K.apply_delta_meta_copy):
+                    fn(*zeros([meta_shape]), put(slots),
+                       put(zi), put(zb), put(zb), put(zb))
             self.delta_shapes_warmed += 1
             done += 1
             d *= 2
@@ -1220,7 +1194,7 @@ class TpuMatcher:
         to K independent :meth:`match_batch` calls at the same Bpad.
 
         Falls back to sequential match_batch calls when the fused path
-        is unavailable (unbucketed table, packed_io off, or K == 1).
+        is unavailable (unbucketed table or K == 1).
         ``lock_timeout``/``require_warm`` follow match_batch's contract.
         """
         if not batches:
@@ -1268,8 +1242,7 @@ class TpuMatcher:
             snapshot = self._entries_snapshot
             dev_arrays = self._dev_arrays
             fast = (len(batches) > 1 and self._bucketed
-                    and operands is not None
-                    and self.packed_io and meta is not None)
+                    and operands is not None)
             if fast:
                 reg_start, reg_end = self._reg_start, self._reg_end
                 glob_pad, bits = self._glob_pad, self._ops_bits
@@ -1365,14 +1338,14 @@ class TpuMatcher:
     @property
     def supports_match_many(self) -> bool:
         """Whether the fused K-batch dispatch path is available
-        (bucketed table layout + codable ids + packed transport — table
-        state, not device state: match_many syncs before dispatch, so a
-        not-yet-built table still qualifies). The collector gates
-        super-batching on this so an unbucketed or unpacked matcher is
-        never fed K windows it would only serialize — that would deepen
-        the overload queue with zero amortization."""
+        (bucketed table layout + codable ids — table state, not device
+        state: match_many syncs before dispatch, so a not-yet-built
+        table still qualifies). The collector gates super-batching on
+        this so an unbucketed matcher is never fed K windows it would
+        only serialize — that would deepen the overload queue with zero
+        amortization."""
         t = self.table
-        return bool(self.packed_io and t.bucketed and t.id_bits)
+        return bool(t.bucketed and t.id_bits)
 
     def ensure_warm_many(self, n_batches: int, n: int) -> None:
         """Background-compile the K-batch super-dispatch signature for
@@ -1436,8 +1409,7 @@ class TpuMatcher:
         device table arrays), and the set of host-fallback pubs (window
         overflow). Registry state (reg_start/…) is passed in, not read
         off self, so a caller can pin the snapshot its device arrays were
-        built from. Shared by match_batch and the bench driver so the
-        bench measures exactly the production call."""
+        built from. Shared by match_batch and match_many."""
         Bpad = pw.shape[0]
         T, seg_max, gc, T2, seg2, gb_end = self._geometry(
             S, glob_pad, reg_start, reg_end, Bpad, align=align)
@@ -1496,8 +1468,7 @@ class TpuMatcher:
         # depends on table CONTENT (amax), so a delta can mint new
         # signatures — the warm gate must see exactly what jit sees.
         sig = (tuple(a.shape for a in args),
-               tuple(sorted(statics.items())), pallas,
-               bool(self.packed_io and meta is not None))
+               tuple(sorted(statics.items())), pallas)
         if require_warm and sig not in self._warm_sigs:
             self.busy_sheds += 1
             raise MatcherBusy(cold=True)
@@ -1508,38 +1479,21 @@ class TpuMatcher:
             table_args = (F_t, t1, dev_arrays[1], dev_arrays[2],
                           dev_arrays[3], dev_arrays[4])
             from ..ops import pallas_match as P
-            flat, pre, total, overflow = \
-                P.match_extract_windowed_flat_pallas(
-                    *table_args, *args, **statics,
-                    interpret=P.use_interpret())
-        elif self.packed_io and meta is not None:
+            out = P.match_extract_windowed_flat_pallas(
+                *table_args, *args, **statics,
+                interpret=P.use_interpret())
+            ph.enter(obs.span("stage_fold_wait_ms"))
+            flat, pre, total, overflow = (np.asarray(a) for a in out)
+            ph.enter(obs.span("stage_fold_resolve_ms"))
+        else:
             # single-upload / single-pull transport (see pack_meta /
-            # flat_pack_args): one int32 vector each way instead of 12
-            # uploads + 4 pulls
+            # flat_pack_args): one int32 vector each way
             out = K.call_packed(F_t, t1, meta, args, statics)
             ph.enter(obs.span("stage_fold_wait_ms"))
             out = np.asarray(out)
             ph.enter(obs.span("stage_fold_resolve_ms"))
             flat, pre, total, overflow = K.unpack_flat_result(
                 out, args[0].shape[0], statics["C"])
-            need_host = overflow[:n].copy()
-            for i in left:
-                need_host[i] = True
-            idx_rows = [flat[pre[i]:pre[i] + total[i]] for i in range(n)]
-            self._warm_sigs.add(sig)
-            return idx_rows, need_host
-        else:
-            faults.inject("device.dispatch")
-            table_args = (F_t, t1, dev_arrays[1], dev_arrays[2],
-                          dev_arrays[3], dev_arrays[4])
-            flat, pre, total, overflow = K.match_extract_windowed_flat(
-                *table_args, *args, **statics)
-        ph.enter(obs.span("stage_fold_wait_ms"))
-        flat = np.asarray(flat)
-        pre = np.asarray(pre)
-        total = np.asarray(total)
-        overflow = np.asarray(overflow)
-        ph.enter(obs.span("stage_fold_resolve_ms"))
         need_host = overflow[:n].copy()
         for i in left:
             need_host[i] = True
@@ -1575,8 +1529,7 @@ class TpuRegView:
 
     def __init__(self, registry, max_levels: int = 16,
                  initial_capacity: int = 1024, max_fanout: int = 256,
-                 flat_avg: int = 128, use_pallas: bool = False,
-                 packed_io: bool = True, mesh=None,
+                 flat_avg: int = 128, use_pallas: bool = False, mesh=None,
                  mesh_native: bool = True,
                  breaker_enabled: bool = True,
                  breaker_failure_threshold: int = 3,
@@ -1618,8 +1571,7 @@ class TpuRegView:
                     max_fanout=max_fanout, flat_avg=flat_avg)
             else:
                 m = TpuMatcher(max_levels, initial_capacity, max_fanout,
-                               flat_avg=flat_avg, use_pallas=use_pallas,
-                               packed_io=packed_io)
+                               flat_avg=flat_avg, use_pallas=use_pallas)
             # production seat: growth rebuilds run in the background
             # while the registry's trie serves (fold / _flush_async
             # catch RebuildInProgress)
@@ -1645,7 +1597,7 @@ class TpuRegView:
     def matcher(self, mountpoint: str = "") -> TpuMatcher:
         """Get/create the mountpoint's matcher, warm-loading it INLINE
         from the registry: the synchronous way in, for callers with no
-        running loop to keep responsive (tools, benches, unit tests). A
+        running loop to keep responsive (tools, unit tests). A
         serving broker never comes through the inline load — its paths
         ask :meth:`begin_load`, which builds the table off the loop
         thread. Raises RebuildInProgress while such a load is under way

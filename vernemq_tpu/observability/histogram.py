@@ -112,7 +112,7 @@ STAGE_FAMILIES: List[Tuple[str, str]] = [
      "Exact host-evaluator latency per predicate batch served "
      "host-side (breaker-open/degraded, sub-threshold, or "
      "unrepresentable-escape pairs; the device-vs-host comparison "
-     "base for bench config 13)."),
+     "base)."),
     ("stage_wire_parse_ms",
      "Wire-plane batch parse latency: one recv buffer -> packed frame "
      "table call (native codec or pure-Python twin), observed PER "
@@ -137,7 +137,7 @@ STAGE_FAMILIES: List[Tuple[str, str]] = [
      "Live-handoff freeze-to-adopt pause: the window during which the "
      "moving unit parks new arrivals, observed per completed handoff "
      "(the bounded-pause guarantee; informs "
-     "handoff_freeze_deadline_ms and bench config 15's pause p99)."),
+     "handoff_freeze_deadline_ms)."),
     ("stage_fold_prep_ms",
      "Device fold host prep, per dispatch: entry of match_batch/"
      "match_many to just before the kernel call (matcher-lock wait, "
@@ -482,7 +482,7 @@ def diff(after: Tuple[List[int], float, int],
          before: Tuple[List[int], float, int]) -> Tuple[List[int], float,
                                                         int]:
     """Observation delta between two snapshots of the same family
-    (bench per-config attribution)."""
+    (what a window of a run observed)."""
     return ([max(0, x - y) for x, y in zip(after[0], before[0])],
             max(0.0, after[1] - before[1]), max(0, after[2] - before[2]))
 
@@ -512,8 +512,8 @@ def quantile(counts: Sequence[int], q: float) -> Optional[float]:
 
 
 def summary(snap: Tuple[Sequence[int], float, int]) -> Dict[str, float]:
-    """p50/p99/p99.9 + count/mean for one family snapshot (bench
-    artifacts, graphite exporter)."""
+    """p50/p99/p99.9 + count/mean for one family snapshot (graphite
+    exporter, admin tables)."""
     counts, s, n = snap
     out: Dict[str, float] = {"count": float(n)}
     if n:

@@ -42,47 +42,14 @@ HASH_ID = 2
 FIRST_WORD_ID = 3  # real words intern from here
 
 
-def match_mask(
-    sub_words: jax.Array,  # int32 [S, L]
-    sub_eff_len: jax.Array,  # int32 [S]
-    has_hash: jax.Array,  # bool [S]
-    first_wild: jax.Array,  # bool [S]
-    active: jax.Array,  # bool [S]
-    pub_words: jax.Array,  # int32 [B, L]
-    pub_len: jax.Array,  # int32 [B]
-    pub_dollar: jax.Array,  # bool [B]
-) -> jax.Array:
-    """Boolean match matrix [B, S]."""
-    L = sub_words.shape[1]
-    B = pub_words.shape[0]
-    S = sub_words.shape[0]
-
-    len_ok = jnp.where(
-        has_hash[None, :],
-        pub_len[:, None] >= sub_eff_len[None, :],
-        pub_len[:, None] == sub_eff_len[None, :],
-    )
-    dollar_ok = ~(pub_dollar[:, None] & first_wild[None, :])
-    init = len_ok & dollar_ok & active[None, :]
-
-    def level_body(l, acc):
-        sw = lax.dynamic_index_in_dim(sub_words, l, axis=1, keepdims=False)  # [S]
-        pw = lax.dynamic_index_in_dim(pub_words, l, axis=1, keepdims=False)  # [B]
-        beyond = l >= sub_eff_len  # [S] padded/'#' region always ok
-        ok_l = (sw[None, :] == pw[:, None]) | (sw == PLUS_ID)[None, :] | beyond[None, :]
-        return acc & ok_l
-
-    return lax.fori_loop(0, L, level_body, init)
-
-
 def match_mask_unrolled(
     sub_words, sub_eff_len, has_hash, first_wild, active,
     pub_words, pub_len, pub_dollar,
 ) -> jax.Array:
-    """match_mask with the level loop statically unrolled — one fused
-    elementwise pass over [B, S] instead of L fori_loop round-trips (XLA
-    cannot fuse across fori_loop iterations; measured ~20% faster and it
-    fuses into downstream reductions)."""
+    """Boolean match matrix [B, S], the level loop statically unrolled:
+    one fused elementwise pass over [B, S] (XLA cannot fuse across
+    ``fori_loop`` iterations) that also fuses into downstream
+    reductions."""
     L = sub_words.shape[1]
     len_ok = jnp.where(
         has_hash[None, :],
@@ -150,22 +117,6 @@ def extract_indices(
     return idx.astype(jnp.int32), valid, count
 
 
-def compact_topk(mask: jax.Array, k: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Compress a [B, S] boolean mask into per-row matched indices.
-
-    Returns ``(idx [B, k] int32, valid [B, k] bool, count [B] int32)``.
-    ``count`` may exceed ``k`` (truncated fanout — callers surface this like
-    the reference surfaces queue drops). Uses ``top_k`` over the 0/1 mask;
-    XLA's top_k is stable, so ties (all the 1s) come back in ascending slot
-    order — matching the deterministic fold order of the trie walk.
-    """
-    k = min(k, mask.shape[1])
-    vals, idx = lax.top_k(mask.astype(jnp.int32), k)
-    valid = vals > 0
-    count = jnp.sum(mask, axis=1, dtype=jnp.int32)
-    return idx.astype(jnp.int32), valid, count
-
-
 def _run_chunked(one, pub_words, pub_len, pub_dollar, chunk: int):
     """Apply ``one((pw, plen, pd)) -> (idx, valid, count)`` over the publish
     batch, optionally in ``chunk``-sized pieces via ``lax.map`` to bound the
@@ -199,8 +150,11 @@ def match_extract(
     k: int = 256,
     chunk: int = 0,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Production match path: unrolled fused mask + sort-free extraction.
-    Same contract as :func:`match_topk` but ~100x faster at S=1M on TPU."""
+    """Full-scan match for an unbucketed table: unrolled fused mask +
+    sort-free extraction. Returns ``(idx [B, k] int32, valid [B, k] bool,
+    count [B] int32)``; ``count`` may exceed ``k`` (truncated fanout: the
+    caller matches that publish on the host). ``chunk`` > 0 runs the
+    batch in pieces of that size (B must divide by it)."""
     S = sub_words.shape[0]
     block = 512 if S % 512 == 0 and S >= 512 else S
 
@@ -793,7 +747,7 @@ def _packed_geometry(args) -> dict:
 def call_packed(F_t, t1, meta, args, statics):
     """The one call shape for the packed transport: derives the static
     geometry from the arg shapes, packs the host args, invokes the
-    kernel. Production, bench and tests all go through here so the
+    kernel. The matcher and the tests all go through here so the
     flat_pack_args layout and the kernel's shape contract cannot
     drift apart. (``device.dispatch`` fault-injection point: the
     robustness harness exercises TPU dispatch failure here.)"""
@@ -813,55 +767,6 @@ def unpack_flat_result(out, B: int, C: int):
     count."""
     return (out[:C], out[C:C + B], out[C + B:C + 2 * B],
             out[C + 2 * B:C + 3 * B].astype(bool))
-
-
-def unpack_rows_result(out, B: int, kf: int):
-    """Decode :func:`match_extract_windowed_rows_packed`'s result vector
-    ``[B*kf + 2B]`` into ``(rows [B, kf], total [B], overflow [B]
-    bool)``."""
-    R = B * kf
-    return (out[:R].reshape(B, kf), out[R:R + B],
-            out[R + B:R + 2 * B].astype(bool))
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("B", "L", "T", "TP", "T2", "id_bits",
-                                    "k", "glob_pad", "seg_max", "seg2_max",
-                                    "gc", "kf"))
-def match_extract_windowed_rows_packed(
-    F_t: jax.Array, t1: jax.Array,
-    meta: jax.Array,         # int32 [S] pack_meta word
-    packed: jax.Array,       # int32 [·] flat_pack_args transport vector
-    *,
-    B: int, L: int, T: int, TP: int, T2: int,
-    id_bits: int, k: int, glob_pad: int, seg_max: int, seg2_max: int,
-    gc: int, kf: int,
-) -> jax.Array:
-    """Packed-I/O transport over the gather-merge rows kernel
-    (:func:`match_extract_windowed_rows`): same single-vector in/out as
-    the packed flat kernel but with NO device scatter — the on-chip A/B
-    candidate for hardware where the flat buffer's scatters dominate.
-    Returns one int32 ``[B*kf + 2B]`` vector (see
-    :func:`unpack_rows_result`)."""
-    rows, total, overflow = _windowed_rows_core(
-        F_t, t1, *_unpack_transport(meta, packed, B, L, T, TP, T2),
-        id_bits=id_bits, k=k, glob_pad=glob_pad, seg_max=seg_max,
-        seg2_max=seg2_max, gc=gc, kf=kf)
-    return jnp.concatenate([rows.reshape(-1), total.astype(jnp.int32),
-                            overflow.astype(jnp.int32)])
-
-
-def call_packed_rows(F_t, t1, meta, args, statics):
-    """Rows-kernel analog of :func:`call_packed` (statics carry ``C``;
-    converted to the per-pub cap ``kf`` the rows kernel takes)."""
-    from ..robustness import faults
-
-    faults.inject("device.dispatch")
-    geom = _packed_geometry(args)
-    st = dict(statics)
-    st["kf"] = st.pop("C") // geom["B"]
-    return match_extract_windowed_rows_packed(
-        F_t, t1, meta, flat_pack_args(args), **geom, **st)
 
 
 @functools.partial(jax.jit,
@@ -923,7 +828,7 @@ def _unpack_transport(meta, packed, B, L, T, TP, T2):
 def _packed_core(F_t, t1, meta, packed, *, B, L, T, TP, T2, id_bits, k,
                  glob_pad, seg_max, seg2_max, gc, C):
     """Unpack + match + repack (shared by the jitted packed entry point
-    and the device-resident throughput scan)."""
+    and the K-batch scan of :func:`match_many`)."""
     with jax.named_scope("unpack_transport"):
         unpacked = _unpack_transport(meta, packed, B, L, T, TP, T2)
     flat, pre, total, overflow = _windowed_flat_core(
@@ -931,36 +836,6 @@ def _packed_core(F_t, t1, meta, packed, *, B, L, T, TP, T2, id_bits, k,
         id_bits=id_bits, k=k, glob_pad=glob_pad, seg_max=seg_max,
         seg2_max=seg2_max, gc=gc, C=C)
     return jnp.concatenate([flat, pre, total, overflow.astype(jnp.int32)])
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("B", "L", "T", "TP", "T2", "id_bits",
-                                    "k", "glob_pad", "seg_max", "seg2_max",
-                                    "gc", "C"))
-def match_packed_scan(
-    F_t, t1, meta,
-    packed_stack,            # int32 [N, P] staged transport vectors
-    *,
-    B: int, L: int, T: int, TP: int, T2: int,
-    id_bits: int, k: int, glob_pad: int, seg_max: int, seg2_max: int,
-    gc: int, C: int,
-):
-    """Device-resident throughput probe: run the packed windowed kernel
-    over a stack of pre-staged arg vectors inside ONE executable
-    (``lax.scan`` serialises the steps) and return a checksum + summed
-    match totals, so zero per-batch host<->device traffic and no
-    dead-code elimination. This isolates what the chip's kernel
-    sustains from what the host↔device transport allows."""
-    def step(acc, p):
-        out = _packed_core(F_t, t1, meta, p, B=B, L=L, T=T, TP=TP, T2=T2,
-                           id_bits=id_bits, k=k, glob_pad=glob_pad,
-                           seg_max=seg_max, seg2_max=seg2_max, gc=gc, C=C)
-        chk, tot = acc
-        return (chk + out[:C].sum(), tot + out[C + B:C + 2 * B].sum()), None
-
-    (chk, tot), _ = lax.scan(step, (jnp.int32(0), jnp.int32(0)),
-                             packed_stack)
-    return chk, tot
 
 
 def _match_many_body(
@@ -981,44 +856,20 @@ def _match_many_body(
     return outs
 
 
-#: Stacked transport: run N packed batches inside ONE executable and
-#: return ALL their result vectors ``[N, C + 3B]`` for ONE host pull —
-#: the production-honest sibling of :func:`match_packed_scan` (which
-#: reduces to a checksum). On a latency-dominated link this amortises
-#: the two per-dispatch round trips over N batches; the bytes moved are
-#: the same as N separate packed calls, so it trades per-batch latency
-#: (N windows' worth) for dispatch-overhead amortisation (ROOFLINE.md).
-match_packed_scan_results = functools.partial(
-    jax.jit,
-    static_argnames=("B", "L", "T", "TP", "T2", "id_bits", "k",
-                     "glob_pad", "seg_max", "seg2_max", "gc", "C"),
-)(_match_many_body)
-
-
-#: The production multi-batch entry point: same scanned executable as
-#: :func:`match_packed_scan_results`, but the staging block is DONATED —
-#: the matcher re-stages a fresh super-batch every dispatch, so keeping
-#: the previous stack alive only doubles HBM footprint; donation lets
-#: XLA reuse the staging allocation across dispatches. No host sync
-#: happens between the K scan iterations: K round trips become 1.
+#: The multi-batch entry point: K packed batches run inside ONE
+#: executable (``lax.scan`` over the stacked transport vectors) and all
+#: their result vectors ``[K, C + 3B]`` come back in ONE host pull. The
+#: staging block is DONATED — the matcher re-stages a fresh super-batch
+#: every dispatch, so keeping the previous stack alive only doubles HBM
+#: footprint; donation lets XLA reuse the staging allocation across
+#: dispatches. No host sync happens between the K scan iterations: K
+#: round trips become 1.
 match_many = functools.partial(
     jax.jit,
     static_argnames=("B", "L", "T", "TP", "T2", "id_bits", "k",
                      "glob_pad", "seg_max", "seg2_max", "gc", "C"),
     donate_argnums=(3,),
 )(_match_many_body)
-
-
-def call_packed_stack(F_t, t1, meta, preps, statics):
-    """Stack the packed arg vectors of ``preps`` (each the trailing-args
-    tuple of one batch, same geometry) and run them as ONE executable.
-    Returns the ``[N, C + 3B]`` stacked result device array."""
-    from ..robustness import faults
-
-    faults.inject("device.dispatch")
-    vecs = np.stack([flat_pack_args(a) for a in preps])
-    return match_packed_scan_results(
-        F_t, t1, meta, vecs, **_packed_geometry(preps[0]), **statics)
 
 
 def call_match_many(F_t, t1, meta, preps, statics, device=None):
@@ -1056,153 +907,6 @@ def unpack_many_results(out, B: int, C: int):
     return [unpack_flat_result(o[i], B, C) for i in range(o.shape[0])]
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("id_bits", "k", "glob_pad", "seg_max",
-                                    "seg2_max", "gc", "kf"))
-def match_extract_windowed_rows(
-    F_t: jax.Array, t1: jax.Array, sub_eff_len: jax.Array,
-    has_hash: jax.Array, first_wild: jax.Array, active: jax.Array,
-    pub_words: jax.Array, pub_len: jax.Array, pub_dollar: jax.Array,
-    n_real: jax.Array,
-    t_sel: jax.Array, t_start: jax.Array,
-    t2_sel: jax.Array, t2_start: jax.Array,
-    a_tile: jax.Array, a_pos: jax.Array,
-    b_tile: jax.Array, b_pos: jax.Array,
-    *, id_bits: int, k: int, glob_pad: int, seg_max: int, seg2_max: int,
-    gc: int, kf: int,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Gather-merge variant of :func:`match_extract_windowed_flat`: same
-    three match phases and per-pub gathers, but the per-part results
-    merge into a padded ``[B, kf]`` row per publish via rank-wise selects
-    + take_along_axis — NO scatter (TPU scatters serialize; if the flat
-    buffer's 3x[B,k] scatter dominates on hardware this variant trades
-    it for three gathers at the cost of a fixed per-pub cap ``kf``
-    instead of flat's batch-averaged capacity).
-
-    Returns ``(rows [B, kf] int32, total [B] int32, overflow [B] bool)``;
-    publish i's matched slots are ``rows[i, :total[i]]`` unless
-    ``overflow[i]`` (total > kf, or a part clipped at k).
-    """
-    return _windowed_rows_core(
-        F_t, t1, sub_eff_len, has_hash, first_wild, active,
-        pub_words, pub_len, pub_dollar, n_real, t_sel, t_start,
-        t2_sel, t2_start, a_tile, a_pos, b_tile, b_pos,
-        id_bits=id_bits, k=k, glob_pad=glob_pad, seg_max=seg_max,
-        seg2_max=seg2_max, gc=gc, kf=kf)
-
-
-def _windowed_rows_core(F_t, t1, sub_eff_len, has_hash, first_wild,
-                        active, pub_words, pub_len, pub_dollar, n_real,
-                        t_sel, t_start, t2_sel, t2_start,
-                        a_tile, a_pos, b_tile, b_pos, *,
-                        id_bits, k, glob_pad, seg_max, seg2_max, gc, kf):
-    B = pub_words.shape[0]
-    real = jnp.arange(B, dtype=jnp.int32) < n_real
-
-    gouts = []
-    for c in range(0, B, gc):
-        sl = slice(c, c + gc)
-        G = build_pub_operand(pub_words[sl], id_bits)
-        mm = lax.dot_general(
-            G, F_t[:, :glob_pad], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) + t1[None, :glob_pad]
-        m = (mm == 0.0) & _epilogue(
-            pub_len[sl], pub_dollar[sl], sub_eff_len[:glob_pad],
-            has_hash[:glob_pad], first_wild[:glob_pad], active[:glob_pad])
-        gouts.append(extract_indices_packed(_pack_mask(m), k, 2048))
-    gidx = jnp.concatenate([o[0] for o in gouts], axis=0)
-    gcount = jnp.concatenate([o[2] for o in gouts], axis=0)
-
-    args = (F_t, t1, sub_eff_len, has_hash, first_wild, active,
-            pub_words, pub_len, pub_dollar)
-    tidx, tvalid, tcount = _window_tiles_sel(
-        *args, t_sel, t_start, id_bits=id_bits, k=k,
-        seg_max=seg_max, glob_pad=glob_pad, wild_rows=False)
-    okA = a_tile >= 0
-    at = jnp.maximum(a_tile, 0)
-    aidx = tidx[at, a_pos]
-    acnt = jnp.where(okA, tcount[at, a_pos], 0)
-    if seg2_max:
-        t2idx, t2valid, t2count = _window_tiles_sel(
-            *args, t2_sel, t2_start, id_bits=id_bits, k=k,
-            seg_max=seg2_max, glob_pad=glob_pad, wild_rows=True)
-        okB = b_tile >= 0
-        bt = jnp.maximum(b_tile, 0)
-        bidx = t2idx[bt, b_pos]
-        bcnt = jnp.where(okB, t2count[bt, b_pos], 0)
-    else:
-        bidx = jnp.zeros((B, k), jnp.int32)
-        bcnt = jnp.zeros((B,), jnp.int32)
-
-    clip = (gcount > k) | (acnt > k) | (bcnt > k)
-    gcnt = jnp.minimum(jnp.where(real, gcount, 0), k)
-    acnt = jnp.minimum(jnp.where(real, acnt, 0), k)
-    bcnt = jnp.minimum(jnp.where(real, bcnt, 0), k)
-    total = gcnt + acnt + bcnt
-    r = jnp.arange(kf, dtype=jnp.int32)[None, :]        # [1, kf]
-    offA = gcnt[:, None]
-    offB = (gcnt + acnt)[:, None]
-    inA = (r >= offA) & (r < offB)
-    inB = r >= offB
-    kc = k - 1
-    pick = lambda src, ranks: jnp.take_along_axis(
-        src, jnp.clip(ranks, 0, kc), axis=1)
-    merged = jnp.where(
-        inB, pick(bidx, r - offB),
-        jnp.where(inA, pick(aidx, r - offA), pick(gidx, jnp.minimum(r, kc))))
-    overflow = ((total > kf) | clip) & real
-    return merged, total.astype(jnp.int32), overflow
-
-
-@functools.partial(jax.jit, static_argnames=("id_bits",),
-                   donate_argnums=(0, 1))
-def apply_delta_operands(
-    F_t: jax.Array, t1: jax.Array,
-    slots: jax.Array,     # int32 [D]
-    d_words: jax.Array,   # int32 [D, L]
-    d_eff_len: jax.Array,  # int32 [D]
-    id_bits: int = 16,
-):
-    """Scatter-update the coded operand columns for dirty table slots
-    (companion to :func:`apply_delta` for the derived F/t1 arrays;
-    F_t/t1 are DONATED — see apply_delta's donation note)."""
-    F_d, t1_d = build_operands(d_words, d_eff_len, id_bits)
-    return F_t.at[:, slots].set(F_d), t1.at[slots].set(t1_d)
-
-
-@functools.partial(jax.jit, static_argnames=("k", "chunk"))
-def match_topk(
-    sub_words: jax.Array,
-    sub_eff_len: jax.Array,
-    has_hash: jax.Array,
-    first_wild: jax.Array,
-    active: jax.Array,
-    pub_words: jax.Array,
-    pub_len: jax.Array,
-    pub_dollar: jax.Array,
-    k: int = 256,
-    chunk: int = 0,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Full batched match: mask + top-k compaction.
-
-    ``chunk`` > 0 processes the publish batch in chunks of that size via
-    ``lax.map`` to bound the [B, S] working set (keeps HBM pressure constant
-    as B grows); B must then be a multiple of ``chunk``.
-    """
-    # compact_topk clamps to the table size — do it here too so the chunked
-    # reshape below agrees with the per-chunk result width
-    k = min(k, sub_words.shape[0])
-
-    def one(args):
-        pw, plen, pd = args
-        m = match_mask(sub_words, sub_eff_len, has_hash, first_wild,
-                       active, pw, plen, pd)
-        return compact_topk(m, k)
-
-    return _run_chunked(one, pub_words, pub_len, pub_dollar, chunk)
-
-
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4))
 def apply_delta(
     sub_words: jax.Array,
@@ -1224,9 +928,8 @@ def apply_delta(
     subscriber-db change events incrementally).
 
     The table arrays are DONATED: without donation every functional
-    ``.at[].set`` copies the full S-row array, so a 128-slot delta at 5M
-    subs moved ~500MB of HBM and cost ~300ms (measured, BENCH config 5);
-    with donation XLA scatters in place. Callers must drop their old
+    ``.at[].set`` copies the full S-row array (~500MB of HBM for a
+    128-slot delta at 5M subs); with donation XLA scatters in place. Callers must drop their old
     references (TpuMatcher.sync reassigns _dev_arrays from the return)."""
     sub_words = sub_words.at[slots].set(d_words)
     sub_eff_len = sub_eff_len.at[slots].set(d_eff_len)
@@ -1240,8 +943,6 @@ def apply_delta(
 # current buffers (donating them mid-flight would invalidate the match's
 # args — TpuMatcher.sync picks per call via its in-flight counter)
 apply_delta_copy = jax.jit(apply_delta.__wrapped__)
-apply_delta_operands_copy = jax.jit(apply_delta_operands.__wrapped__,
-                                    static_argnames=("id_bits",))
 
 
 def delta_pack_args(slots, words, eff, hh, fw, ac):
@@ -1300,47 +1001,6 @@ def apply_delta_fused(
 
 apply_delta_fused_copy = jax.jit(apply_delta_fused.__wrapped__,
                                  static_argnames=("D", "L", "id_bits"))
-
-
-@functools.partial(jax.jit, static_argnames=("D", "L", "id_bits"),
-                   donate_argnums=(0, 1, 2, 3, 4, 5, 6))
-def apply_delta_fused_nometa(
-    sub_words, sub_eff_len, has_hash, first_wild, active,  # table [S,·]
-    F_t, t1,                                               # coded operands
-    packed,                                                # delta_pack_args
-    *, D: int, L: int, id_bits: int,
-):
-    """:func:`apply_delta_fused` for matchers running packed_io=False
-    (no pack_meta word): the unpacked transport used to ship SIX arrays
-    and dispatch up to three scatter calls per delta flush — this keeps
-    the delta path at ONE upload + ONE fused scatter there too (every
-    extra per-flush dispatch is a separate executable launch and a
-    separate host↔device round trip). Same donation contract as :func:`apply_delta_fused`.
-
-    Returns ``((sub_words, eff, hh, fw, ac), (F_t, t1))``.
-    """
-    o = 0
-    slots = packed[o:o + D]; o += D
-    w = packed[o:o + D * L].reshape(D, L); o += D * L
-    e = packed[o:o + D]; o += D
-    nh = packed[o:o + D].astype(bool); o += D
-    nf = packed[o:o + D].astype(bool); o += D
-    na = packed[o:o + D].astype(bool)
-    sub_words = sub_words.at[slots].set(w)
-    sub_eff_len = sub_eff_len.at[slots].set(e)
-    has_hash = has_hash.at[slots].set(nh)
-    first_wild = first_wild.at[slots].set(nf)
-    active = active.at[slots].set(na)
-    F_d, t1_d = build_operands(w, e, id_bits)
-    F_t = F_t.at[:, slots].set(F_d)
-    t1 = t1.at[slots].set(t1_d)
-    return ((sub_words, sub_eff_len, has_hash, first_wild, active),
-            (F_t, t1))
-
-
-apply_delta_fused_nometa_copy = jax.jit(
-    apply_delta_fused_nometa.__wrapped__,
-    static_argnames=("D", "L", "id_bits"))
 
 
 @functools.partial(jax.jit, static_argnames=("D", "L", "id_bits", "glob"),

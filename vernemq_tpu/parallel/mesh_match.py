@@ -128,8 +128,8 @@ class MeshMatcher(ShardedWindowedMatcher):
         super().__init__(table, mesh, max_fanout=max_fanout,
                          with_total=with_total, flat_avg=flat_avg,
                          merge=merge)
-        # slice-routing accounting (bench config 12 / `vmq-admin mesh
-        # show` / mesh_* gauges)
+        # slice-routing accounting (`vmq-admin mesh show` / mesh_*
+        # gauges)
         self.route_flushes = 0          # slice-routed delta flushes
         self.route_dirty_slices = 0     # dirty slices scattered, cumulative
         self.route_gzone_flushes = 0    # flushes that touched the g-zone
@@ -205,7 +205,7 @@ class MeshMatcher(ShardedWindowedMatcher):
         arrays reassembled zero-copy. A flush whose dirty rows all fall
         outside the g-zone leaves every replica mirror untouched too —
         there is no full-table scatter path here at all (the routing
-        guarantee bench config 12 asserts)."""
+        guarantee ``tests/test_mesh_match.py`` asserts)."""
         t = self.table
         t0 = time.monotonic()
         slots = np.fromiter(t.dirty, dtype=np.int32)
@@ -391,7 +391,7 @@ class MeshMatcher(ShardedWindowedMatcher):
     # -------------------------------------------------------------- status
 
     def mesh_status(self) -> Dict[str, Any]:
-        """Routing + residency snapshot for admin/gauges/bench. The
+        """Routing + residency snapshot for admin and gauges. The
         per-slice row counts are an O(S) active-mask reduction — cached
         per device generation (flush/build counters) so every metrics
         scrape and $SYS tick doesn't rescan a 10M-row table."""

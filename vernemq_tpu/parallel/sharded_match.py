@@ -698,7 +698,7 @@ class ShardedTpuMatcher(TpuMatcher):
         cap = max(initial_capacity, 4096 * nsub, 32768)
         super().__init__(max_levels=max_levels, initial_capacity=cap,
                          max_fanout=max_fanout, flat_avg=flat_avg,
-                         packed_io=False, use_pallas=False)
+                         use_pallas=False)
         self.mesh = mesh
         # merge=True: the production posture — results merged across the
         # 'sub' axis on device (ICI all_gather), so the host pulls ONE

@@ -30,7 +30,7 @@ Two primitives, both over ``multiprocessing.shared_memory``:
   reads everyone else's: this is how per-worker ``OverloadGovernor``
   instances fuse into one cluster-style aggregate pressure level, how
   histograms and the event journal merge at the scrape point, and
-  what ``vmq-admin workers show`` / bench config 11 read.
+  what ``vmq-admin workers show`` reads.
 
 Blocking helpers (``pop_wait``/``push_wait``) exist for plain-thread
 consumers (the match service's drainer). They must never be called from

@@ -79,7 +79,7 @@ K_PING = 4     #: PINGREQ / PINGRESP
 _cached = False
 _native = None
 _pure_warned = False
-#: test/bench hook: force the pure-Python plane (parse_batch + headers
+#: test hook: force the pure-Python plane (parse_batch + headers
 #: + the per-frame parse in the codecs consult load_native once at
 #: import, so tests swap codec_v4._C/_C5 alongside this)
 _force_pure = False

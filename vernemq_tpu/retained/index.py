@@ -86,7 +86,7 @@ class RetainedIndex:
         self._NB = 1
         self._inflight = 0  # dispatched matches holding the device arrays
         # background growth rebuild (RebuildInProgress → host walk serves);
-        # bare indexes in benches/tests time the inline path instead
+        # bare indexes in tests take the inline path instead
         self.async_rebuild = True
         self._rebuild_thread: Optional[threading.Thread] = None
         # stall watchdog (robustness/watchdog.py): background rebuilds
@@ -145,7 +145,7 @@ class RetainedIndex:
         host store (the boot warm-load of ``vmq_retain_srv``'s cache,
         here store → device table). Call before serving; deltas arrive
         via :meth:`on_retain` afterwards. Synchronous variant for
-        tests/bench/direct embedding — the broker path uses
+        tests and direct embedding — the broker path uses
         :meth:`warm_load_async` so a million-topic load cannot stall
         the event loop."""
         with self.lock:
@@ -710,7 +710,7 @@ class RetainedEngine:
 
     def index(self, mountpoint: str = "") -> RetainedIndex:
         """Get/create the mountpoint's index, warm-loading SYNCHRONOUSLY
-        on first use — the tests/bench/embedding entry point. Call on
+        on first use — the tests' and embedders' entry point. Call on
         the event-loop thread (store mutation is loop-side); broker
         serving goes through :meth:`index_async` instead so a large
         warm load cannot stall the loop."""

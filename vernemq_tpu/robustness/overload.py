@@ -70,8 +70,8 @@ staged cheapest-first and strictly additive:
   backlog).
 
 ``overload_mode=binary`` keeps the legacy behaviour (the flag + fixed
-0.1s sleep, no graded responses) so the two postures can be A/B'd —
-bench config 9 ("overload storm") runs both. ``vmq-admin overload
+0.1s sleep, no graded responses) so the two postures can be A/B'd
+(``tests/test_overload.py`` drives both). ``vmq-admin overload
 show|set-level`` surfaces the state and pins a level for drills, like
 ``breaker trip``. These levels are the hardware-tuning surface for
 ROADMAP's fault-storms item: on the real chip the ``tpu_breaker_*``

@@ -72,7 +72,7 @@ class ResumeCollector:
         self.defer_gate = None
         self._defers_in_row = 0
         self._defer_armed = False
-        # observability (broker gauges / bench artifact)
+        # observability (broker gauges)
         self.batched_sessions = 0    # sessions served by a batched read
         self.batched_reads = 0       # executor read_many calls
         self.host_sessions = 0       # small flushes served per-session

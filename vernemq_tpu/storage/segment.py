@@ -709,8 +709,7 @@ def open_engine(directory: str, filename: str = "store",
     pure-Python segment twin otherwise (or with ``prefer="segment"``),
     a :class:`MemEngine` when ``directory`` is empty. Same interface
     across all three — callers learn which one served from
-    ``engine.kind`` (the bench artifacts record it so partition-storm /
-    reconnect-storm numbers are comparable across boxes)."""
+    ``engine.kind``."""
     if not directory:
         return MemEngine()
     os.makedirs(directory, exist_ok=True)
