@@ -12,7 +12,9 @@ import contextlib
 import pytest
 
 from vernemq_tpu.broker.config import Config
-from vernemq_tpu.broker.server import start_broker
+from vernemq_tpu.broker.egress import JOIN_MAX, Outbox
+from vernemq_tpu.broker.metrics import Metrics
+from vernemq_tpu.broker.server import StreamTransport, start_broker
 from vernemq_tpu.client import MQTTClient
 from vernemq_tpu.protocol import codec_v4, codec_v5, fastpath, wire
 from vernemq_tpu.protocol.types import (Connect, Publish, SubOpts,
@@ -443,37 +445,200 @@ async def test_wire_metrics_and_stage_families_exposed():
         await server.stop()
 
 
+class SockSpy:
+    """An asyncio transport's write side, recording each call."""
+
+    def __init__(self, fail=False):
+        self.calls = []  # ("write", data) | ("writelines", [chunks])
+        self.fail = fail
+        self.closed = False
+
+    def write(self, data):
+        if self.fail:
+            raise OSError("broken pipe")
+        self.calls.append(("write", data))
+
+    def writelines(self, chunks):
+        if self.fail:
+            raise OSError("broken pipe")
+        self.calls.append(("writelines", list(chunks)))
+
+    def close(self):
+        self.closed = True
+
+    def stream(self):
+        return b"".join(bytes(c) for kind, d in self.calls
+                        for c in ([d] if kind == "write" else d))
+
+
+def egress_counts():
+    return (fastpath.egress_flushes, fastpath.egress_writes,
+            fastpath.egress_joined, fastpath.egress_scattered)
+
+
+def _outbox():
+    return Outbox(Metrics())
+
+
+async def _outbox_bytes_are_those_of_sequential_writes():
+    ob = _outbox()
+    socks = [SockSpy(), SockSpy()]
+    ts = [StreamTransport(s, ob) for s in socks]
+    ts[0].write(b"aa")
+    ts[1].write(b"11")
+    ts[0].write_iov((b"bb", b"cc"))
+    ts[1].write_iov((b"22", memoryview(b"33")))
+    ts[0].write(b"dd")
+    assert [s.calls for s in socks] == [[], []]  # nothing until the flush
+    ob.flush()
+    assert [s.stream() for s in socks] == [b"aabbccdd", b"112233"]
+    assert [len(s.calls) for s in socks] == [1, 1]  # ONE write each
+    ob.flush()  # nothing pending: a no-op
+    assert [len(s.calls) for s in socks] == [1, 1]
+    ts[0].write(b"ee")
+    ob.flush()
+    assert socks[0].stream() == b"aabbccddee"
+    assert socks[0].calls[-1] == ("write", b"ee")
+
+
+async def _outbox_schedules_one_callback_a_turn():
+    ob = _outbox()
+    loop = asyncio.get_running_loop()
+    scheduled = []
+    call_soon = loop.call_soon
+
+    def counting(cb, *a, **kw):
+        if cb == ob.flush:  # asyncio's own task steps are not ours
+            scheduled.append(cb)
+        return call_soon(cb, *a, **kw)
+
+    loop.call_soon = counting
+    try:
+        socks = [SockSpy() for _ in range(5)]
+        ts = [StreamTransport(s, ob) for s in socks]
+        flushes, writes, _, _ = egress_counts()
+        for i, t in enumerate(ts):
+            t.write(b"%d" % i)
+            t.write(b"x")
+        ob.touch()  # a counter-only caller adds no second callback
+        assert len(scheduled) == 1
+        await asyncio.sleep(0)  # the next turn: the callback ran
+        assert [s.stream() for s in socks] == \
+            [b"%dx" % i for i in range(5)]  # in listing order, each whole
+        assert egress_counts()[:2] == (flushes + 1, writes + 5)
+        ts[2].write(b"again")  # the next turn's first write lists anew
+        assert len(scheduled) == 2
+        await asyncio.sleep(0)
+        assert socks[2].stream() == b"2xagain"
+    finally:
+        del loop.call_soon
+
+
+async def _outbox_joins_small_iovecs_and_scatters_large_ones():
+    ob = _outbox()
+    small, large, single = SockSpy(), SockSpy(), SockSpy()
+    hdr, payload = b"\x32\x0a\x00\x01t\x00\x01", b"p" * 16
+    big = b"B" * JOIN_MAX  # header + this is over the bound
+    StreamTransport(small, ob).write_iov((hdr, payload))
+    StreamTransport(large, ob).write_iov((hdr, big))
+    StreamTransport(single, ob).write(big + big)
+    _, writes, joined, scattered = egress_counts()
+    ob.flush()
+    assert small.calls == [("write", hdr + payload)]  # one plain send
+    (kind, chunks), = large.calls
+    assert kind == "writelines" and chunks[1] is big  # never copied here
+    assert single.calls == [("write", big + big)]  # one chunk: as it is
+    assert egress_counts()[1:] == (writes + 3, joined + 1, scattered + 1)
+
+
+async def _outbox_walk_survives_a_raising_transport():
+    ob = _outbox()
+    socks = [SockSpy(), SockSpy(fail=True), SockSpy()]
+    ts = [StreamTransport(s, ob) for s in socks]
+    for t in ts:
+        t.write(b"one")
+    ob.flush()
+    assert [s.stream() for s in socks] == [b"one", b"", b"one"]
+    assert [t.closed for t in ts] == [False, True, False]
+    ts[1].write(b"two")  # a closed transport takes no more writes
+    assert ob._handle is None and ts[1]._chunks == []
+
+
+async def _outbox_close_flushes_and_a_closed_listing_is_skipped():
+    ob = _outbox()
+    socks = [SockSpy(), SockSpy()]
+    ts = [StreamTransport(s, ob) for s in socks]
+    ts[0].write_iov((b"will", b"go"))
+    ts[1].write(b"stays")
+    ts[0].close()  # its own chunks first, then the socket
+    assert socks[0].calls == [("write", b"willgo")] and socks[0].closed
+    _, writes, _, _ = egress_counts()
+    ob.flush()  # still listed, now closed and empty: skipped
+    assert len(socks[0].calls) == 1 and socks[1].stream() == b"stays"
+    assert egress_counts()[1] == writes + 1
+    ts[0].close()  # idempotent
+    assert len(socks[0].calls) == 1
+
+
+async def _outbox_counters_equal_per_write_accounting():
+    """A QoS1 publish to one QoS1 and one QoS0 subscriber over real
+    sockets: once the bytes are read, the six folded counters have
+    moved by exactly what the sockets saw."""
+    names = ("bytes_sent", "mqtt_publish_sent", "mqtt_puback_sent",
+             "queue_message_in", "queue_message_out",
+             "router_matches_local")
+    broker, server = await boot()
+    try:
+        subs = []
+        for i in range(2):
+            sub = await Raw.connect(server.port, "ecsub%d" % i)
+            await sub.send(codec_v4.serialise(Subscribe(
+                packet_id=1, topics=[("q/#", SubOpts(qos=i))])))
+            await sub.read_frames(2)
+            subs.append(sub)
+        pub = await Raw.connect(server.port, "ecpub")
+        before = [broker.metrics.value(n) for n in names]
+        seen = [len(r.buf) for r in subs + [pub]]
+        await pub.send(q_publish(0) + q_publish(1))
+        await pub.read_frames(1 + 2)
+        for sub in subs:
+            await sub.read_frames(2 + 2)
+        got = sum(len(r.buf) - n for r, n in zip(subs + [pub], seen))
+        n = len(q_publish(0))  # QoS1 delivery; QoS0 has no packet id
+        assert got == 2 * (n + n - 2) + 2 * 4  # deliveries and PUBACKs
+        assert [broker.metrics.value(n) - b
+                for n, b in zip(names, before)] == [got, 4, 2, 4, 4, 4]
+        ob = broker.outbox
+        assert (ob.bytes_sent, ob.publish_sent, ob.puback_sent,
+                ob.queue_in, ob.queue_out, ob.matches_local) == (0,) * 6
+        for r in subs + [pub]:
+            r.close()
+    finally:
+        await broker.stop()
+        await server.stop()
+
+
+OUTBOX_CASES = {
+    "sequential_bytes": _outbox_bytes_are_those_of_sequential_writes,
+    "one_callback_a_turn": _outbox_schedules_one_callback_a_turn,
+    "join_small_scatter_large":
+        _outbox_joins_small_iovecs_and_scatters_large_ones,
+    "raising_transport": _outbox_walk_survives_a_raising_transport,
+    "close_and_closed_listing":
+        _outbox_close_flushes_and_a_closed_listing_is_skipped,
+    "counters_fold": _outbox_counters_equal_per_write_accounting,
+}
+
+
 @pytest.mark.asyncio
-async def test_stream_transport_iovec_flush():  # async: write() schedules
-    # its flush on the running loop; the test then drives _flush by hand
-    """StreamTransport coalesces iovec chunks and flushes them as ONE
-    writelines tick, byte-identical to sequential writes."""
-    from vernemq_tpu.broker.server import StreamTransport
-
-    written = []
-
-    class W:
-        def write(self, data):
-            written.append(bytes(data))
-
-        def writelines(self, chunks):
-            written.append(b"".join(chunks))
-
-        def close(self):
-            pass
-
-    t = StreamTransport(W())
-    t.write(b"aa")
-    t.write_iov((b"bb", b"cc"))
-    t.write(b"dd")
-    assert written == []  # nothing until the scheduled flush
-    t._flush()
-    assert written == [b"aabbccdd"]
-    t._flush()  # empty flush is a no-op
-    assert written == [b"aabbccdd"]
-    t.write(b"ee")
-    t._flush()
-    assert written == [b"aabbccdd", b"ee"]
+@pytest.mark.parametrize("case", sorted(OUTBOX_CASES))
+async def test_stream_transport_iovec_flush(case):
+    """StreamTransport collects a turn's chunks per connection and the
+    broker's Outbox flushes every transport written in the turn from
+    ONE callback: per-connection bytes identical to sequential writes,
+    the form of the write chosen from the pending bytes, the egress
+    counters folded ahead of the writes."""
+    await OUTBOX_CASES[case]()
 
 
 class Raw5(Raw):
@@ -1509,6 +1674,177 @@ async def test_a_turns_chunks_are_served_together_by_one_callback():
         del server._serve_inbox
         for r in pubs + [sub]:
             r.close()
+    finally:
+        await broker.stop()
+        await server.stop()
+
+
+@contextlib.contextmanager
+def counted_gates():
+    """Each evaluation of the broker-wide half of the wire gate by the
+    listener's ``_serve_inbox``, with its verdict."""
+    from vernemq_tpu.broker import server as server_mod
+
+    verdicts = []
+    orig = server_mod.wire_broker_ready
+
+    def counting(broker):
+        verdicts.append(orig(broker))
+        return verdicts[-1]
+
+    server_mod.wire_broker_ready = counting
+    try:
+        yield verdicts
+    finally:
+        server_mod.wire_broker_ready = orig
+
+
+async def _gate_fleet(server, broker, n=3):
+    """A QoS0 subscriber and ``n`` parked publishers."""
+    sub = await Raw.connect(server.port, "gtsub")
+    await sub.send(codec_v4.serialise(Subscribe(
+        packet_id=1, topics=[("q/#", SubOpts(qos=0))])))
+    await sub.read_frames(2)
+    pubs = [await Raw.connect(server.port, "gtpub%d" % i)
+            for i in range(n)]
+    protos = [proto_of(broker, "gtpub%d" % i) for i in range(n)]
+    for p in protos:
+        await parked(p)
+    return sub, pubs, protos
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("edge", [None, "hook", "governor"])
+async def test_a_pass_evaluates_the_broker_wide_gate_once(edge):
+    """One ``_serve_inbox`` pass evaluates the broker-wide half of the
+    wire gate ONCE, whatever the number of chunks. Open, every chunk is
+    served inline and the pass itself — admissions, fanout writes,
+    acknowledgements — leaves the verdict where it was. A hook or a
+    raised governor level set BETWEEN two turns closes the gate for
+    every chunk of the next pass: all go to their tasks, which serve
+    them on the classic path."""
+    from vernemq_tpu.broker.session import wire_broker_ready
+
+    broker, server = await boot()
+    try:
+        sub, pubs, protos = await _gate_fleet(server, broker)
+        # a first turn with the gate open
+        with counted_gates() as verdicts:
+            inline, task = chunk_counts()
+            for i, p in enumerate(protos):
+                p.data_received(q_publish(i))
+            await asyncio.sleep(0)
+            assert verdicts == [True]
+            assert chunk_counts() == (inline + 3, task)
+            assert wire_broker_ready(broker)  # the pass did not move it
+        for r in pubs:
+            await r.read_frames(2)
+        if edge == "hook":
+            broker.hooks.register("on_publish", lambda *a, **kw: None)
+        elif edge == "governor":
+            broker.overload.pin(1)
+        with counted_gates() as verdicts:
+            inline, task = chunk_counts()
+            classic = fastpath.classic_pubs_qos
+            for i, p in enumerate(protos):
+                p.data_received(q_publish(3 + i))
+            await asyncio.sleep(0)
+            assert verdicts == [edge is None]
+            if edge is None:
+                assert chunk_counts() == (inline + 3, task)
+            else:
+                assert chunk_counts() == (inline, task + 3)
+        for r in pubs:
+            acks = (await r.read_frames(3))[1:]
+            assert [type(a).__name__ for a in acks] == ["Puback"] * 2
+        if edge is not None:
+            assert fastpath.classic_pubs_qos == classic + 3
+        frames = (await sub.read_frames(2 + 6))[2:]
+        assert sorted(f.payload for f in frames) == \
+            [b"q%04d" % i for i in range(6)]
+        if edge == "governor":
+            broker.overload.pin(None)
+        for r in pubs + [sub]:
+            r.close()
+    finally:
+        await broker.stop()
+        await server.stop()
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("state", ["closed", "disconnected"])
+async def test_a_pass_tests_the_sessions_half_per_chunk(state):
+    """Inside one pass, behind an open broker-wide gate, a session that
+    is closed or no longer connected still falls to its task; its
+    neighbours are served inline."""
+    broker, server = await boot()
+    try:
+        sub, pubs, protos = await _gate_fleet(server, broker)
+        odd = session_of(broker, "gtpub1")
+        if state == "closed":
+            odd.closed = True
+        else:
+            odd.connected = False
+        with counted_gates() as verdicts:
+            inline, task = chunk_counts()
+            fast = fastpath.fastpath_pubs_qos
+            for i, p in enumerate(protos):
+                p.data_received(q_publish(i))
+            await asyncio.sleep(0)
+            assert verdicts == [True]
+            assert chunk_counts() == (inline + 2, task + 1)
+            assert fastpath.fastpath_pubs_qos == fast + 2
+        assert protos[1]._session is None  # woken: the task has the bytes
+        for r in (pubs[0], pubs[2]):
+            ack = (await r.read_frames(2))[1]
+            assert type(ack).__name__ == "Puback"
+        for r in pubs + [sub]:
+            r.close()
+    finally:
+        await broker.stop()
+        await server.stop()
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("edge", ["tracer", "governor", "hook", "rate",
+                                  "disabled", "netsplit", "closed",
+                                  "disconnected"])
+async def test_the_task_side_gate_is_the_whole_gate(edge):
+    """``Session.wire_fast_ready`` — what the connection's task checks
+    per batch and after every await — is both halves: each broker-wide
+    edge closes it with the session's half open, each session edge with
+    the broker-wide half open."""
+    from vernemq_tpu.broker.session import wire_broker_ready
+
+    broker, server = await boot()
+    try:
+        pub = await Raw.connect(server.port, "wgpub")
+        session = session_of(broker, "wgpub")
+        assert session.wire_fast_ready()
+        if edge == "tracer":
+            broker.start_trace("nobody-by-this-name")
+        elif edge == "governor":
+            broker.overload.pin(2)
+        elif edge == "hook":
+            broker.hooks.register("auth_on_publish", lambda *a, **kw: None)
+        elif edge == "rate":
+            broker.config.set("max_message_rate", 10)
+        elif edge == "disabled":
+            broker.config.set("wire_fastpath_enabled", False)
+        elif edge == "netsplit":
+            broker._cluster_ready = False
+        elif edge == "closed":
+            session.closed = True
+        else:
+            session.connected = False
+        session_edge = edge in ("closed", "disconnected")
+        assert wire_broker_ready(broker) is session_edge
+        assert session.wire_session_ready() is not session_edge
+        assert not session.wire_fast_ready()
+        if edge == "governor":
+            broker.overload.pin(None)
+        session.closed = False
+        pub.close()
     finally:
         await broker.stop()
         await server.stop()

@@ -21,6 +21,7 @@ from ..protocol.types import Will
 from ..robustness import faults
 from ..storage.msg_store import FileMsgStore, MemoryMsgStore, MsgStore
 from .config import Config
+from .egress import Outbox
 from .message import Msg, SubscriberId
 from .metrics import Metrics
 from .plugins import HookError, HookRegistry
@@ -42,6 +43,8 @@ class Broker:
         self.node_name = node_name
         self._resolve_base_dirs()
         self.metrics = Metrics()
+        # a loop turn's socket writes and egress counters (broker/egress.py)
+        self.outbox = Outbox(self.metrics)
         self.hooks = HookRegistry()
         from ..plugins import PluginManager
 
@@ -410,6 +413,23 @@ class Broker:
                                 "record, a run bound. With "
                                 "wire_inline_chunks, how often the "
                                 "inline run engages.",
+            "wire_egress_flushes": "Outbox flushes (broker/egress.py): "
+                                   "one per loop turn in which any "
+                                   "stream transport was written or an "
+                                   "egress counter moved.",
+            "wire_egress_writes": "Transports those flushes wrote: one "
+                                  "socket write each, whatever the "
+                                  "number of frames queued on it in "
+                                  "the turn.",
+            "wire_egress_joined": "Of those, flushes of several chunks "
+                                  "(a delivery's header + payload, a "
+                                  "run of acks) small enough to leave "
+                                  "as ONE joined write (a plain send).",
+            "wire_egress_scattered": "Of those, flushes of several "
+                                     "chunks over the join bound, sent "
+                                     "through writelines so a shared "
+                                     "payload is not copied per "
+                                     "recipient.",
             "wire_fanout_batches": "One-call batched fanout header "
                                    "encodes (publish_headers_batch): "
                                    "each emitted N per-recipient "
@@ -1554,6 +1574,7 @@ class Broker:
                 dispatch_deadline_ms=self._dispatch_deadline_ms(),
                 item_expiry_ms=self._collector_expiry_ms(),
                 filter_engine=self.filter_engine,
+                after_release=self.outbox.flush,
             )
         return self._collector
 

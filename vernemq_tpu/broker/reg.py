@@ -989,12 +989,15 @@ class Registry:
             m.observe("stage_wire_encode_ms",
                       (time.monotonic() - t0) * 1e3)
             self.fanout_fast_pubs += 1
-            m.incr("queue_message_in", sent + parked)
-            m.incr("queue_message_out", sent)
-            if nbytes:
-                m.incr("bytes_sent", nbytes)
-            m.incr("mqtt_publish_sent", sent)
-            m.incr("router_matches_local", len(recips))
+            # plain integers, folded into Metrics by the outbox's flush
+            # ahead of this turn's writes (broker/egress.py)
+            ob = self.broker.outbox
+            ob.queue_in += sent + parked
+            ob.queue_out += sent
+            ob.bytes_sent += nbytes
+            ob.publish_sent += sent
+            ob.matches_local += len(recips)
+            ob.touch()
 
     def _pre_publish(self, msg: Msg) -> Msg:
         cfg = self.broker.config

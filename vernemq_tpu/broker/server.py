@@ -4,8 +4,9 @@ Mirrors the reference socket layer: one lightweight task per connection
 (``vmq_ranch.erl:41-43`` — one Erlang process per socket), buffered reparse
 of incoming bytes driving the session FSM (``vmq_ranch.erl:167-251``),
 write coalescing per event-loop tick (the MSS flush-threshold batching of
-``vmq_ranch.erl:253-262``), and protocol detection on the first CONNECT
-frame choosing the v4 or v5 FSM (``vmq_mqtt_pre_init.erl:58-70``).
+``vmq_ranch.erl:253-262``; ``broker/egress.py``), and protocol detection
+on the first CONNECT frame choosing the v4 or v5 FSM
+(``vmq_mqtt_pre_init.erl:58-70``).
 
 What runs where. An ``mqtt`` / ``mqtts`` listener reads its sockets at
 the protocol level (``MqttProtocol``): the connection's task runs
@@ -18,10 +19,24 @@ for a chunk that holds nothing else. In two phases: ``data_received``
 only notes the chunk, so a loop turn's socket reads run back to back,
 and ONE callback of the listener (``MQTTServer._serve_inbox``), first
 in the next turn, serves all of them. The first record it cannot serve
-goes to the task with every byte behind it. WebSocket and
-PROXY-protocol listeners need a reader of their own for their first
-bytes and hand the task a ``read_chunk``; both forms walk a frame
-table's fast stretch through the one ``wire_run``.
+goes to the task with every byte behind it. That callback evaluates
+the broker-wide half of the wire gate once for the pass
+(``session.wire_broker_ready``); a chunk tests only its session's half,
+and the task keeps the whole gate (``Session.wire_fast_ready``).
+WebSocket and PROXY-protocol listeners need a reader of their own for
+their first bytes and hand the task a ``read_chunk``; both forms walk a
+frame table's fast stretch through the one ``wire_run``.
+
+The turn is the unit of the WRITES as well (``broker/egress.py``). A
+``StreamTransport`` — every ``mqtt`` / ``mqtts`` / PROXY connection's —
+only collects its chunks and lists itself in the broker's ``Outbox``;
+one flush walks the turn's transports back to back, one socket write
+each, after folding the turn's egress counters into ``Metrics``. It
+runs at the end of the callback that filled it — a release chunk of the
+collector (``BatchCollector._release``: 64 deliveries and 64 PUBACKs,
+each after its route returned), ``_serve_inbox`` under the trie view —
+and otherwise as the outbox's own callback, first in the next turn
+(a task's CONNACK, SUBACK, PINGRESP).
 """
 
 from __future__ import annotations
@@ -40,7 +55,8 @@ from ..protocol.types import (
 )
 from ..utils.aio import close_server
 from .broker import Broker
-from .session import Session, Transport
+from .egress import StreamTransport
+from .session import Session, Transport, wire_broker_ready
 from .websocket import WsError
 
 log = logging.getLogger("vernemq_tpu.server")
@@ -51,68 +67,6 @@ CONNECT_TIMEOUT = 10.0
 #: batched view) before it waits for them to come back
 FRAME_RUN = 64
 MAX_FRAME_SIZE = 268435455
-
-
-class StreamTransport(Transport):
-    """Write-coalescing wrapper over an asyncio transport: session
-    writes within one loop tick collect into ONE iovec (a chunk list)
-    that the flush hands to ``writelines`` — one C-level join + one
-    syscall-bound send per loop iteration, however many small
-    PUBACK/PUBLISH frames landed in it. Compared to the previous
-    single-bytearray coalescer this removes the per-write append copy
-    entirely: a fanout's shared payload bytes object is referenced from
-    every recipient's iovec and only touched once, inside the
-    transport's join. The list swap at flush keeps the PR 7
-    swap-not-copy behaviour whether or not the native encoder is
-    present."""
-
-    def __init__(self, transport: asyncio.WriteTransport):
-        self._transport = transport
-        self._chunks: list = []
-        self._flush_scheduled = False
-        self.closed = False
-
-    def write(self, data: bytes) -> None:
-        if self.closed:
-            return
-        self._chunks.append(data)
-        if not self._flush_scheduled:
-            self._flush_scheduled = True
-            asyncio.get_event_loop().call_soon(self._flush)
-
-    def write_iov(self, chunks) -> None:
-        """Queue a writev-ready iovec (e.g. the native encoder's
-        (header, payload) pair) without assembling a per-frame bytes
-        object."""
-        if self.closed:
-            return
-        self._chunks.extend(chunks)
-        if not self._flush_scheduled:
-            self._flush_scheduled = True
-            asyncio.get_event_loop().call_soon(self._flush)
-
-    def _flush(self) -> None:
-        self._flush_scheduled = False
-        if self.closed or not self._chunks:
-            return
-        chunks, self._chunks = self._chunks, []
-        try:
-            if len(chunks) == 1:
-                self._transport.write(chunks[0])
-            else:
-                self._transport.writelines(chunks)
-        except Exception:
-            self.closed = True
-
-    def close(self) -> None:
-        if self.closed:
-            return
-        self._flush()
-        self.closed = True
-        try:
-            self._transport.close()
-        except Exception:
-            pass
 
 
 def parse_nodelay_option(raw: str) -> Optional[bool]:
@@ -533,15 +487,16 @@ class MqttProtocol(asyncio.Protocol):
 
     # ------------------------------------------------ the inline run
 
-    def _serve(self, session: Session, data: bytes) -> None:
+    def _serve(self, session: Session, data: bytes, gate: bool) -> None:
         """One recv chunk while the task is parked (from the listener's
-        ``_serve_inbox``): the per-chunk gate, the batch parse, the fast
-        records. Served whole, nobody is woken; else the task gets the
-        bytes from the first record this could not serve (a closed
-        gate: all of them)."""
+        ``_serve_inbox``, which evaluated ``gate``, the broker-wide half
+        of ``wire_fast_ready``, once for the pass): the session's half
+        of the gate, the batch parse, the fast records. Served whole,
+        nobody is woken; else the task gets the bytes from the first
+        record this could not serve (a closed gate: all of them)."""
         buf = self._tail + data if self._tail else data
         try:
-            if session.wire_fast_ready():
+            if gate and session.wire_session_ready():
                 tok = obs.span_begin("stage_wire_parse_ms")
                 try:
                     table, nrec, consumed = fastpath.parse_batch(
@@ -617,7 +572,8 @@ class MqttProtocol(asyncio.Protocol):
                 transport.close()  # cert required for identity mapping
                 return
             await mqtt_connection(
-                srv.broker, self, StreamTransport(transport),
+                srv.broker, self,
+                StreamTransport(transport, srv.broker.outbox),
                 transport.get_extra_info("peername") or ("", 0),
                 srv.max_frame_size, preauth_user=preauth,
                 mountpoint=srv.mountpoint,
@@ -700,13 +656,19 @@ class MQTTServer:
         order by this one callback — scheduled by the first of them, so
         it runs ahead of the next turn's reads and timers. A connection
         woken meanwhile (EOF, a lost socket) keeps its bytes for its
-        task."""
+        task. The broker-wide half of the wire gate is evaluated ONCE
+        for the pass (``wire_broker_ready``: why nothing in the pass
+        can move it); each chunk tests its session's half. What the
+        pass wrote (the trie view routes and acknowledges inline)
+        leaves at its end, ahead of the turn's reads."""
         inbox, self._inbox = self._inbox, []
+        gate = wire_broker_ready(self.broker)
         for proto in inbox:
             session = proto._session
             if session is not None:
                 data, proto._buf = proto._buf, b""
-                proto._serve(session, data)
+                proto._serve(session, data, gate)
+        self.broker.outbox.flush()
 
     def _admit(self, conn) -> bool:
         """Count an accepted connection in, unless the listener is at
@@ -765,7 +727,7 @@ class MQTTServer:
         try:
             await mqtt_connection(
                 self.broker, lambda: reader.read(65536),
-                StreamTransport(writer.transport), peer,
+                StreamTransport(writer.transport, self.broker.outbox), peer,
                 self.max_frame_size, preauth_user=preauth,
                 mountpoint=self.mountpoint,
                 allowed_protocol_versions=self.allowed_protocol_versions)

@@ -168,7 +168,9 @@ class Session:
             self.broker.trace_frame("out", self.mountpoint, self.client_id, frame)
         data = self.codec.serialise(frame)
         self.transport.write(data)
-        self.broker.metrics.incr("bytes_sent", len(data))
+        ob = self.broker.outbox  # folded into Metrics once a loop turn
+        ob.bytes_sent += len(data)
+        ob.touch()
 
     def _metric_in(self, frame: Frame) -> None:
         m = _IN_METRIC.get(type(frame))
@@ -727,35 +729,22 @@ class Session:
         publishes, plus the 2-byte ack family): True only when NO
         per-publish Python edge applies — no tracer, no per-publish
         auth/deliver hooks, no rate limit, governor idle, cluster
-        ready, no payload predicates on this mountpoint. Checked once
-        per parsed batch (and re-checked after cooperative yields);
-        anything that needs per-frame policy falls back to the classic
-        handler frame by frame."""
+        ready (``wire_broker_ready``), this session live and no payload
+        predicates on its mountpoint (``wire_session_ready``). The
+        connection's task checks it once per parsed batch and again
+        after every await; a protocol-level listener evaluates the
+        broker-wide half once a loop turn and the session's half a
+        chunk. Anything that needs per-frame policy falls back to the
+        classic handler frame by frame."""
+        return wire_broker_ready(self.broker) and self.wire_session_ready()
+
+    def wire_session_ready(self) -> bool:
+        """The session's half of the wire gate: connected, not closed,
+        no payload predicates on its mountpoint."""
         if not self.connected or self.closed:
             return False
-        b = self.broker
-        cfg = b.config
-        if not cfg.get("wire_fastpath_enabled", True):
-            return False
-        if b.tracer is not None or cfg.max_message_rate:
-            return False
-        gov = b.overload
-        if gov is not None:
-            if gov.level > 0:
-                return False
-        elif b.sysmon is not None and b.sysmon.overloaded:
-            return False
-        h = b.hooks
-        if (h.has("auth_on_publish") or h.has("auth_on_publish_m5")
-                or h.has("on_publish") or h.has("on_deliver")):
-            return False
-        if not b.cluster_ready() \
-                and not cfg.allow_publish_during_netsplit:
-            return False
-        eng = getattr(b, "filter_engine", None)
-        if eng is not None and eng.wants(self.mountpoint):
-            return False
-        return True
+        eng = getattr(self.broker, "filter_engine", None)
+        return eng is None or not eng.wants(self.mountpoint)
 
     def _wire_cache_topic(self, buf, t_off: int, t_len: int):
         """Resolve ``(words, topic_str)`` through the per-connection
@@ -948,7 +937,7 @@ class Session:
                 if self.proto_ver == PROTO_5 and not matches:
                     ack.reason_code = RC_NO_MATCHING_SUBSCRIBERS
                 self.send(ack)
-                b.metrics.incr("mqtt_puback_sent")
+                b.outbox.puback_sent += 1  # folded with the send's bytes
             else:
                 self.send(Pubrec(packet_id=pid))
                 b.metrics.incr("mqtt_pubrec_sent")
@@ -1723,6 +1712,36 @@ _IN_METRIC = {
     Disconnect: "mqtt_disconnect_received",
     Auth: "mqtt_auth_received",
 }
+
+
+def wire_broker_ready(b: "Broker") -> bool:
+    """The broker-wide half of the wire gate (``Session.wire_fast_ready``).
+
+    It may be evaluated once for a whole pass of synchronous wire-plane
+    work (``MQTTServer._serve_inbox``: a loop turn's chunks) because
+    nothing such a pass runs writes one of its inputs: the config knobs
+    and the tracer are set by admin commands, hooks by plugin
+    enable/disable, the governor's level by its own tick, the sysmon's
+    lag sample or a pin, cluster readiness by membership events — each a
+    task or callback of its own on the loop, which cannot run inside
+    another callback. A wire-plane record (publish admission, collector
+    submit, fanout write, ack bookkeeping) reads them and never awaits."""
+    cfg = b.config
+    if not cfg.get("wire_fastpath_enabled", True):
+        return False
+    if b.tracer is not None or cfg.max_message_rate:
+        return False
+    gov = b.overload
+    if gov is not None:
+        if gov.level > 0:
+            return False
+    elif b.sysmon is not None and b.sysmon.overloaded:
+        return False
+    h = b.hooks
+    if (h.has("auth_on_publish") or h.has("auth_on_publish_m5")
+            or h.has("on_publish") or h.has("on_deliver")):
+        return False
+    return b.cluster_ready() or bool(cfg.allow_publish_during_netsplit)
 
 
 class Transport:

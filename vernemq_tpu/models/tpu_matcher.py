@@ -1867,8 +1867,12 @@ class BatchCollector:
                  lock_busy_shed_ms: int = 500, super_batch_k: int = 8,
                  latency_budget_ms: float = 50.0,
                  watchdog=None, dispatch_deadline_ms: float = 0.0,
-                 item_expiry_ms: float = 0.0, filter_engine=None):
+                 item_expiry_ms: float = 0.0, filter_engine=None,
+                 after_release=None):
         self.view = view
+        # called at the end of every release chunk: the broker flushes
+        # the chunk's socket writes there (broker/egress.py)
+        self._after_release = after_release
         # payload-filter engine (vernemq_tpu/filters/): when set, every
         # flush's matched fanout runs the predicate phase — device
         # dispatch chained behind topic match, host evaluator on every
@@ -2060,6 +2064,8 @@ class BatchCollector:
             asyncio.get_event_loop().call_soon(self._release)
         else:
             self._releasing = False
+        if self._after_release is not None:
+            self._after_release()
 
     def _settle_via_trie(self, mp: str, topic, ent,
                          fallback_exc: Optional[BaseException] = None,

@@ -164,6 +164,11 @@ STAGE_FAMILIES: List[Tuple[str, str]] = [
      "window entry, batched header encode, socket write) or "
      "route_rows (queue enqueue, session deliver, PUBLISH encode and "
      "socket write of every recipient)."),
+    ("stage_egress_flush_ms",
+     "Outbox flush per loop turn that wrote: the fold of the turn's "
+     "egress counters and one socket write per transport written in "
+     "the turn, back to back (broker/egress.py; a release chunk's 64 "
+     "deliveries and 64 PUBACKs are one flush)."),
     ("stage_ack_in_ms",
      "Inbound PUBACK/PUBCOMP handling per ack: in-flight window "
      "bookkeeping, pending pump and queue notify_ready."),

@@ -104,6 +104,10 @@ fastpath_acks = 0       #: ack frames resolved object-free
 inline_chunks = 0       #: recv chunks served whole by the connection's protocol
 task_chunks = 0         #: chunks, or remainders, handed to the connection's task
 fanout_batches = 0      #: batched fanout header encodes (one per fanout)
+egress_flushes = 0      #: outbox flushes (one a loop turn that wrote)
+egress_writes = 0       #: transports written by them
+egress_joined = 0       #: of those, several chunks sent as one joined write
+egress_scattered = 0    #: of those, several chunks sent through writelines
 
 
 def load_native():
@@ -159,6 +163,10 @@ def stats():
         "wire_inline_chunks": float(inline_chunks),
         "wire_task_chunks": float(task_chunks),
         "wire_fanout_batches": float(fanout_batches),
+        "wire_egress_flushes": float(egress_flushes),
+        "wire_egress_writes": float(egress_writes),
+        "wire_egress_joined": float(egress_joined),
+        "wire_egress_scattered": float(egress_scattered),
         "wire_breaker_state": float(breaker.state),
     }
 
