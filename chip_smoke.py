@@ -94,6 +94,10 @@ REHEARSAL_SUPER_BURST = 3 * MAX_BATCH + 1024
 PUBS_PER_CONNECTION = 1024
 N_PUBLISHERS = -(-SUPER_BURST // PUBS_PER_CONNECTION)
 HOST_FALLBACK_SHARE = 0.02  # per-publish exact host fallbacks tolerated
+#: the fan-out check: one topic of the one-chip boot held by this many
+#: subscribers (four times the default tpu_max_fanout of 256)
+FANOUT_TOPIC = ("fanout", "all", "one")
+FANOUT_ROWS = 1000
 MESH_BURST = 64
 MESH_FALLBACK_SHARE = 0.25  # slice-straddling buckets fall back by design
 #: collector counters of publishes the host trie served in place of the
@@ -231,6 +235,8 @@ def row_set(rows) -> set:
 
 
 def counters(matcher, collector) -> Dict[str, int]:
+    from vernemq_tpu.models import tpu_matcher  # the wide pass's totals
+
     out = {k: int(getattr(collector, k)) for k in HOST_SERVED}
     out.update(
         host_hybrid_pubs=collector.host_hybrid_pubs,
@@ -240,6 +246,8 @@ def counters(matcher, collector) -> Dict[str, int]:
         match_publishes=matcher.match_publishes,
         super_dispatches=matcher.super_dispatches,
         host_fallbacks=matcher.host_fallbacks,
+        wide_publishes=tpu_matcher.wide_publishes,
+        wide_failures=tpu_matcher.wide_failures,
         warm_failures=matcher.warm_failures,
         device_failures=matcher.device_failures,
         busy_sheds=matcher.busy_sheds,
@@ -939,6 +947,9 @@ async def one_chip(args, jax, chk: Checks) -> None:
     emit(phase="corpus", subscriptions=len(rows), seed=args.seed,
          mix="build_corpus: 60% exact, 20% w/+/w, 10% +/w/w, 10% w/w/#",
          build_s=round(time.monotonic() - t0, 2))
+    # ...and one topic held by FANOUT_ROWS subscribers: past
+    # tpu_max_fanout, what the wide pass is for (fanout_phase)
+    rows += [(list(FANOUT_TOPIC), len(rows) + i) for i in range(FANOUT_ROWS)]
     specs = smoke_specs(pools, 4)
     rig = await boot_and_warm(args, rows, "boot1", specs)
     matcher = rig.matcher
@@ -985,6 +996,7 @@ async def one_chip(args, jax, chk: Checks) -> None:
             lambda: zipf_topics(rng, pools, rig.super_burst),
             expect_super=True)
         await delta_phase(rig, chk, pools)
+        await fanout_phase(rig, chk)
         why = ("compiles for the v5e (tests/test_tpu_compile.py); no phase "
                "here drives it yet (ROADMAP S6)")
         emit(phase="retained_replay", status="not run", why=why)
@@ -1026,6 +1038,23 @@ async def delta_phase(rig: Rig, chk: Checks, pools) -> None:
                   "delta: applied by scatter, not by a rebuild")
     finally:
         await late.close()
+
+
+async def fanout_phase(rig: Rig, chk: Checks) -> None:
+    """A publish that matches FANOUT_ROWS rows — four times
+    ``tpu_max_fanout`` — is answered whole by the device (the wide pass):
+    rows equal to the trie's, and not one publish matched again on the
+    host."""
+    before = counters(rig.matcher, rig.collector)
+    await asserted_burst(rig, chk, "fanout_1000",
+                         lambda: [FANOUT_TOPIC] * 16, fallback_share=0.0)
+    d = moved(before, counters(rig.matcher, rig.collector))
+    chk.check(d["host_fallbacks"] == 0 and d["wide_failures"] == 0
+              and d["wide_publishes"] >= 16,
+              f"fanout_1000: {FANOUT_ROWS} rows a publish answered by the "
+              "device, host_fallbacks unchanged",
+              moved={k: d[k] for k in ("host_fallbacks", "wide_publishes",
+                                       "wide_failures", "match_publishes")})
 
 
 # ------------------------------------------------------ four-chip phase

@@ -594,3 +594,26 @@ async def test_collector_close_settles_pending():
     late = await col.submit("", ("cl", "5"))
     assert [v for _, v in late] == [5]
     assert col.device_batches == 0  # nothing ever dispatched
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("queued", [False, True], ids=["idle", "queued"])
+async def test_collector_pressure_latency_counts_only_while_busy(queued):
+    """The replay collector's dispatch-latency EWMA folds only on a flush:
+    after one slow first dispatch (a SUBSCRIBE with more filters than the
+    host threshold, compiling) an idle collector reported it as pressure
+    for as long as nobody subscribed again, above the governor's L1 exit.
+    With replays queued it is pressure."""
+    from vernemq_tpu.retained.collector import RetainedBatchCollector
+    from vernemq_tpu.robustness.overload import LATENCY_SEVERITY_CAP
+
+    store = RetainStore()
+    col = RetainedBatchCollector(RetainedEngine(store), store,
+                                 window_us=10_000_000, max_batch=64,
+                                 host_threshold=0, latency_budget_ms=50.0)
+    col.dispatch_ewma_ms = 400.0
+    futs = [col.submit("", ("p", "1"))] if queued else []
+    assert col.pressure() == pytest.approx(
+        LATENCY_SEVERITY_CAP if queued else 0.0)
+    col.close()
+    await asyncio.gather(*futs)

@@ -164,6 +164,33 @@ def test_match_many_compiles(matcher, one_chip):
     assert _total_bytes(compiled) < HBM_BYTES
 
 
+def _compile_wide(m, one_chip, U):
+    from vernemq_tpu.ops import match_kernel as K
+
+    if ("wide", U) not in _COMPILED:
+        S, L = int(m._dev_arrays[0].shape[0]), m.table.L
+        statics = m._wide_statics(S, m._glob_pad, m._reg_start,
+                                  m._reg_end, m._ops_bits)
+        z = np.zeros((U, 3), np.int64)
+        packed = K.wide_pack_args(np.zeros((U, L), np.int32),
+                                  np.zeros(U, np.int32),
+                                  np.zeros(U, np.int32), z, z)
+        _COMPILED["wide", U] = K.wide_mask_packed.lower(
+            *_table_sds(m, one_chip), _sds(packed, one_chip),
+            U=U, L=L, **statics).compile()
+    return _COMPILED["wide", U]
+
+
+def test_wide_mask_compiles(matcher, one_chip):
+    """The wide pass (``K.call_wide``: the whole bit mask of a publish's
+    regions, for what the flat form's caps cut off) at both of its rungs
+    and the 1M table's widest windows."""
+    from vernemq_tpu.models.tpu_matcher import WIDE_RUNGS
+
+    for U in WIDE_RUNGS:
+        assert _total_bytes(_compile_wide(matcher, one_chip, U)) < HBM_BYTES
+
+
 D_TOP = 128  # the top of the pre-warmed delta ladder (tpu_delta_warm_max)
 
 
@@ -198,16 +225,18 @@ def test_delta_scatter_compiles(matcher, one_chip):
 @pytest.mark.parametrize("program,scopes", [
     ("packed", ("unpack_transport", "dense_region0", "probe_a", "probe_b",
                 "flat_combine")),
-    ("delta", ("delta_scatter",))])
+    ("delta", ("delta_scatter",)),
+    ("wide", ("wide_mask",))])
 def test_device_programs_name_their_phases(matcher, one_chip, program,
                                            scopes):
     """``jax.named_scope`` reaches the v5e's compiled program: every phase
     of the match and the delta scatter is the ``op_name`` of instructions
     that survived optimisation, which is where a device trace's
     operations are attributed from (``benchmark/trace/spans.py``)."""
-    compiled = (_compile_packed(matcher, one_chip, 9)
-                if program == "packed" else _compile_delta(matcher,
-                                                           one_chip))
+    compiled = {"packed": lambda: _compile_packed(matcher, one_chip, 9),
+                "delta": lambda: _compile_delta(matcher, one_chip),
+                "wide": lambda: _compile_wide(matcher, one_chip, 8)
+                }[program]()
     text = compiled.as_text()
     for scope in scopes:
         assert f"/{scope}/" in text, scope

@@ -9,6 +9,7 @@ import random
 
 import pytest
 
+from vernemq_tpu.models import tpu_matcher
 from vernemq_tpu.models.tpu_matcher import TpuMatcher
 from vernemq_tpu.models.trie import SubscriptionTrie
 from vernemq_tpu.protocol import topic as T
@@ -571,9 +572,9 @@ async def test_tpu_mesh_unsatisfiable_fails_boot(spec, match):
 
 def test_flat_capacity_overflow_falls_back_exact():
     """A batch whose total fanout exceeds the flat buffer (C =
-    Bpad*flat_avg) must stay exact: overflowed pubs take the host path
+    Bpad*flat_avg) must stay exact: overflowed pubs take the wide pass
     instead of losing matches (match_extract_windowed_flat's overflow
-    contract)."""
+    contract), and none the host scan."""
     rng = random.Random(7)
     m = _bucketed_matcher(max_fanout=256, flat_avg=1)  # C == Bpad: tiny
     trie = SubscriptionTrie()
@@ -583,10 +584,12 @@ def test_flat_capacity_overflow_falls_back_exact():
         trie.add(list(f), i, None)
     topics = [(f"r{rng.randrange(16)}", f"d{rng.randrange(40)}",
                f"m{rng.randrange(16)}") for _ in range(64)]
-    before = m.host_fallbacks
+    before, wide = m.host_fallbacks, tpu_matcher.wide_publishes
     for topic, rows in zip(topics, m.match_batch(topics)):
         assert norm(rows) == norm(trie.match(list(topic))), topic
-    assert m.host_fallbacks > before  # the tiny flat buffer did overflow
+    # the tiny flat buffer did overflow
+    assert tpu_matcher.wide_publishes > wide
+    assert m.host_fallbacks == before
 
 
 def test_flat_padded_batch_tail_is_inert():

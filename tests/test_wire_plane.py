@@ -15,9 +15,10 @@ from vernemq_tpu.broker.config import Config
 from vernemq_tpu.broker.egress import JOIN_MAX, Outbox
 from vernemq_tpu.broker.metrics import Metrics
 from vernemq_tpu.broker.server import StreamTransport, start_broker
+from vernemq_tpu.broker.session import WIRE_CLOSED, WIRE_OPEN, WIRE_PAUSED
 from vernemq_tpu.client import MQTTClient
 from vernemq_tpu.protocol import codec_v4, codec_v5, fastpath, wire
-from vernemq_tpu.protocol.types import (Connect, Publish, SubOpts,
+from vernemq_tpu.protocol.types import (Connect, Puback, Publish, SubOpts,
                                         Subscribe)
 
 
@@ -618,7 +619,43 @@ async def _outbox_counters_equal_per_write_accounting():
         await server.stop()
 
 
+async def _outbox_flush_writes_a_bounded_number_of_transports_a_turn():
+    """More transports listed than ``FLUSH_MAX``: a flush writes the
+    first ``FLUSH_MAX`` in listing order and schedules the rest for the
+    next turn; a transport that waits keeps collecting frames and sends
+    them as ONE write; the counters fold at the first flush."""
+    from vernemq_tpu.broker.egress import FLUSH_MAX
+
+    ob = _outbox()
+    n = 2 * FLUSH_MAX + 10
+    socks = [SockSpy() for _ in range(n)]
+    ts = [StreamTransport(s, ob) for s in socks]
+    flushes, writes, _, _ = egress_counts()
+    for i, t in enumerate(ts):
+        t.write(b"%d." % i)
+    ob.publish_sent += n
+    ob.flush()  # the callback that filled the outbox runs it
+    written = [i for i, s in enumerate(socks) if s.calls]
+    assert written == list(range(FLUSH_MAX))
+    assert ob._metrics.value("mqtt_publish_sent") == n  # folded whole
+    ts[n - 1].write(b"more")      # still listed: no second listing
+    ts[0].write(b"again")         # written: listed anew, at the end
+    await asyncio.sleep(0)        # the turn after: the scheduled flush
+    assert [i for i, s in enumerate(socks) if s.calls] == \
+        list(range(2 * FLUSH_MAX))
+    await asyncio.sleep(0)
+    await asyncio.sleep(0)
+    assert [s.stream() for s in socks[1:n - 1]] == \
+        [b"%d." % i for i in range(1, n - 1)]
+    assert socks[n - 1].calls == [("write", b"%d.more" % (n - 1))]
+    assert socks[0].stream() == b"0.again" and len(socks[0].calls) == 2
+    assert egress_counts()[:2] == (flushes + 3, writes + n + 1)
+    assert ob._handle is None and not ob._listed
+
+
 OUTBOX_CASES = {
+    "bounded_flush":
+        _outbox_flush_writes_a_bounded_number_of_transports_a_turn,
     "sequential_bytes": _outbox_bytes_are_those_of_sequential_writes,
     "one_callback_a_turn": _outbox_schedules_one_callback_a_turn,
     "join_small_scatter_large":
@@ -1247,7 +1284,7 @@ async def test_batched_gate_leaves_edges_to_the_classic_path(edge):
         elif edge == "hook":
             broker.hooks.register("on_publish", lambda *a: None)
         else:
-            broker.overload.pin(1)
+            broker.overload.pin(2)
         fast, classic = fastpath.fastpath_pubs_qos, \
             fastpath.classic_pubs_qos
         await pub.send(q_publish(0, **kw))
@@ -1495,19 +1532,25 @@ async def test_inline_run_stops_at_the_run_bound():
 @pytest.mark.asyncio
 @pytest.mark.parametrize("edge", ["tracer", "governor"])
 async def test_inline_run_stays_off_behind_a_closed_gate(edge):
-    """(e) Gate closed — a tracer, the governor at level 1 — and
-    the protocol serves nothing: every chunk goes to the task, and the
-    conversation's bytes are those of the open gate."""
+    """(e) Gate closed — a tracer — and the protocol serves nothing:
+    every chunk goes to the task; the governor at level 1 — and it
+    serves no chunk that holds a PUBLISH (the task sleeps its pause).
+    The conversation's bytes are those of the open gate."""
     open_gate = await _conversation_on("protocol")
-    broker, server = await boot()
+    broker, server = await boot(overload_l1_throttle_ms=1)
     try:
         if edge == "tracer":
             broker.start_trace("nobody-by-this-name")
         else:
             broker.overload.pin(1)
         inline, task = chunk_counts()
+        pubs = fastpath.fastpath_pubs + fastpath.fastpath_pubs_qos
         closed_gate = await _conversation(server.port)
-        assert fastpath.inline_chunks == inline
+        if edge == "tracer":
+            assert fastpath.inline_chunks == inline
+        else:
+            # paid on the task, then the wire plane all the same
+            assert fastpath.fastpath_pubs + fastpath.fastpath_pubs_qos > pubs
         assert fastpath.task_chunks > task
         if edge == "governor":
             broker.overload.pin(None)
@@ -1686,17 +1729,17 @@ def counted_gates():
     from vernemq_tpu.broker import server as server_mod
 
     verdicts = []
-    orig = server_mod.wire_broker_ready
+    orig = server_mod.wire_gate
 
     def counting(broker):
         verdicts.append(orig(broker))
         return verdicts[-1]
 
-    server_mod.wire_broker_ready = counting
+    server_mod.wire_gate = counting
     try:
         yield verdicts
     finally:
-        server_mod.wire_broker_ready = orig
+        server_mod.wire_gate = orig
 
 
 async def _gate_fleet(server, broker, n=3):
@@ -1734,7 +1777,7 @@ async def test_a_pass_evaluates_the_broker_wide_gate_once(edge):
             for i, p in enumerate(protos):
                 p.data_received(q_publish(i))
             await asyncio.sleep(0)
-            assert verdicts == [True]
+            assert verdicts == [WIRE_OPEN]
             assert chunk_counts() == (inline + 3, task)
             assert wire_broker_ready(broker)  # the pass did not move it
         for r in pubs:
@@ -1742,14 +1785,14 @@ async def test_a_pass_evaluates_the_broker_wide_gate_once(edge):
         if edge == "hook":
             broker.hooks.register("on_publish", lambda *a, **kw: None)
         elif edge == "governor":
-            broker.overload.pin(1)
+            broker.overload.pin(2)
         with counted_gates() as verdicts:
             inline, task = chunk_counts()
             classic = fastpath.classic_pubs_qos
             for i, p in enumerate(protos):
                 p.data_received(q_publish(3 + i))
             await asyncio.sleep(0)
-            assert verdicts == [edge is None]
+            assert verdicts == [WIRE_OPEN if edge is None else WIRE_CLOSED]
             if edge is None:
                 assert chunk_counts() == (inline + 3, task)
             else:
@@ -1764,6 +1807,96 @@ async def test_a_pass_evaluates_the_broker_wide_gate_once(edge):
             [b"q%04d" % i for i in range(6)]
         if edge == "governor":
             broker.overload.pin(None)
+        for r in pubs + [sub]:
+            r.close()
+    finally:
+        await broker.stop()
+        await server.stop()
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("burst", [1, 5, 14])
+async def test_level_1_pauses_a_burst_once_and_keeps_it_whole(burst):
+    """The governor at level 1 under the batched view: a chunk of
+    ``burst`` QoS 1 publishes pays its reader pauses as ONE sleep (at
+    most a second's worth at once) and then runs on the wire plane — the
+    burst reaches the collector in one flush, not a publish a flush as
+    on the classic path — while a chunk of acks is served inline with no
+    pause; every publish is delivered and acknowledged in order."""
+    from vernemq_tpu.broker.session import wire_gate
+
+    broker, server = await boot_batched(overload_l1_throttle_ms=100)
+    try:
+        sub = await Raw.connect(server.port, "l1sub")
+        await sub.send(codec_v4.serialise(Subscribe(
+            packet_id=1, topics=[("q/#", SubOpts(qos=1))])))
+        await sub.read_frames(2)
+        pub = await Raw.connect(server.port, "l1pub")
+        held = HeldFold(broker, hold=())  # the size of every flush
+        broker.overload.pin(1)
+        assert wire_gate(broker) == WIRE_PAUSED
+        fast, classic = fastpath.fastpath_pubs_qos, \
+            fastpath.classic_pubs_qos
+        throttled = broker.metrics.value("overload_publish_throttled")
+        t0 = asyncio.get_running_loop().time()
+        await pub.send(b"".join(q_publish(i) for i in range(burst)))
+        acks = (await pub.read_frames(1 + burst))[1:]
+        waited = asyncio.get_running_loop().time() - t0
+        assert [a.packet_id for a in acks] == list(range(1, burst + 1))
+        got = (await sub.read_frames(2 + burst))[2:]
+        assert [f.payload for f in got] == \
+            [b"q%04d" % i for i in range(burst)]
+        assert fastpath.fastpath_pubs_qos == fast + burst
+        assert fastpath.classic_pubs_qos == classic
+        assert broker.metrics.value("overload_publish_throttled") \
+            == throttled + burst
+        # one pause of burst x 100 ms, in pieces of at most a second
+        assert waited >= 0.1 * burst - 0.02
+        assert held.calls == ([10, 4] if burst == 14 else [burst])
+        # the subscriber's acks owe nothing and wake nobody
+        inline, task = chunk_counts()
+        for f in got:
+            await sub.send(codec_v4.serialise(Puback(packet_id=f.packet_id)))
+        ssub = session_of(broker, "l1sub")
+        await until(lambda: not ssub.waiting_acks)
+        assert chunk_counts()[1] == task and chunk_counts()[0] > inline
+        broker.overload.pin(None)
+        sub.close()
+        pub.close()
+    finally:
+        await broker.stop()
+        await server.stop()
+
+
+@pytest.mark.asyncio
+async def test_level_1_leaves_a_tick_of_bursts_to_the_device():
+    """Three publishers' bursts of five on one tick, the governor at
+    level 1, ``tpu_host_batch_threshold`` 8: the pauses end together, the
+    fifteen publishes reach the collector as flushes past the threshold
+    and the device serves every one — a pause a publish would hand the
+    collector flushes of three, all of them the host trie's."""
+    broker, server = await boot_batched(tpu_host_batch_threshold=8,
+                                        overload_l1_throttle_ms=40)
+    try:
+        sub = await Raw.connect(server.port, "tksub")
+        await sub.send(codec_v4.serialise(Subscribe(
+            packet_id=1, topics=[("q/#", SubOpts(qos=0))])))
+        await sub.read_frames(2)
+        pubs = [await Raw.connect(server.port, "tkpub%d" % i)
+                for i in range(3)]
+        held = HeldFold(broker, hold=())
+        col = broker.batch_collector()
+        hybrid = col.host_hybrid_pubs
+        broker.overload.pin(1)
+        for r in pubs:
+            await r.send(b"".join(q_publish(i) for i in range(5)))
+        for r in pubs:
+            acks = (await r.read_frames(6))[1:]
+            assert [a.packet_id for a in acks] == [1, 2, 3, 4, 5]
+        assert len((await sub.read_frames(2 + 15))[2:]) == 15
+        assert sum(held.calls) == 15 and min(held.calls) > 8
+        assert col.host_hybrid_pubs == hybrid
+        broker.overload.pin(None)
         for r in pubs + [sub]:
             r.close()
     finally:
@@ -1791,7 +1924,7 @@ async def test_a_pass_tests_the_sessions_half_per_chunk(state):
             for i, p in enumerate(protos):
                 p.data_received(q_publish(i))
             await asyncio.sleep(0)
-            assert verdicts == [True]
+            assert verdicts == [WIRE_OPEN]
             assert chunk_counts() == (inline + 2, task + 1)
             assert fastpath.fastpath_pubs_qos == fast + 2
         assert protos[1]._session is None  # woken: the task has the bytes

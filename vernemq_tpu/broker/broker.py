@@ -309,6 +309,22 @@ class Broker:
             "tpu_saturated_merges": "Flushes merged into a later batch "
                                     "(both pipeline slots busy).",
             "tpu_async_rebuilds": "Background device-table rebuilds.",
+            "tpu_wide_publishes": "Publishes past the flat match "
+                                  "result's caps (tpu_max_fanout a part, "
+                                  "flat capacity a batch) that the device "
+                                  "answered whole with the wide pass.",
+            "tpu_wide_dispatches": "Wide-pass device dispatches.",
+            "tpu_wide_topics": "Distinct topics the wide-pass dispatches "
+                               "matched (identical topics of a dispatch "
+                               "are matched once).",
+            "tpu_wide_rows": "Matched rows the wide pass brought back, "
+                             "summed over its publishes.",
+            "tpu_wide_failures": "Publishes whose wide answer fell short "
+                                 "of the flat form's own count "
+                                 "(host-matched instead).",
+            "tpu_release_rows": "Matched rows (at least one a "
+                                "submission) the collector's release "
+                                "queue released.",
             # degraded-mode observability (robustness tentpole): breaker
             # state + fallback/fault counters, published to $SYS like
             # every other metric by the systree reporter
@@ -421,6 +437,11 @@ class Broker:
                                   "socket write each, whatever the "
                                   "number of frames queued on it in "
                                   "the turn.",
+            "wire_egress_publishes": "PUBLISH frames those writes "
+                                     "carried: over wire_egress_writes, "
+                                     "the frames a socket write took out "
+                                     "(a fan-out puts several of one "
+                                     "turn on one socket).",
             "wire_egress_joined": "Of those, flushes of several chunks "
                                   "(a delivery's header + payload, a "
                                   "run of acks) small enough to leave "

@@ -140,10 +140,13 @@ DEFAULTS: Dict[str, Any] = {
     # matcher
     "default_reg_view": "trie",  # trie | tpu — the reg-view seam (vmq_mqtt_fsm.erl:105)
     "tpu_batch_window_us": 200,
-    # per-part device fanout cap (k): beyond it the pub falls back to the
-    # exact host match — 256 balances extraction cost vs fallback rate
+    # per-part width (k) of the device's flat result: a publish that
+    # matches more rows in a part is answered by a second, wide pass (the
+    # whole bit mask of its regions) — 256 balances extraction cost vs
+    # how often that second round trip is paid
     "tpu_max_fanout": 256,
-    # flat result-buffer slots per pub, batch-averaged (C = Bpad * this)
+    # flat result-buffer slots per pub, batch-averaged (C = Bpad * this);
+    # publishes past the buffer's end take the wide pass too
     "tpu_flat_avg": 128,
     # pre-size the device table for a known subscriber scale: growth
     # rebuilds (repartition + full re-upload) happen at doublings, so an

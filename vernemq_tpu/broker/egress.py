@@ -34,6 +34,18 @@ from .session import Transport
 #: in hand.
 JOIN_MAX = 4096
 
+#: transports one flush writes; the rest stay listed, in order, for the
+#: flush of the next turn (the outbox's own callback, or the next release
+#: chunk's). A socket write is ~46 µs on the chip's host and nothing a
+#: flush can shorten, so a turn that wrote to 1,000 sessions — one publish
+#: of the suite's fan-out case — held the loop 46 ms for its sends alone,
+#: and a timer waits two such turns (PERF.md §6, PR 32, calls 2 and 3: the
+#: loop 0.19–0.36 s late against ``sysmon_lag_threshold`` 0.25 s). A
+#: transport that waits keeps collecting frames and sends them as one
+#: write, so the cap costs no socket call. 256 is two release chunks of
+#: one-row publishes: no turn of point-to-point traffic reaches it.
+FLUSH_MAX = 256
+
 
 class Outbox:
     """The transports written in this loop turn, in first-write order,
@@ -73,10 +85,11 @@ class Outbox:
         self._handle = asyncio.get_event_loop().call_soon(self.flush)
 
     def flush(self) -> None:
-        """Fold the counters, then write every listed transport. Runs as
-        the scheduled callback at the head of the next turn, or sooner
-        from the callback that filled the outbox; with nothing pending
-        it returns at once."""
+        """Fold the counters, then write the listed transports, at most
+        ``FLUSH_MAX`` of them (the rest: the next turn's flush, which
+        this one schedules). Runs as the scheduled callback at the head
+        of the next turn, or sooner from the callback that filled the
+        outbox; with nothing pending it returns at once."""
         handle = self._handle
         if handle is None:
             return
@@ -85,7 +98,13 @@ class Outbox:
         tok = obs.span_begin("stage_egress_flush_ms")
         try:
             self._fold()
-            listed, self._listed = self._listed, []
+            listed = self._listed
+            if len(listed) > FLUSH_MAX:
+                listed, self._listed = (listed[:FLUSH_MAX],
+                                        listed[FLUSH_MAX:])
+                self._schedule()
+            else:
+                self._listed = []
             writes = joined = scattered = 0
             for transport in listed:
                 form = transport._flush()
@@ -109,6 +128,7 @@ class Outbox:
             self.bytes_sent = 0
         if self.publish_sent:
             incr("mqtt_publish_sent", self.publish_sent)
+            fastpath.egress_publishes += self.publish_sent
             self.publish_sent = 0
         if self.puback_sent:
             incr("mqtt_puback_sent", self.puback_sent)

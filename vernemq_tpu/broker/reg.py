@@ -1312,6 +1312,16 @@ class Registry:
                     out["tpu_breaker_time_degraded_seconds"] = round(
                         out.get("tpu_breaker_time_degraded_seconds", 0.0)
                         + br.time_degraded(), 3)
+            # the wide result: publishes past the flat form's caps that
+            # the device answered whole (failures: host-matched); the
+            # matcher module's process totals
+            from ..models import tpu_matcher as _tm
+
+            out["tpu_wide_publishes"] = _tm.wide_publishes
+            out["tpu_wide_dispatches"] = _tm.wide_dispatches
+            out["tpu_wide_topics"] = _tm.wide_topics
+            out["tpu_wide_rows"] = _tm.wide_rows
+            out["tpu_wide_failures"] = _tm.wide_failures
         col = getattr(self.broker, "_collector", None)
         if col is not None:
             # small flushes served host-side by hybrid dispatch
@@ -1328,6 +1338,7 @@ class Registry:
             # past their queued-item expiry (stall watchdog bounds)
             out["tpu_stalled_host_pubs"] = col.stalled_host_pubs
             out["tpu_expired_host_pubs"] = col.expired_host_pubs
+            out["tpu_release_rows"] = col.release_rows
         # deterministic fault-injection harness (robustness/faults.py)
         from ..robustness import faults as _faults
 

@@ -56,7 +56,7 @@ from ..protocol.types import (
 from ..utils.aio import close_server
 from .broker import Broker
 from .egress import StreamTransport
-from .session import Session, Transport, wire_broker_ready
+from .session import WIRE_OPEN, Session, Transport, wire_gate
 from .websocket import WsError
 
 log = logging.getLogger("vernemq_tpu.server")
@@ -107,6 +107,13 @@ _unpack_rec = fastpath.REC.unpack_from
 #: dup — the dup retransmit and retained forms keep the classic path
 #: (dedup/store edges)
 _FAST_QOS_FLAGS = (0x32, 0x34)
+
+
+def _owes_pause(table, end: int) -> bool:
+    """Whether a frame table holds a PUBLISH: the records the governor's
+    level 1 pauses a reader for (``Session.wire_pause``, the task's)."""
+    kinds = table[:end:REC_SIZE]
+    return fastpath.K_PUB in kinds or fastpath.K_PUB0 in kinds
 
 
 def wire_run(session: Session, buf, table, off: int, end: int,
@@ -272,6 +279,7 @@ async def mqtt_connection(
         # takes the classic handler here, unchanged.
         buf = bytes(rest)
         frames_run = 0
+        gov = broker.overload
         v5 = codec is codec_v5
         while not session.closed:
             if buf:
@@ -290,6 +298,27 @@ async def mqtt_connection(
                                        FRAME_RUN - frames_run)
                         frames_run += (ran - off) // REC_SIZE
                         off = ran
+                    elif gov is not None and gov.level == 1:
+                        # the governor at level 1: the next records'
+                        # publishes pay their reader pauses as one and
+                        # run on the wire plane together
+                        stop = await session.wire_pause(
+                            table, off, min(end, off + FRAME_RUN * REC_SIZE))
+                        if session.closed:
+                            break
+                        if stop > off:
+                            ran = wire_run(session, buf, table, off, stop,
+                                           FRAME_RUN)
+                            frames_run = (ran - off) // REC_SIZE
+                            off = ran
+                            fast_gate = session.wire_fast_ready()
+                            if off == stop:
+                                if frames_run >= FRAME_RUN:
+                                    # a full stretch that owed no pause
+                                    # (acks alone): yield all the same
+                                    frames_run = 0
+                                    await asyncio.sleep(0)
+                                continue
                     if off < end and frames_run < FRAME_RUN:
                         # the record the fast run stopped at
                         rec = _unpack_rec(table, off)
@@ -487,13 +516,16 @@ class MqttProtocol(asyncio.Protocol):
 
     # ------------------------------------------------ the inline run
 
-    def _serve(self, session: Session, data: bytes, gate: bool) -> None:
+    def _serve(self, session: Session, data: bytes, gate: int) -> None:
         """One recv chunk while the task is parked (from the listener's
         ``_serve_inbox``, which evaluated ``gate``, the broker-wide half
         of ``wire_fast_ready``, once for the pass): the session's half
         of the gate, the batch parse, the fast records. Served whole,
         nobody is woken; else the task gets the bytes from the first
-        record this could not serve (a closed gate: all of them)."""
+        record this could not serve (a closed gate: all of them; the
+        governor at level 1, ``WIRE_PAUSED``: all of a chunk that holds
+        a PUBLISH, whose pause only a task can sleep — a chunk of acks
+        is served here as ever)."""
         buf = self._tail + data if self._tail else data
         try:
             if gate and session.wire_session_ready():
@@ -505,7 +537,9 @@ class MqttProtocol(asyncio.Protocol):
                 finally:
                     obs.span_end("stage_wire_parse_ms", tok)
                 end = nrec * REC_SIZE
-                off = wire_run(session, buf, table, 0, end, FRAME_RUN)
+                off = 0
+                if gate == WIRE_OPEN or not _owes_pause(table, end):
+                    off = wire_run(session, buf, table, 0, end, FRAME_RUN)
                 if off == end:
                     # an incomplete frame at the tail waits here for
                     # the next chunk
@@ -662,7 +696,7 @@ class MQTTServer:
         pass wrote (the trie view routes and acknowledges inline)
         leaves at its end, ahead of the turn's reads."""
         inbox, self._inbox = self._inbox, []
-        gate = wire_broker_ready(self.broker)
+        gate = wire_gate(self.broker)
         for proto in inbox:
             session = proto._session
             if session is not None:

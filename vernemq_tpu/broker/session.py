@@ -738,6 +738,45 @@ class Session:
         classic handler frame by frame."""
         return wire_broker_ready(self.broker) and self.wire_session_ready()
 
+    async def wire_pause(self, table, off: int, end: int) -> int:
+        """The governor at level 1, on the connection's task: sleep the
+        reader pauses that the publishes among the records ``off`` to
+        ``end`` of the frame ``table`` owe as ONE pause, and return the
+        offset up to which the records may then run on the wire plane
+        (``off``: none, because the gate is shut by more than level 1,
+        or moved while this slept). A burst so stays a burst — it reaches
+        the collector whole and its deliveries share their socket writes
+        — at the mean admitted rate of a pause a publish; the classic
+        handler, which routes a connection's publishes one at a time,
+        would scatter it into flushes the host trie serves. One pause
+        covers at most ``WIRE_PAUSE_MAX`` seconds of publishes (the
+        keep-alive clock stands still meanwhile) and always one; acks owe
+        nothing."""
+        gov = self.broker.overload
+        if wire_gate(self.broker) != WIRE_PAUSED \
+                or not self.wire_session_ready():
+            return off
+        delay = gov.reader_delay(self.sid)
+        room = max(1, int(WIRE_PAUSE_MAX / delay)) if delay > 0 else end
+        n = 0
+        stop = off
+        for kind in table[off:end:fastpath.REC_SIZE]:
+            if kind == fastpath.K_PUB0 or kind == fastpath.K_PUB:
+                if n == room:
+                    break
+                n += 1
+            stop += fastpath.REC_SIZE
+        if n and delay > 0:
+            self.broker.metrics.incr("mqtt_publish_throttled", n)
+            await gov.pause_reader(n, delay)
+            # what moved while this slept: level 0 opens the gate wide,
+            # level 2 or a closed session leave the records to the
+            # classic handler (which asks the governor again)
+            if wire_gate(self.broker) == WIRE_CLOSED \
+                    or not self.wire_session_ready():
+                return off
+        return stop
+
     def wire_session_ready(self) -> bool:
         """The session's half of the wire gate: connected, not closed,
         no payload predicates on its mountpoint."""
@@ -1080,8 +1119,9 @@ class Session:
         b = self.broker
         b.metrics.incr("mqtt_publish_received", n + nq)
         if b.overload is not None:
-            # the heaviest-talker signal keeps integrating even though
-            # the fast path never parks (it only runs at level 0)
+            # the heaviest-talker signal keeps integrating: the fast
+            # path runs at level 0, and at level 1 behind ONE pause a
+            # chunk (``wire_pause``), which books no talker
             b.overload.record_publish_n(self.sid, n + nq)
         fastpath.fastpath_pubs += n
         fastpath.fastpath_pubs_qos += nq
@@ -1714,7 +1754,19 @@ _IN_METRIC = {
 }
 
 
-def wire_broker_ready(b: "Broker") -> bool:
+#: verdicts of the broker-wide half of the wire gate (``wire_gate``)
+WIRE_CLOSED = 0
+#: the governor at level 1: open to records that owe its reader pause
+#: nothing — the 2-byte acks, and publishes whose pause their
+#: connection's task has slept (``Session.wire_pause``)
+WIRE_PAUSED = 1
+WIRE_OPEN = 2
+#: seconds of reader pauses ``Session.wire_pause`` sleeps at once (the
+#: bound the governor's token wait keeps, for the same keep-alive)
+WIRE_PAUSE_MAX = 1.0
+
+
+def wire_gate(b: "Broker") -> int:
     """The broker-wide half of the wire gate (``Session.wire_fast_ready``).
 
     It may be evaluated once for a whole pass of synchronous wire-plane
@@ -1725,23 +1777,39 @@ def wire_broker_ready(b: "Broker") -> bool:
     lag sample or a pin, cluster readiness by membership events — each a
     task or callback of its own on the loop, which cannot run inside
     another callback. A wire-plane record (publish admission, collector
-    submit, fanout write, ack bookkeeping) reads them and never awaits."""
+    submit, fanout write, ack bookkeeping) reads them and never awaits.
+
+    Level 1 of the governor is a reader pause for inbound PUBLISHes and
+    nothing else, so it leaves the plane to whoever owes no pause
+    (``WIRE_PAUSED``); from level 2 on (token bucket, QoS0 shed) every
+    record takes the classic handler."""
     cfg = b.config
     if not cfg.get("wire_fastpath_enabled", True):
-        return False
+        return WIRE_CLOSED
     if b.tracer is not None or cfg.max_message_rate:
-        return False
+        return WIRE_CLOSED
+    verdict = WIRE_OPEN
     gov = b.overload
     if gov is not None:
         if gov.level > 0:
-            return False
+            if gov.level > 1 or gov.mode != "governor":
+                return WIRE_CLOSED
+            verdict = WIRE_PAUSED
     elif b.sysmon is not None and b.sysmon.overloaded:
-        return False
+        return WIRE_CLOSED
     h = b.hooks
     if (h.has("auth_on_publish") or h.has("auth_on_publish_m5")
             or h.has("on_publish") or h.has("on_deliver")):
-        return False
-    return b.cluster_ready() or bool(cfg.allow_publish_during_netsplit)
+        return WIRE_CLOSED
+    if b.cluster_ready() or bool(cfg.allow_publish_during_netsplit):
+        return verdict
+    return WIRE_CLOSED
+
+
+def wire_broker_ready(b: "Broker") -> bool:
+    """``wire_gate`` wide open: nothing broker-wide stands between a
+    record and the wire plane."""
+    return wire_gate(b) == WIRE_OPEN
 
 
 class Transport:

@@ -44,10 +44,33 @@ Row = Tuple[Tuple[str, ...], Hashable, Any]
 #: old-abandoned + fresh rebuild threads each see their own token)
 _rebuild_tls = threading.local()
 
+#: The wide result (``K.wide_mask_packed``), process totals as the wire
+#: plane keeps its own in ``protocol/fastpath`` (one process, one device;
+#: the gauges ``tpu_wide_*``): publishes the flat form flagged overflow
+#: and the device answered whole, the device calls that did, the distinct
+#: topics those matched, the rows they brought back, and publishes whose
+#: wide answer fell short of the flat form's own count (host-matched).
+#: Folded once a wide pass, under ``_wide_lock`` (an executor thread a
+#: matcher).
+wide_publishes = 0
+wide_dispatches = 0
+wide_topics = 0
+wide_rows = 0
+wide_failures = 0
+_wide_lock = threading.Lock()
+
+
 TILE_PUBS = 256  # pubs per window tile (MXU row-tile friendly)
 FAIR_MULT = 2    # window width vs per-tile fair share of the zone (the
                  # wider the window, the fewer tiles but the more rows
                  # each tile matmuls — an on-chip tuning knob)
+
+
+#: publishes a wide-pass program takes (K.wide_mask_packed's ``U``): the
+#: distinct topics of a batch that overflowed the flat form, padded to
+#: the smallest rung that holds them, the largest rung at a time. Two
+#: compile signatures a table geometry: a fan-out burst repeats few topics
+WIDE_RUNGS = (8, 64)
 
 
 def _pow2ceil(n: int) -> int:
@@ -282,7 +305,9 @@ class _FoldPhases:
     def close(self) -> None:
         sp, self._open = self._open, None
         if sp is not None:
-            self.ms[sp.family] = sp.end(record=False)
+            # a phase entered twice (resolve, around a wide pass) sums
+            self.ms[sp.family] = (self.ms.get(sp.family, 0.0)
+                                  + sp.end(record=False))
 
     def locked(self) -> None:
         self.lock_wait_ms = (time.monotonic() - self.t_in) * 1e3
@@ -300,6 +325,10 @@ class _FoldPhases:
         obs.observe("stage_fold_launch_ms", launch)
         obs.observe("stage_fold_wait_ms", wait)
         obs.observe("stage_fold_resolve_ms", resolve)
+        wide = ms("stage_fold_wide_ms")
+        if wide is not None:  # a dispatch that had a wide pass
+            obs.observe("stage_fold_wide_ms", wide)
+            fields["wide_ms"] = round(wide, 4)
         record_dispatch(
             "match", t_disp, dur, prep_ms=round(prep, 4),
             launch_ms=round(launch, 4), wait_ms=round(wait, 4),
@@ -497,6 +526,7 @@ class TpuMatcher:
         def _w() -> None:
             try:
                 topics = [("warmup", "ladder", str(i)) for i in range(Bpad)]
+                self._warm_wide()  # the shed may have been the wide pass's
                 self.match_batch(topics, _warmup=True)
             except (RebuildInProgress, DeviceDegraded):
                 pass  # table rebuilding / breaker open — retried later
@@ -1008,6 +1038,10 @@ class TpuMatcher:
                 return done
             topics = [("warmup", "ladder", str(i)) for i in range(b)]
             try:
+                if b == 1:
+                    # first, so that whoever waits for the last rung has
+                    # the wide programs too
+                    self._warm_wide()
                 self.match_batch(topics, _warmup=True)
             except (RebuildInProgress, DeviceDegraded):
                 return done  # rebuilding / breaker open: warm on demand
@@ -1306,6 +1340,20 @@ class TpuMatcher:
             self._warm_sigs.add(sig)
             if not _warmup:
                 self.super_dispatches += 1
+            folded: List[tuple] = []  # (idx_rows, need_host) a batch
+            wide: List[tuple] = []
+            for topics, enc, (flat, pre, total, overflow), left in zip(
+                    batches, encoded, results, lefts):
+                idx_rows, need_host, over = self._flat_views(
+                    len(topics), flat, pre, total, overflow, left)
+                folded.append((idx_rows, need_host))
+                if len(over):
+                    wide.append((*enc, over, total, idx_rows, need_host))
+            if wide:  # ONE wide pass for the K batches' overflowed topics
+                ph.enter(obs.span("stage_fold_wide_ms"))
+                self._wide_pass(operands, meta, reg_start, reg_end,
+                                glob_pad, bits, S, wide, require_warm)
+                ph.enter(obs.span("stage_fold_resolve_ms"))
         except MatcherBusy:
             raise
         except Exception as e:
@@ -1319,16 +1367,8 @@ class TpuMatcher:
         finally:
             with self.lock:
                 self._inflight -= 1
-        outs: List[List[List[Row]]] = []
-        for topics, (flat, pre, total, overflow), left in zip(
-                batches, results, lefts):
-            n = len(topics)
-            need_host = overflow[:n].copy()
-            for i in left:
-                need_host[i] = True
-            idx_rows = [flat[pre[i]:pre[i] + total[i]] for i in range(n)]
-            outs.append(self._resolve_rows(topics, idx_rows, need_host,
-                                           snapshot))
+        outs = [self._resolve_rows(topics, idx_rows, need_host, snapshot)
+                for topics, (idx_rows, need_host) in zip(batches, folded)]
         if dur is not None:
             ph.record(t_disp, dur, k=len(batches), batch=n_pubs,
                       bpad=int(Bpad),
@@ -1365,6 +1405,7 @@ class TpuMatcher:
                 batches = [
                     [("warmup", "ladder", str(i)) for i in range(Bpad)]
                     for _ in range(n_batches)]
+                self._warm_wide()
                 self.match_many(batches, _warmup=True)
             except (RebuildInProgress, DeviceDegraded):
                 pass  # table rebuilding / breaker open — retried later
@@ -1384,16 +1425,23 @@ class TpuMatcher:
         threading.Thread(target=_w, name=f"tpu-warm-many-{n_batches}",
                          daemon=True).start()
 
-    def _geometry(self, S, glob_pad, reg_start, reg_end, Bpad, align=0):
-        """Static kernel geometry for both probes at this batch size."""
+    def _region_maxima(self, reg_start, reg_end) -> Tuple[int, int]:
+        """Rows of the widest level-0 bucket region and of the widest
+        g-bucket region (0 where the table has no g-buckets)."""
         ng = self._ng
-        gb_end = self._gb_end
         amax = (int((reg_end[1 + ng:] - reg_start[1 + ng:]).max())
                 if len(reg_start) > 1 + ng else 0)
+        gmax = (int((reg_end[1:1 + ng] - reg_start[1:1 + ng]).max())
+                if ng else 0)
+        return amax, gmax
+
+    def _geometry(self, S, glob_pad, reg_start, reg_end, Bpad, align=0):
+        """Static kernel geometry for both probes at this batch size."""
+        gb_end = self._gb_end
+        amax, gmax = self._region_maxima(reg_start, reg_end)
         T, seg_max, gc = window_params(S, glob_pad, amax, Bpad,
                                        zone=S - gb_end, align=align)
-        if ng:
-            gmax = int((reg_end[1:1 + ng] - reg_start[1:1 + ng]).max())
+        if gmax:
             T2, seg2, _ = window_params(S, glob_pad, gmax, Bpad,
                                         zone=gb_end - glob_pad, align=align)
         else:
@@ -1494,13 +1542,159 @@ class TpuMatcher:
             ph.enter(obs.span("stage_fold_resolve_ms"))
             flat, pre, total, overflow = K.unpack_flat_result(
                 out, args[0].shape[0], statics["C"])
-        need_host = overflow[:n].copy()
+        idx_rows, need_host, over = self._flat_views(
+            n, flat, pre, total, overflow, left)
+        self._warm_sigs.add(sig)
+        if len(over):
+            # what the flat form's caps cut off, the device answers whole
+            ph.enter(obs.span("stage_fold_wide_ms"))
+            self._wide_pass(operands, meta, reg_start, reg_end, glob_pad,
+                            bits, S, [(pw, pl, pd, pb, gb, over, total,
+                                       idx_rows, need_host)],
+                            require_warm)
+            ph.enter(obs.span("stage_fold_resolve_ms"))
+        return idx_rows, need_host
+
+    @staticmethod
+    def _flat_views(n, flat, pre, total, overflow, left):
+        """One batch's flat result as ``(idx_rows, need_host, over)``:
+        per-publish VIEWS into ``flat`` (no copies), the window leftovers
+        marked for the host, and the positions the flat form flagged
+        ``overflow`` — the wide pass's."""
+        need_host = np.zeros(n, dtype=bool)
         for i in left:
             need_host[i] = True
-        # per-pub results are VIEWS into flat — no per-pub copies
         idx_rows = [flat[pre[i]:pre[i] + total[i]] for i in range(n)]
-        self._warm_sigs.add(sig)
-        return idx_rows, need_host
+        return idx_rows, need_host, np.flatnonzero(overflow[:n] & ~need_host)
+
+    def _wide_statics(self, S, glob_pad, reg_start, reg_end, bits) -> dict:
+        """Static geometry of the wide pass: a window as wide as the
+        widest region of its kind (pow2, so growth inside it keeps the
+        signature), never wider than the table."""
+        amax, gmax = self._region_maxima(reg_start, reg_end)
+        return dict(id_bits=bits, glob_pad=glob_pad,
+                    wa=min(_pow2ceil(max(amax, 2048)), S),
+                    wb=min(_pow2ceil(max(gmax, 2048)), S) if gmax else 0)
+
+    @staticmethod
+    def _wide_sig(U: int, L: int, S: int, statics: dict) -> tuple:
+        return ("wide", U, L, S, tuple(sorted(statics.items())))
+
+    def _wide_pass(self, operands, meta, reg_start, reg_end, glob_pad,
+                   bits, S, parts, require_warm: bool) -> None:
+        """Answer on the device the publishes the flat form flagged
+        ``overflow``. ``parts``: one ``(pw, pl, pd, pb, gb, over, total,
+        idx_rows, need_host)`` a batch of the dispatch — ``over`` the
+        flagged publishes' positions; ``idx_rows[i]`` is replaced by the
+        publish's whole list of slot ids. Identical topics, of one batch
+        or of several, are matched once. A wide answer shorter than what
+        the flat form itself counted for the publish (``total``: its
+        parts' counts clamped at k) is no answer: counted, host-matched."""
+        pub = np.concatenate([
+            np.concatenate([pw[o], pl[o, None], pd[o, None],
+                            pb[o, None], gb[o, None]], axis=1)
+            for pw, pl, pd, pb, gb, o, *_ in parts])
+        uniq, inv = np.unique(pub, axis=0, return_inverse=True)
+        inv = inv.ravel()
+        L = uniq.shape[1] - 4
+        statics = self._wide_statics(S, glob_pad, reg_start, reg_end, bits)
+        wa, wb = statics["wa"], statics["wb"]
+        F_t, t1 = operands
+        ids: List[np.ndarray] = []
+        calls = 0
+        for c0 in range(0, len(uniq), WIDE_RUNGS[-1]):
+            chunk = uniq[c0:c0 + WIDE_RUNGS[-1]]
+            n = len(chunk)
+            U = next(r for r in WIDE_RUNGS if r >= n)
+            sig = self._wide_sig(U, L, S, statics)
+            if require_warm and sig not in self._warm_sigs:
+                self.busy_sheds += 1
+                raise MatcherBusy(cold=True)
+            pw = np.full((U, L), np.int32(K.PAD_ID), np.int32)
+            pl = np.zeros(U, np.int32)
+            pd = np.zeros(U, np.int32)
+            pw[:n], pl[:n], pd[:n] = chunk[:, :L], chunk[:, L], chunk[:, L + 1]
+            wins = []
+            for col, w in ((L + 2, wa), (L + 3, wb)):
+                # pad publishes keep an empty region: lo == hi == 0
+                win = np.zeros((U, 3), np.int64)
+                if w:
+                    win[:n, 1] = reg_start[chunk[:, col]]
+                    win[:n, 2] = reg_end[chunk[:, col]]
+                    win[:n, 0] = np.minimum(win[:n, 1], S - w)
+                wins.append(win)
+            out = np.asarray(K.call_wide(F_t, t1, meta, pw, pl, pd,
+                                         wins[0], wins[1], statics))
+            self._warm_sigs.add(sig)
+            calls += 1
+            ids.extend(K.unpack_wide_bits(out[u], glob_pad, wa,
+                                          int(wins[0][u, 0]),
+                                          int(wins[1][u, 0]))
+                       for u in range(n))
+        at = pubs = nrows = short = 0
+        for *_, over, total, idx_rows, need_host in parts:
+            for i in over:
+                rows = ids[inv[at]]
+                at += 1
+                if len(rows) < total[i]:
+                    short += 1
+                    need_host[i] = True
+                    continue
+                idx_rows[i] = rows
+                pubs += 1
+                nrows += len(rows)
+        global wide_publishes, wide_dispatches, wide_topics, wide_rows, \
+            wide_failures
+        with _wide_lock:
+            wide_dispatches += calls
+            wide_topics += len(uniq)
+            wide_publishes += pubs
+            wide_rows += nrows
+            wide_failures += short
+
+    def _warm_wide(self) -> int:
+        """Compile the wide pass's rungs for the table as it stands, if
+        one of its publishes can overflow the flat form at all: a table
+        whose fan-out bound is under both caps (``max_fanout`` a part,
+        ``flat_avg`` a publish on average) never dispatches them and
+        compiles none. Returns the rungs run; raises what a warm-up
+        ``match_batch`` raises."""
+        with self.lock:
+            try:
+                self.sync()
+            except RebuildInProgress:
+                return 0
+            if not (self._bucketed and self._operands is not None
+                    and self._meta is not None
+                    and self.table.fanout_bound
+                    > min(self.max_fanout, self.flat_avg)):
+                return 0
+            operands, meta = self._operands, self._meta
+            reg_start, reg_end = self._reg_start, self._reg_end
+            S, L = int(self._dev_arrays[0].shape[0]), self.table.L
+            statics = self._wide_statics(S, self._glob_pad, reg_start,
+                                         reg_end, self._ops_bits)
+            self._inflight += 1
+        done = 0
+        try:
+            for U in WIDE_RUNGS:
+                sig = self._wide_sig(U, L, S, statics)
+                if self._closed or sig in self._warm_sigs:
+                    continue
+                z = np.zeros((U, 3), np.int64)
+                np.asarray(K.call_wide(
+                    *operands, meta,
+                    np.full((U, L), np.int32(K.PAD_ID), np.int32),
+                    np.zeros(U, np.int32), np.zeros(U, np.int32), z, z,
+                    statics))
+                self._warm_sigs.add(sig)
+                done += 1
+        except Exception as e:
+            self._record_device_failure(e)  # raises DeviceDegraded
+        finally:
+            with self.lock:
+                self._inflight -= 1
+        return done
 
     def _host_match(self, topic: Sequence[str], snapshot=None) -> List[Row]:
         from ..protocol.topic import match_dollar_aware
@@ -1943,6 +2137,7 @@ class BatchCollector:
 
         self._order: "_collections.deque" = _collections.deque()
         self._releasing = False  # a _release callback is scheduled
+        self.release_rows = 0  # matched rows (at least 1 a submission) released
 
     def pressure(self) -> float:
         """Device-path pressure in [0, 1] for the overload governor:
@@ -2012,6 +2207,24 @@ class BatchCollector:
     #: subscriptions: 1-5 s, which the overload governor answers by
     #: disconnecting the publishers).
     _RELEASE_CHUNK = 64
+    #: ...and matched rows released per loop callback: a submission
+    #: costs its rows (at least one), since routing it enters that many
+    #: sessions' windows and queues that many frames (~5.4 µs a row on
+    #: the v5e's host inside a 1,000-recipient fan-out; the socket writes
+    #: are the outbox's, bounded there: ``egress.FLUSH_MAX``). A callback
+    #: releases submissions until either budget is spent and always one,
+    #: so a publish wider than the budget leaves whole (its recipients
+    #: share one Msg and one header batch). 64 entries of ``tpu_flat_avg``
+    #: 128 rows: ~45 ms of routing, so that a timer, which waits two
+    #: callbacks, runs ~0.12 s late at most — half of
+    #: ``sysmon_lag_threshold``. What the budget trades (PERF.md §6, PR 32,
+    #: 1,000 subscribers a topic): a smaller one splits a tick's routing
+    #: into more callbacks, each ending in a flush, so a socket gets more
+    #: writes of fewer frames (2,048 rows: thirteen callbacks and ~7
+    #: writes a socket for 25 publishes); a larger one holds the loop
+    #: longer than the lag alarm allows (16,384 rows: the loop 0.18–0.36 s
+    #: late, the alarm rang in one run of seven).
+    _RELEASE_ROWS = 64 * 128
 
     def _settle(self, ent, res=None, exc=None) -> None:
         """Record a submission's result. Settled entries are released to
@@ -2031,13 +2244,24 @@ class BatchCollector:
             asyncio.get_event_loop().call_soon(self._release)
 
     def _release(self) -> None:
+        with obs.span("stage_release_turn_ms"):
+            self._release_chunk()
+
+    def _release_chunk(self) -> None:
         order = self._order
         budget = self._RELEASE_CHUNK
+        rows_left = self._RELEASE_ROWS
         while order and order[0].ready and budget:
-            ent = order.popleft()
+            ent = order[0]
+            cost = len(ent.res) if ent.res else 1
+            if cost > rows_left and budget < self._RELEASE_CHUNK:
+                break  # the next callback's
+            order.popleft()
             fut = ent.fut
             if fut is not None and fut.done():  # cancelled by the caller
                 continue
+            rows_left -= cost
+            self.release_rows += cost
             if budget == self._RELEASE_CHUNK and ent.t:
                 # how long this chunk's head stood settled while the
                 # chunks before it were released and routed: a wait, so

@@ -108,6 +108,14 @@ class SubscriptionTable:
         # filters longer than L levels: host-trie overflow (kept tiny)
         self.overflow = SubscriptionTrie()
         self.count = 0
+        # what bounds a publish's fan-out, kept as rows come and go: a
+        # filter without wildcards matches one topic only, so a publish
+        # matches at most the rows of ONE such filter plus the rows whose
+        # filter holds a wildcard
+        self._plain_rows: Dict[Tuple[str, ...], int] = {}
+        self._plain_hist: Dict[int, int] = {}  # rows a filter -> filters
+        self._plain_peak = 0
+        self._wild_rows = 0
         self.entries: List[Optional[Tuple[Tuple[str, ...], Hashable, Any]]] = []
         self._alloc_regions(max(initial_capacity, 16))
 
@@ -379,6 +387,7 @@ class SubscriptionTable:
             return
         self._insert(fw, key, value)
         self.count += 1
+        self._count_row(fw, 1)
 
     def remove(self, filter_words: Sequence[str], key: Hashable) -> bool:
         fw = tuple(filter_words)
@@ -396,7 +405,35 @@ class SubscriptionTable:
         self._free[region].append(slot)
         self.dirty.add(slot)
         self.count -= 1
+        self._count_row(fw, -1)
         return True
+
+    def _count_row(self, fw: Tuple[str, ...], d: int) -> None:
+        """One device row of filter ``fw`` came (``d`` = 1) or went (-1)."""
+        if PLUS in fw or (fw and fw[-1] == HASH):
+            self._wild_rows += d
+            return
+        hist = self._plain_hist
+        c = self._plain_rows.get(fw, 0)
+        if c:
+            hist[c] -= 1
+        if c + d:
+            self._plain_rows[fw] = c + d
+            hist[c + d] = hist.get(c + d, 0) + 1
+        else:
+            del self._plain_rows[fw]
+        if c + d > self._plain_peak:
+            self._plain_peak = c + d
+        elif c == self._plain_peak and not hist[c]:
+            self._plain_peak = c + d  # the one filter at the peak lost a row
+
+    @property
+    def fanout_bound(self) -> int:
+        """The most device rows one publish can match as the table
+        stands: the rows of its largest wildcard-free filter plus every
+        row whose filter holds a wildcard (whether or not they can match
+        the same topic: a bound, not a count)."""
+        return self._plain_peak + self._wild_rows
 
     # ---------------------------------------------------------- publish side
 

@@ -152,10 +152,21 @@ STAGE_FAMILIES: List[Tuple[str, str]] = [
     ("stage_fold_resolve_ms",
      "Device fold host resolve, per dispatch: result unpack and slot "
      "ids to entry rows, up to the fold's return."),
+    ("stage_fold_wide_ms",
+     "Device fold wide pass, per dispatch that had one: publishes the "
+     "flat result's caps (tpu_max_fanout a part, flat capacity a "
+     "batch) cut off are matched again for their whole bit mask — "
+     "launch, pull, bits to slot ids."),
+    ("stage_release_turn_ms",
+     "One release callback of the collector: the submissions it "
+     "releases (at most 64, at most 8192 matched rows, at least one), "
+     "their inline routes and acknowledgements, and the outbox flush "
+     "that ends it."),
     ("stage_release_wait_ms",
      "Collector release-queue wait per release chunk: from the "
      "settling of a chunk's head submission to its release "
-     "(submissions leave in submission order, 64 per loop callback: a "
+     "(submissions leave in submission order, at most 64 and 8192 "
+     "matched rows per loop callback: a "
      "continuation routes and acknowledges inline, a future wakes "
      "the session that awaits it)."),
     ("stage_route_ms",
@@ -167,8 +178,8 @@ STAGE_FAMILIES: List[Tuple[str, str]] = [
     ("stage_egress_flush_ms",
      "Outbox flush per loop turn that wrote: the fold of the turn's "
      "egress counters and one socket write per transport written in "
-     "the turn, back to back (broker/egress.py; a release chunk's 64 "
-     "deliveries and 64 PUBACKs are one flush)."),
+     "the turn, back to back, at most 256 a flush (broker/egress.py; a "
+     "release chunk's 64 deliveries and 64 PUBACKs are one flush)."),
     ("stage_ack_in_ms",
      "Inbound PUBACK/PUBCOMP handling per ack: in-flight window "
      "bookkeeping, pending pump and queue notify_ready."),

@@ -799,15 +799,18 @@ def match_extract_windowed_flat_packed(
                         seg_max=seg_max, seg2_max=seg2_max, gc=gc, C=C)
 
 
+def _unpack_meta(meta):
+    """The pack_meta word -> ``(eff_len, has_hash, first_wild, active)``."""
+    return (meta & 0xFFFF, ((meta >> 16) & 1).astype(bool),
+            ((meta >> 17) & 1).astype(bool), ((meta >> 18) & 1).astype(bool))
+
+
 def _unpack_transport(meta, packed, B, L, T, TP, T2):
     """THE decoder of the flat_pack_args layout + pack_meta word — the
     single counterpart to the host-side packers; every packed kernel
     entry point goes through here so the layout cannot drift between
     variants. Returns the 18-arg tail of the unpacked kernels."""
-    eff = meta & 0xFFFF
-    hh = ((meta >> 16) & 1).astype(bool)
-    fw = ((meta >> 17) & 1).astype(bool)
-    act = ((meta >> 18) & 1).astype(bool)
+    eff, hh, fw, act = _unpack_meta(meta)
     o = 0
     pw = packed[o:o + B * L].reshape(B, L); o += B * L
     pl = packed[o:o + B]; o += B
@@ -905,6 +908,121 @@ def unpack_many_results(out, B: int, C: int):
     where the caller pulled already, to time the pull by itself)."""
     o = np.asarray(out)
     return [unpack_flat_result(o[i], B, C) for i in range(o.shape[0])]
+
+
+def wide_pack_args(pw, pl, pd, a_win, b_win) -> "np.ndarray":
+    """Host side of the wide pass's transport: the ``U`` publishes' coded
+    levels, lengths and ``$`` flags and their two windows (``[U, 3]``
+    each: first row of the window, first and end row of the publish's
+    OWN region inside it), ONE int32 vector. Mirrored by
+    :func:`wide_mask_packed`."""
+    return np.concatenate([
+        np.ascontiguousarray(pw, dtype=np.int32).ravel(),
+        np.asarray(pl, dtype=np.int32).ravel(),
+        np.asarray(pd, dtype=np.int32).ravel(),
+        np.ascontiguousarray(a_win, dtype=np.int32).T.ravel(),
+        np.ascontiguousarray(b_win, dtype=np.int32).T.ravel(),
+    ])
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("U", "L", "id_bits", "glob_pad", "wa",
+                                    "wb"))
+def wide_mask_packed(
+    F_t: jax.Array,          # bf16 [K, S] coded operands (build_operands)
+    t1: jax.Array,           # f32 [S]
+    meta: jax.Array,         # int32 [S] pack_meta word
+    packed: jax.Array,       # int32 [U*(L+8)] wide_pack_args vector
+    *,
+    U: int, L: int, id_bits: int, glob_pad: int, wa: int, wb: int,
+) -> jax.Array:
+    """The WIDE result of the windowed match: for each of ``U`` publishes
+    the bit-packed match mask of ALL the rows it can match — region 0,
+    its level-0 bucket's region (a window of ``wa`` rows) and, where the
+    table has g-buckets (``wb`` > 0), its level-1 g-bucket's region — so a
+    fan-out is bounded by the table and by no ``k``. The flat form
+    (:func:`match_extract_windowed_flat_packed`) answers a publish with
+    at most ``k`` ids a part and ``C`` a batch; what it flags
+    ``overflow`` is answered here, and the host turns bits into slot ids
+    (``TpuMatcher._wide_pass``).
+
+    The same exact test as the flat form (coded mismatch == 0 and the
+    length / ``$`` / liveness epilogue), a window's rows confined to the
+    publish's own region ``[lo, hi)``: a bucket's region holds only
+    concrete-first rows and a g-bucket's only wildcard-first ones, so no
+    row is counted twice. The windows are walked one publish at a time
+    (``lax.map``): a fan-out burst repeats few topics, and each
+    ``[1, K] x [K, w]`` product reads its window once.
+
+    Returns uint32 ``[U, (glob_pad + wa + wb) / 32]``: bit ``j`` of a
+    row is region-0 row ``j``, then row ``a_start + j - glob_pad`` of
+    the first window, then of the second."""
+    with jax.named_scope("wide_mask"):
+        eff, hh, fw, act = _unpack_meta(meta)
+        o = U * L
+        pw = packed[:o].reshape(U, L)
+        pl = packed[o:o + U]
+        pd = packed[o + U:o + 2 * U].astype(bool)
+        win = packed[o + 2 * U:].reshape(6, U)
+        Kd = F_t.shape[0]
+        G = build_pub_operand(pw, id_bits)
+        mm = lax.dot_general(
+            G, F_t[:, :glob_pad], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) + t1[None, :glob_pad]
+        m0 = (mm == 0.0) & _epilogue(
+            pl, pd, eff[:glob_pad], hh[:glob_pad], fw[:glob_pad],
+            act[:glob_pad])
+        parts = [_pack_mask(m0)]
+
+        def window(width, start, lo, hi):
+            j = jnp.arange(width, dtype=jnp.int32)
+
+            def one(x):
+                g, plen, pdol, s0, r0, r1 = x
+                Fseg = lax.dynamic_slice(F_t, (0, s0), (Kd, width))
+                t1s = lax.dynamic_slice(t1, (s0,), (width,))
+                sl = lambda a: lax.dynamic_slice(a, (s0,), (width,))
+                mw = lax.dot_general(
+                    g[None, :], Fseg, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) + t1s[None, :]
+                row = s0 + j
+                m = (mw == 0.0) & _epilogue(
+                    plen[None], pdol[None], sl(eff), sl(hh), sl(fw),
+                    sl(act)) & ((row >= r0) & (row < r1))[None, :]
+                return _pack_mask(m)[0]
+
+            return lax.map(one, (G, pl, pd, start, lo, hi))
+
+        parts.append(window(wa, win[0], win[1], win[2]))
+        if wb:
+            parts.append(window(wb, win[3], win[4], win[5]))
+        return jnp.concatenate(parts, axis=1)
+
+
+def call_wide(F_t, t1, meta, pw, pl, pd, a_win, b_win, statics):
+    """The one call shape of the wide pass (``device.dispatch`` fault
+    point, as :func:`call_packed`)."""
+    from ..robustness import faults
+
+    faults.inject("device.dispatch")
+    U, L = pw.shape
+    return wide_mask_packed(F_t, t1, meta,
+                            wide_pack_args(pw, pl, pd, a_win, b_win),
+                            U=U, L=L, **statics)
+
+
+def unpack_wide_bits(words: "np.ndarray", glob_pad: int, wa: int,
+                     a_start: int, b_start: int) -> "np.ndarray":
+    """One publish's row of :func:`wide_mask_packed` -> its matched slot
+    ids (int32, ascending inside each of the three parts)."""
+    nz = np.flatnonzero(np.unpackbits(
+        np.ascontiguousarray(words).view(np.uint8), bitorder="little"))
+    a = nz >= glob_pad
+    b = nz >= glob_pad + wa
+    return (nz + a * (a_start - glob_pad)
+            + b * (b_start - a_start - wa)).astype(np.int32)
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4))

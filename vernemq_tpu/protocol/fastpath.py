@@ -106,6 +106,7 @@ task_chunks = 0         #: chunks, or remainders, handed to the connection's tas
 fanout_batches = 0      #: batched fanout header encodes (one per fanout)
 egress_flushes = 0      #: outbox flushes (one a loop turn that wrote)
 egress_writes = 0       #: transports written by them
+egress_publishes = 0    #: PUBLISH frames those writes carried
 egress_joined = 0       #: of those, several chunks sent as one joined write
 egress_scattered = 0    #: of those, several chunks sent through writelines
 
@@ -165,6 +166,7 @@ def stats():
         "wire_fanout_batches": float(fanout_batches),
         "wire_egress_flushes": float(egress_flushes),
         "wire_egress_writes": float(egress_writes),
+        "wire_egress_publishes": float(egress_publishes),
         "wire_egress_joined": float(egress_joined),
         "wire_egress_scattered": float(egress_scattered),
         "wire_breaker_state": float(breaker.state),

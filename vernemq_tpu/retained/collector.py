@@ -177,9 +177,15 @@ class RetainedBatchCollector:
         dispatch is reduced headroom, not overload)."""
         from ..robustness.overload import collector_pressure
 
+        # as BatchCollector.pressure: the EWMA only folds on a flush, so
+        # with nothing queued or in flight it is the memory of the last
+        # storm (one slow first dispatch of a SUBSCRIBE with several
+        # filters), not pressure — left in, it holds the governor at L1
+        # for as long as nobody subscribes again
+        idle = not self._pending and not self._inflight
         return collector_pressure(
             len(self._pending), self.max_batch * self.MAX_INFLIGHT,
-            self.dispatch_ewma_ms, self.latency_budget_ms)
+            0.0 if idle else self.dispatch_ewma_ms, self.latency_budget_ms)
 
     def _flush(self) -> None:
         self._flush_handle = None

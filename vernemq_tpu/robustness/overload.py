@@ -540,10 +540,7 @@ class OverloadGovernor:
         # little as 0.1x, so shedding lands on the load source instead
         # of collapsing p99 for everyone (the binary flag's failure
         # mode). With no rate history yet everyone pays the base.
-        mean = self._rates_mean
-        share = (self._talker_rates.get(sid, 0.0) / mean) \
-            if mean > 0 else 1.0
-        delay = self.l1_throttle_s * lv * min(4.0, max(0.1, share))
+        delay = self.reader_delay(sid)
         if lv >= 2:
             wait = self._token_wait(sid, time.monotonic())
             if wait > 0:
@@ -554,6 +551,27 @@ class OverloadGovernor:
             # configured to 0 the counter must not climb at publish rate
             self.broker.metrics.incr("overload_publish_throttled")
         return delay
+
+    def reader_delay(self, sid: Any) -> float:
+        """The proportional pause one inbound PUBLISH of ``sid`` owes at
+        the level in force (0.0 at level 0), before any token wait."""
+        mean = self._rates_mean
+        share = (self._talker_rates.get(sid, 0.0) / mean) \
+            if mean > 0 else 1.0
+        return self.l1_throttle_s * self.level * min(4.0, max(0.1, share))
+
+    async def pause_reader(self, n: int, delay: float) -> None:
+        """Level 1 on the wire plane (``Session.wire_pause``): the pauses
+        of ``n`` inbound PUBLISHes of one recv chunk, ``delay`` each,
+        slept as one. The session counts as parked meanwhile; the wire
+        plane books its talker counts when it admits them
+        (``record_publish_n``)."""
+        self.broker.metrics.incr("overload_publish_throttled", n)
+        self._active_throttles += 1
+        try:
+            await asyncio.sleep(n * delay)
+        finally:
+            self._active_throttles -= 1
 
     def _token_wait(self, sid: Any, now: float) -> float:
         rate = self.l2_client_rate
