@@ -89,15 +89,17 @@ def matcher():
     return m
 
 
-def _prep(m, n, align=0):
+def _prep(m, n, align=0, live=None):
     """(args, statics) of a batch of ``n`` publishes — shapes depend on
-    the padded batch and the table geometry only, not on the topics."""
+    the padded batch and the table geometry only, not on the topics.
+    ``live``: the table's own counts of live rows in region 0 and the
+    g-buckets unless given (they decide which phases the program holds)."""
     topics = [("warmup", "ladder", str(i)) for i in range(n)]
     pw, pl, pd, pb, gb = m._encode_batch_ex(topics)
     S = int(m._dev_arrays[0].shape[0])
     args, statics, _left = m._flat_prep(
-        m._reg_start, m._reg_end, m._glob_pad, m._ops_bits, S,
-        pw, pl, pd, pb, gb, n, align=align)
+        m._reg_start, m._reg_end, m._glob_pad, live or m._live,
+        m._ops_bits, S, pw, pl, pd, pb, gb, n, align=align)
     return args, statics
 
 
@@ -123,17 +125,17 @@ def _total_bytes(compiled) -> int:
 _COMPILED = {}  # a program is compiled once for the tests that read it
 
 
-def _compile_packed(m, one_chip, n):
+def _compile_packed(m, one_chip, n, live=None):
     from vernemq_tpu.ops import match_kernel as K
 
-    if ("packed", n) not in _COMPILED:
-        args, statics = _prep(m, n)
+    if ("packed", n, live) not in _COMPILED:
+        args, statics = _prep(m, n, live=live)
         packed = K.flat_pack_args(args)
-        _COMPILED["packed", n] = \
+        _COMPILED["packed", n, live] = \
             K.match_extract_windowed_flat_packed.lower(
                 *_table_sds(m, one_chip), _sds(packed, one_chip),
                 **K._packed_geometry(args), **statics).compile()
-    return _COMPILED["packed", n]
+    return _COMPILED["packed", n, live]
 
 
 @pytest.mark.parametrize("n", [4096, 9], ids=["B4096", "Bmin"])
@@ -170,7 +172,7 @@ def _compile_wide(m, one_chip, U):
     if ("wide", U) not in _COMPILED:
         S, L = int(m._dev_arrays[0].shape[0]), m.table.L
         statics = m._wide_statics(S, m._glob_pad, m._reg_start,
-                                  m._reg_end, m._ops_bits)
+                                  m._reg_end, m._live, m._ops_bits)
         z = np.zeros((U, 3), np.int64)
         packed = K.wide_pack_args(np.zeros((U, L), np.int32),
                                   np.zeros(U, np.int32),
@@ -222,24 +224,36 @@ def test_delta_scatter_compiles(matcher, one_chip):
     assert _total_bytes(_compile_delta(matcher, one_chip)) < HBM_BYTES
 
 
-@pytest.mark.parametrize("program,scopes", [
-    ("packed", ("unpack_transport", "dense_region0", "probe_a", "probe_b",
-                "flat_combine")),
-    ("delta", ("delta_scatter",)),
-    ("wide", ("wide_mask",))])
-def test_device_programs_name_their_phases(matcher, one_chip, program,
-                                           scopes):
+_FLAT = ("unpack_transport", "flat_combine", "probe_a")
+
+
+@pytest.mark.parametrize("program,live,scopes,absent", [
+    # the 1M corpus holds ``+/w/w`` filters and none with both first
+    # levels wild, so its own program is probe A + probe B; the forms
+    # around it are compiled from other counts on the same geometry
+    ("packed", None, _FLAT + ("probe_b",), ("dense_region0",)),
+    ("packed", (0, 0), _FLAT, ("dense_region0", "probe_b")),
+    ("packed", (1, 1), _FLAT + ("dense_region0", "probe_b"), ()),
+    ("delta", None, ("delta_scatter",), ()),
+    ("wide", None, ("wide_mask",), ())],
+    ids=["packed", "packed_a", "packed_gab", "delta", "wide"])
+def test_device_programs_name_their_phases(matcher, one_chip, program, live,
+                                           scopes, absent):
     """``jax.named_scope`` reaches the v5e's compiled program: every phase
     of the match and the delta scatter is the ``op_name`` of instructions
     that survived optimisation, which is where a device trace's
-    operations are attributed from (``benchmark/trace/spans.py``)."""
-    compiled = {"packed": lambda: _compile_packed(matcher, one_chip, 9),
-                "delta": lambda: _compile_delta(matcher, one_chip),
-                "wide": lambda: _compile_wide(matcher, one_chip, 8)
-                }[program]()
+    operations are attributed from (``benchmark/trace/spans.py``) — and a
+    phase whose rows hold nothing live is not in the program at all."""
+    assert matcher._live[0] == 0 < matcher._live[1]
+    compiled = {
+        "packed": lambda: _compile_packed(matcher, one_chip, 9, live),
+        "delta": lambda: _compile_delta(matcher, one_chip),
+        "wide": lambda: _compile_wide(matcher, one_chip, 8)}[program]()
     text = compiled.as_text()
     for scope in scopes:
         assert f"/{scope}/" in text, scope
+    for scope in absent:
+        assert f"/{scope}/" not in text, scope
 
 
 def test_pallas_match_compiles(matcher, one_chip):

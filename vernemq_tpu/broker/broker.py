@@ -322,6 +322,13 @@ class Broker:
             "tpu_wide_failures": "Publishes whose wide answer fell short "
                                  "of the flat form's own count "
                                  "(host-matched instead).",
+            "tpu_phase_dispatches": "Windowed match programs executed "
+                                    "for live traffic (single or super "
+                                    "dispatch).",
+            "tpu_phase_runs": "Match phases compiled into those programs, "
+                              "summed (1-3 each: the dense pass over "
+                              "region 0 and probe B are left out while "
+                              "their rows hold no live subscription).",
             "tpu_release_rows": "Matched rows (at least one a "
                                 "submission) the collector's release "
                                 "queue released.",

@@ -1322,6 +1322,10 @@ class Registry:
             out["tpu_wide_topics"] = _tm.wide_topics
             out["tpu_wide_rows"] = _tm.wide_rows
             out["tpu_wide_failures"] = _tm.wide_failures
+            # the flat form's phases: programs executed and the phases
+            # compiled into them (1-3 each; 1 = probe A alone)
+            out["tpu_phase_dispatches"] = _tm.phase_dispatches
+            out["tpu_phase_runs"] = _tm.phase_runs
         col = getattr(self.broker, "_collector", None)
         if col is not None:
             # small flushes served host-side by hybrid dispatch

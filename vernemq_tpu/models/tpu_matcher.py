@@ -50,14 +50,21 @@ _rebuild_tls = threading.local()
 #: and the device answered whole, the device calls that did, the distinct
 #: topics those matched, the rows they brought back, and publishes whose
 #: wide answer fell short of the flat form's own count (host-matched).
-#: Folded once a wide pass, under ``_wide_lock`` (an executor thread a
+#: Folded once a wide pass, under ``_totals_lock`` (an executor thread a
 #: matcher).
 wide_publishes = 0
 wide_dispatches = 0
 wide_topics = 0
 wide_rows = 0
 wide_failures = 0
-_wide_lock = threading.Lock()
+#: The flat form's phases, process totals beside them (gauges
+#: ``tpu_phase_*``): executions of a windowed match program, single or
+#: super, that counted as a dispatch, and the phases compiled into each
+#: (1-3: dense region 0, probe A, probe B), summed. Folded once a
+#: dispatch, where its ring record is written.
+phase_dispatches = 0
+phase_runs = 0
+_totals_lock = threading.Lock()
 
 
 TILE_PUBS = 256  # pubs per window tile (MXU row-tile friendly)
@@ -289,13 +296,16 @@ class _FoldPhases:
     (``stage_device_dispatch_ms``'s rule: no warm-up, no abandoned
     straggler, no failure)."""
 
-    __slots__ = ("t_in", "lock_wait_ms", "ms", "_open")
+    __slots__ = ("t_in", "lock_wait_ms", "ms", "_open", "phases")
 
     def __init__(self) -> None:
         self.t_in = time.monotonic()
         self.lock_wait_ms = 0.0
         self.ms: Dict[str, float] = {}
         self._open = None
+        # the phases of the windowed program this fold executed ("a",
+        # "ab", "gab", ...); empty for the unbucketed full scan
+        self.phases = ""
 
     def enter(self, span) -> None:
         """Close the phase that is open and open ``span``."""
@@ -315,6 +325,7 @@ class _FoldPhases:
     def record(self, t_disp: float, dur: float, **fields: Any) -> None:
         """One observation a family and the dispatch ring's record
         (``dur``: what ``stage_device_dispatch_ms`` observed)."""
+        global phase_dispatches, phase_runs
         self.close()
         ms = self.ms.get
         prep = ms("stage_fold_prep_ms", 0.0)
@@ -329,6 +340,11 @@ class _FoldPhases:
         if wide is not None:  # a dispatch that had a wide pass
             obs.observe("stage_fold_wide_ms", wide)
             fields["wide_ms"] = round(wide, 4)
+        if self.phases:
+            fields["phases"] = self.phases
+            with _totals_lock:
+                phase_dispatches += 1
+                phase_runs += len(self.phases)
         record_dispatch(
             "match", t_disp, dur, prep_ms=round(prep, 4),
             launch_ms=round(launch, 4), wait_ms=round(wait, 4),
@@ -367,6 +383,9 @@ class TpuMatcher:
         self._reg_start: Optional[np.ndarray] = None
         self._reg_end: Optional[np.ndarray] = None
         self._glob_pad = 0
+        # SubscriptionTable.live_rows as of the device arrays: pinned
+        # with the geometry above, never read off the table at dispatch
+        self._live: Tuple[int, int] = (0, 0)
         self._bucketed = False
         self.match_batches = 0
         self.match_publishes = 0
@@ -470,7 +489,7 @@ class TpuMatcher:
             "active": c(t.active), "bits": t.id_bits,
             "reg_start": t.reg_start.copy(),
             "reg_end": (t.reg_start + t.reg_cap).copy(),
-            "glob_pad": int(t.reg_cap[0]),
+            "glob_pad": int(t.reg_cap[0]), "live": t.live_rows,
             "gb_end": t.gb_end if t.bucketed else int(t.reg_cap[0]),
             "ng": t.NG, "bucketed": t.bucketed, "entries": entries,
         }
@@ -658,6 +677,7 @@ class TpuMatcher:
         self._reg_start = state["reg_start"]
         self._reg_end = state["reg_end"]
         self._glob_pad = state["glob_pad"]
+        self._live = state["live"]
         self._gb_end = state["gb_end"]
         self._ng = state["ng"]
         self._bucketed = state["bucketed"]
@@ -821,9 +841,11 @@ class TpuMatcher:
             t.resized = True
             raise
         # region geometry may have moved WITHOUT a resize (bucket
-        # relocation into the spare tail) — refresh the window view
+        # relocation into the spare tail) — refresh the window view, and
+        # the live counts that say which phases the scattered rows need
         self._reg_start = t.reg_start.copy()
         self._reg_end = (t.reg_start + t.reg_cap).copy()
+        self._live = t.live_rows
 
     def _apply_delta_device(self, slots: np.ndarray) -> None:
         """Device half of a delta sync: scatter the (padded) ``slots``
@@ -1111,6 +1133,7 @@ class TpuMatcher:
             if bucketed:
                 reg_start, reg_end = self._reg_start, self._reg_end
                 glob_pad, bits = self._glob_pad, self._ops_bits
+                live = self._live
                 pw, pl, pd, pb, gb = self._encode_batch_ex(topics)
             else:
                 pw, pl, pd = self.encode_batch(topics)
@@ -1131,8 +1154,8 @@ class TpuMatcher:
             if bucketed:
                 idx_rows, need_host = self._match_windowed(
                     dev_arrays, operands, meta, reg_start, reg_end,
-                    glob_pad, bits, pw, pl, pd, pb, gb, len(topics), ph,
-                    require_warm=require_warm)
+                    glob_pad, live, bits, pw, pl, pd, pb, gb, len(topics),
+                    ph, require_warm=require_warm)
             else:
                 chunk = 1024 if pw.shape[0] > 1024 else 0  # lax.map serialises
                 # full-scan fallback: MXU matmul path needs byte-splittable
@@ -1280,6 +1303,7 @@ class TpuMatcher:
             if fast:
                 reg_start, reg_end = self._reg_start, self._reg_end
                 glob_pad, bits = self._glob_pad, self._ops_bits
+                live = self._live
                 S = int(dev_arrays[0].shape[0])
                 Bpad = max(self._pad_batch(len(b)) for b in batches)
                 # only the encode (table interner access) needs the
@@ -1319,10 +1343,11 @@ class TpuMatcher:
             statics = None
             for topics, (pw, pl, pd, pb, gb) in zip(batches, encoded):
                 args, statics, left = self._flat_prep(
-                    reg_start, reg_end, glob_pad, bits, S,
+                    reg_start, reg_end, glob_pad, live, bits, S,
                     pw, pl, pd, pb, gb, len(topics))
                 preps.append(args)
                 lefts.append(left)
+            ph.phases = self._phases(statics)
             sig = ("many", len(batches),
                    tuple(a.shape for a in preps[0]),
                    tuple(sorted(statics.items())))
@@ -1352,7 +1377,7 @@ class TpuMatcher:
             if wide:  # ONE wide pass for the K batches' overflowed topics
                 ph.enter(obs.span("stage_fold_wide_ms"))
                 self._wide_pass(operands, meta, reg_start, reg_end,
-                                glob_pad, bits, S, wide, require_warm)
+                                glob_pad, live, bits, S, wide, require_warm)
                 ph.enter(obs.span("stage_fold_resolve_ms"))
         except MatcherBusy:
             raise
@@ -1425,22 +1450,30 @@ class TpuMatcher:
         threading.Thread(target=_w, name=f"tpu-warm-many-{n_batches}",
                          daemon=True).start()
 
-    def _region_maxima(self, reg_start, reg_end) -> Tuple[int, int]:
+    def _region_maxima(self, reg_start, reg_end, live) -> Tuple[int, int]:
         """Rows of the widest level-0 bucket region and of the widest
-        g-bucket region (0 where the table has no g-buckets)."""
+        g-bucket region — 0 where the table has no g-buckets or, by the
+        pinned ``live`` counts, none of them holds a live row: a window
+        over the g-zone is then no part of any program."""
         ng = self._ng
         amax = (int((reg_end[1 + ng:] - reg_start[1 + ng:]).max())
                 if len(reg_start) > 1 + ng else 0)
         gmax = (int((reg_end[1:1 + ng] - reg_start[1:1 + ng]).max())
-                if ng else 0)
+                if ng and live[1] else 0)
         return amax, gmax
 
-    def _geometry(self, S, glob_pad, reg_start, reg_end, Bpad, align=0):
-        """Static kernel geometry for both probes at this batch size."""
+    def _geometry(self, S, glob_pad, reg_start, reg_end, live, Bpad,
+                  align=0):
+        """Static kernel geometry for both probes at this batch size. A
+        phase whose rows hold nothing live (``live``: region 0, the
+        g-buckets) gets the static that leaves it out of the program:
+        ``gc`` 0 for the dense phase, ``seg2`` 0 for probe B."""
         gb_end = self._gb_end
-        amax, gmax = self._region_maxima(reg_start, reg_end)
+        amax, gmax = self._region_maxima(reg_start, reg_end, live)
         T, seg_max, gc = window_params(S, glob_pad, amax, Bpad,
                                        zone=S - gb_end, align=align)
+        if not live[0]:
+            gc = 0
         if gmax:
             T2, seg2, _ = window_params(S, glob_pad, gmax, Bpad,
                                         zone=gb_end - glob_pad, align=align)
@@ -1448,19 +1481,28 @@ class TpuMatcher:
             T2, seg2 = 1, 0
         return T, seg_max, gc, T2, seg2, gb_end
 
-    def _flat_prep(self, reg_start, reg_end, glob_pad, bits, S,
+    @staticmethod
+    def _phases(statics: dict) -> str:
+        """Which phases a flat program of these statics holds: ``g`` the
+        dense pass over region 0, ``a`` probe A, ``b`` probe B."""
+        return (("g" if statics["gc"] else "") + "a"
+                + ("b" if statics["seg2_max"] else ""))
+
+    def _flat_prep(self, reg_start, reg_end, glob_pad, live, bits, S,
                    pw, pl, pd, pb, gb, n, align=0):
         """Host prep for :func:`K.match_extract_windowed_flat`: window
         geometry, selector tiles, per-pub tile coordinates, flat
         capacity. Returns ``(args, statics, left)`` — the kernel's
         trailing positional args + static kwargs (the leading six are the
         device table arrays), and the set of host-fallback pubs (window
-        overflow). Registry state (reg_start/…) is passed in, not read
-        off self, so a caller can pin the snapshot its device arrays were
-        built from. Shared by match_batch and match_many."""
+        overflow). Registry state (reg_start/…, the ``live`` counts) is
+        passed in, not read off self, so a caller can pin the snapshot its
+        device arrays were built from: a program compiled without a phase
+        must never meet arrays that hold a row of it. Shared by
+        match_batch and match_many."""
         Bpad = pw.shape[0]
         T, seg_max, gc, T2, seg2, gb_end = self._geometry(
-            S, glob_pad, reg_start, reg_end, Bpad, align=align)
+            S, glob_pad, reg_start, reg_end, live, Bpad, align=align)
         (t_sel, t_start, tile_of, pos_of,
          leftovers) = prepare_windows(pw, pl, pd, pb, n, reg_start,
                                       reg_end, S, T, seg_max,
@@ -1494,11 +1536,12 @@ class TpuMatcher:
         return args, statics, set(leftovers) | set(left2)
 
     def _match_windowed(self, dev_arrays, operands, meta, reg_start,
-                        reg_end, glob_pad, bits, pw, pl, pd, pb, gb, n,
-                        ph, require_warm: bool = False):
+                        reg_end, glob_pad, live, bits, pw, pl, pd, pb, gb,
+                        n, ph, require_warm: bool = False):
         """Run the windowed device path (the production kernel, flat
-        variant): a dense pass over region 0 plus probe-A (level-0
-        bucket) and probe-B (level-1 g-bucket) window tiles, compacted
+        variant): probe-A (level-0 bucket) window tiles plus, where the
+        pinned ``live`` counts say their rows hold something, a dense
+        pass over region 0 and probe-B (level-1 g-bucket) tiles, compacted
         device-side into one flat buffer. Returns (per-pub slot index
         views, need_host bool array) in original batch order; need_host
         marks pubs the device could not serve exactly (window-overflow
@@ -1509,8 +1552,9 @@ class TpuMatcher:
         pallas = (self.use_pallas and S % 2048 == 0 and glob_pad % 2048 == 0
                   and self._gb_end % 2048 == 0)
         args, statics, left = self._flat_prep(
-            reg_start, reg_end, glob_pad, bits, S, pw, pl, pd, pb, gb, n,
-            align=2048 if pallas else 0)
+            reg_start, reg_end, glob_pad, live, bits, S, pw, pl, pd, pb, gb,
+            n, align=2048 if pallas else 0)
+        ph.phases = self._phases(statics)
         # the full compile signature of this dispatch: arg shapes +
         # static kwargs (+ S via statics / shapes). Window geometry
         # depends on table CONTENT (amax), so a delta can mint new
@@ -1549,8 +1593,8 @@ class TpuMatcher:
             # what the flat form's caps cut off, the device answers whole
             ph.enter(obs.span("stage_fold_wide_ms"))
             self._wide_pass(operands, meta, reg_start, reg_end, glob_pad,
-                            bits, S, [(pw, pl, pd, pb, gb, over, total,
-                                       idx_rows, need_host)],
+                            live, bits, S, [(pw, pl, pd, pb, gb, over, total,
+                                             idx_rows, need_host)],
                             require_warm)
             ph.enter(obs.span("stage_fold_resolve_ms"))
         return idx_rows, need_host
@@ -1567,11 +1611,13 @@ class TpuMatcher:
         idx_rows = [flat[pre[i]:pre[i] + total[i]] for i in range(n)]
         return idx_rows, need_host, np.flatnonzero(overflow[:n] & ~need_host)
 
-    def _wide_statics(self, S, glob_pad, reg_start, reg_end, bits) -> dict:
+    def _wide_statics(self, S, glob_pad, reg_start, reg_end, live,
+                      bits) -> dict:
         """Static geometry of the wide pass: a window as wide as the
         widest region of its kind (pow2, so growth inside it keeps the
-        signature), never wider than the table."""
-        amax, gmax = self._region_maxima(reg_start, reg_end)
+        signature), never wider than the table; no second window
+        (``wb`` 0) while no g-bucket holds a live row."""
+        amax, gmax = self._region_maxima(reg_start, reg_end, live)
         return dict(id_bits=bits, glob_pad=glob_pad,
                     wa=min(_pow2ceil(max(amax, 2048)), S),
                     wb=min(_pow2ceil(max(gmax, 2048)), S) if gmax else 0)
@@ -1581,7 +1627,7 @@ class TpuMatcher:
         return ("wide", U, L, S, tuple(sorted(statics.items())))
 
     def _wide_pass(self, operands, meta, reg_start, reg_end, glob_pad,
-                   bits, S, parts, require_warm: bool) -> None:
+                   live, bits, S, parts, require_warm: bool) -> None:
         """Answer on the device the publishes the flat form flagged
         ``overflow``. ``parts``: one ``(pw, pl, pd, pb, gb, over, total,
         idx_rows, need_host)`` a batch of the dispatch — ``over`` the
@@ -1597,7 +1643,8 @@ class TpuMatcher:
         uniq, inv = np.unique(pub, axis=0, return_inverse=True)
         inv = inv.ravel()
         L = uniq.shape[1] - 4
-        statics = self._wide_statics(S, glob_pad, reg_start, reg_end, bits)
+        statics = self._wide_statics(S, glob_pad, reg_start, reg_end, live,
+                                     bits)
         wa, wb = statics["wa"], statics["wb"]
         F_t, t1 = operands
         ids: List[np.ndarray] = []
@@ -1645,7 +1692,7 @@ class TpuMatcher:
                 nrows += len(rows)
         global wide_publishes, wide_dispatches, wide_topics, wide_rows, \
             wide_failures
-        with _wide_lock:
+        with _totals_lock:
             wide_dispatches += calls
             wide_topics += len(uniq)
             wide_publishes += pubs
@@ -1673,7 +1720,7 @@ class TpuMatcher:
             reg_start, reg_end = self._reg_start, self._reg_end
             S, L = int(self._dev_arrays[0].shape[0]), self.table.L
             statics = self._wide_statics(S, self._glob_pad, reg_start,
-                                         reg_end, self._ops_bits)
+                                         reg_end, self._live, self._ops_bits)
             self._inflight += 1
         done = 0
         try:

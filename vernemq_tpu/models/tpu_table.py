@@ -149,8 +149,16 @@ class SubscriptionTable:
         # the dense global phase shrinks to region 0 (both levels wild)
         # while g-buckets get window probes like ordinary buckets
         # NG >= 16 keeps the g-zone >= 4096 rows (window-geometry floor);
-        # smaller bucketed tables keep wildcard-first filters dense
+        # smaller bucketed tables keep wildcard-first filters dense.
+        # NG only ALLOTS the g-zone: a match program holds the dense
+        # phase and probe B only while region 0 / the g-buckets hold a
+        # live row (``live_rows``; TpuMatcher._geometry), so a table of
+        # concrete-first filters pays for neither
         self.NG = min(64, self.NB) if self.NB >= 16 else 0
+        # live rows of region 0 and of regions 1..NG, kept by _insert /
+        # remove; the caller re-inserts entries, which counts them again
+        self._live0 = 0
+        self._liveg = 0
         self._bucket_cache: Dict[int, int] = {}
         self._gbucket_cache: Dict[int, int] = {}
         align = REGION_ALIGN if big else 8
@@ -354,6 +362,7 @@ class SubscriptionTable:
                 self._rebuild()
                 region = self._region_of_filter(fw)  # NB may have changed
         slot = self._free[region].pop()
+        self._count_region(region, 1)
         hh = bool(fw) and fw[-1] == HASH
         concrete = fw[:-1] if hh else fw
         intern = self.interner.intern
@@ -403,10 +412,26 @@ class SubscriptionTable:
         self.entries[slot] = None
         region = int(self._region_of_slot[slot])
         self._free[region].append(slot)
+        self._count_region(region, -1)
         self.dirty.add(slot)
         self.count -= 1
         self._count_row(fw, -1)
         return True
+
+    def _count_region(self, region: int, d: int) -> None:
+        """A live row came to (``d`` = 1) or left (-1) ``region``: by
+        REGION, not by address, so a region that moved keeps its count."""
+        if region == 0:
+            self._live0 += d
+        elif region <= self.NG:
+            self._liveg += d
+
+    @property
+    def live_rows(self) -> Tuple[int, int]:
+        """Live rows of region 0 (first two levels wild) and of the
+        g-buckets (wildcard-first, concrete level 1): what decides whether
+        a match program holds the dense phase and probe B."""
+        return self._live0, self._liveg
 
     def _count_row(self, fw: Tuple[str, ...], d: int) -> None:
         """One device row of filter ``fw`` came (``d`` = 1) or went (-1)."""

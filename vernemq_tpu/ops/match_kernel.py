@@ -524,31 +524,28 @@ def _gather_parts(tidx, tvalid, tcount, tile, pos):
     return idx, valid, cnt
 
 
-def _flat_combine(real, k, C, g, a, b):
-    """Flat compaction of the three per-pub result parts (each an
-    ``(idx, valid, cnt)`` triple): prefix-sum the clamped counts, scatter
-    every matched slot id into one [C] buffer. See
-    :func:`match_extract_windowed_flat` for the contract."""
-    (gidx, gvalid, gcount), (aidx, avalid, acnt), (bidx, bvalid, bcnt) = \
-        g, a, b
-    clip = (gcount > k) | (acnt > k) | (bcnt > k)
-    gcnt = jnp.minimum(jnp.where(real, gcount, 0), k)
-    acnt = jnp.minimum(jnp.where(real, acnt, 0), k)
-    bcnt = jnp.minimum(jnp.where(real, bcnt, 0), k)
-    total = gcnt + acnt + bcnt
+def _flat_combine(real, k, C, parts):
+    """Flat compaction of the per-pub result parts of the phases the
+    program holds, in phase order (each an ``(idx, valid, cnt)`` triple):
+    prefix-sum the clamped counts, scatter every matched slot id into one
+    [C] buffer. See :func:`match_extract_windowed_flat` for the
+    contract."""
+    clip = jnp.zeros(real.shape, bool)
+    cnts = []
+    for _idx, _valid, cnt in parts:
+        clip = clip | (cnt > k)
+        cnts.append(jnp.minimum(jnp.where(real, cnt, 0), k))
+    total = sum(cnts)
     pre = jnp.cumsum(total) - total               # exclusive prefix
     j = jnp.arange(k, dtype=jnp.int32)[None, :]
     flat = jnp.zeros((C,), jnp.int32)
-
-    def scat(flat, base, idx, valid, cnt):
+    base = pre
+    for (idx, valid, _cnt), cnt in zip(parts, cnts):
         # extraction guarantees rank j holds the j-th match (j < count)
         pos = base[:, None] + j
         p = jnp.where(valid & real[:, None] & (j < cnt[:, None]), pos, C)
-        return flat.at[p].set(idx, mode="drop")
-
-    flat = scat(flat, pre, gidx, gvalid, gcnt)
-    flat = scat(flat, pre + gcnt, aidx, avalid, acnt)
-    flat = scat(flat, pre + gcnt + acnt, bidx, bvalid, bcnt)
+        flat = flat.at[p].set(idx, mode="drop")
+        base = base + cnt
     overflow = ((pre + total > C) | clip) & real
     return (flat, pre.astype(jnp.int32), total.astype(jnp.int32), overflow)
 
@@ -587,7 +584,7 @@ def match_extract_windowed_flat(
     """The production match path — ONE fused executable per batch, with
     device-side FLAT COMPACTION.
 
-    Three match phases against the two-level bucket layout
+    Up to three match phases against the two-level bucket layout
     (models/tpu_table.py — the trie's first- and second-edge narrowing
     as dense windows; the per-publish ETS walk of
     ``vmq_reg_trie.erl:358-383`` recast as batched matmuls):
@@ -599,6 +596,18 @@ def match_extract_windowed_flat(
     3. PROBE B: publishes tiled by their level-1 word's g-bucket
        (wildcard-first filters with a concrete level 1); windows match
        only wildcard-first rows.
+
+    A phase costs its extractions — ``[slots, k, 64]`` gathers a dense
+    chunk or a window tile — whatever the rows behind its mask hold, so
+    a program holds a phase only if its rows can match at all: probe A
+    always, the dense phase unless ``gc`` is 0, probe B unless
+    ``seg2_max`` is 0. The caller decides from the live rows of region 0
+    and of the g-buckets in the table snapshot the device arrays were
+    built from (``TpuMatcher._geometry``): a table of concrete-first
+    filters runs probe A alone, and the first wildcard-first SUBSCRIBE
+    changes the statics — another program, compiled off the serving
+    path like any cold signature. ``glob_pad`` keeps its value either
+    way: probe A's row guard needs it.
 
     Design notes (measured on the TPU runtime): per-execution overhead
     is ~5ms regardless of op count, ``lax.map`` serialises tile
@@ -646,11 +655,14 @@ def _windowed_flat_core(F_t, t1, sub_eff_len, has_hash, first_wild, active,
     # else: a device trace attributes each operation's time to its phase
     B = pub_words.shape[0]
     real = jnp.arange(B, dtype=jnp.int32) < n_real
+    parts = []  # of the phases compiled in, in phase order
 
-    with jax.named_scope("dense_region0"):
-        g = _dense_region0(F_t, t1, sub_eff_len, has_hash, first_wild,
-                           active, pub_words, pub_len, pub_dollar,
-                           id_bits=id_bits, k=k, glob_pad=glob_pad, gc=gc)
+    if gc:
+        with jax.named_scope("dense_region0"):
+            parts.append(_dense_region0(
+                F_t, t1, sub_eff_len, has_hash, first_wild, active,
+                pub_words, pub_len, pub_dollar,
+                id_bits=id_bits, k=k, glob_pad=glob_pad, gc=gc))
 
     args = (F_t, t1, sub_eff_len, has_hash, first_wild, active,
             pub_words, pub_len, pub_dollar)
@@ -658,16 +670,14 @@ def _windowed_flat_core(F_t, t1, sub_eff_len, has_hash, first_wild, active,
         tidx, tvalid, tcount = _window_tiles_sel(
             *args, t_sel, t_start, id_bits=id_bits, k=k,
             seg_max=seg_max, glob_pad=glob_pad, wild_rows=False)
-        a = _gather_parts(tidx, tvalid, tcount, a_tile, a_pos)
+        parts.append(_gather_parts(tidx, tvalid, tcount, a_tile, a_pos))
     if seg2_max:
         with jax.named_scope("probe_b"):
             t2idx, t2valid, t2count = _window_tiles_sel(
                 *args, t2_sel, t2_start, id_bits=id_bits, k=k,
                 seg_max=seg2_max, glob_pad=glob_pad, wild_rows=True)
-            b = _gather_parts(t2idx, t2valid, t2count, b_tile, b_pos)
-    else:
-        b = (jnp.zeros((B, k), jnp.int32), jnp.zeros((B, k), bool),
-             jnp.zeros((B,), jnp.int32))
+            parts.append(
+                _gather_parts(t2idx, t2valid, t2count, b_tile, b_pos))
 
     # flat compaction: pad pubs contribute nothing; each real pub owns
     # the contiguous range [pre, pre+total). Budget with counts CLAMPED
@@ -677,7 +687,7 @@ def _windowed_flat_core(F_t, t1, sub_eff_len, has_hash, first_wild, active,
     # entire raw fanout and cascade spurious capacity overflows (= slow
     # exact host scans) across the rest of the batch.
     with jax.named_scope("flat_combine"):
-        return _flat_combine(real, k, C, g, a, b)
+        return _flat_combine(real, k, C, parts)
 
 
 @jax.jit

@@ -201,10 +201,12 @@ def match_extract_windowed_flat_pallas(
     B = pub_words.shape[0]
     real = jnp.arange(B, dtype=jnp.int32) < n_real
 
-    g = K._dense_region0(
-        F_t, t1, sub_eff_len, has_hash, first_wild, active,
-        pub_words, pub_len, pub_dollar, id_bits=id_bits, k=k,
-        glob_pad=glob_pad, gc=gc)
+    parts = []  # of the phases compiled in (``gc`` / ``seg2_max`` 0: out)
+    if gc:
+        parts.append(K._dense_region0(
+            F_t, t1, sub_eff_len, has_hash, first_wild, active,
+            pub_words, pub_len, pub_dollar, id_bits=id_bits, k=k,
+            glob_pad=glob_pad, gc=gc))
 
     flags = (has_hash.astype(jnp.int32)
              | (first_wild.astype(jnp.int32) << 1)
@@ -213,14 +215,12 @@ def match_extract_windowed_flat_pallas(
         F_t, t1, sub_eff_len, flags, pub_words, pub_len, pub_dollar,
         t_sel, t_start, id_bits=id_bits, k=k, seg_max=seg_max,
         glob_pad=glob_pad, wild_rows=False, interpret=interpret)
-    a = K._gather_parts(tidx, tvalid, tcount, a_tile, a_pos)
+    parts.append(K._gather_parts(tidx, tvalid, tcount, a_tile, a_pos))
     if seg2_max:
         t2idx, t2valid, t2count = _probe_pallas(
             F_t, t1, sub_eff_len, flags, pub_words, pub_len, pub_dollar,
             t2_sel, t2_start, id_bits=id_bits, k=k, seg_max=seg2_max,
             glob_pad=glob_pad, wild_rows=True, interpret=interpret)
-        b = K._gather_parts(t2idx, t2valid, t2count, b_tile, b_pos)
-    else:
-        b = (jnp.zeros((B, k), jnp.int32), jnp.zeros((B, k), bool),
-             jnp.zeros((B,), jnp.int32))
-    return K._flat_combine(real, k, C, g, a, b)
+        parts.append(
+            K._gather_parts(t2idx, t2valid, t2count, b_tile, b_pos))
+    return K._flat_combine(real, k, C, parts)
