@@ -17,9 +17,12 @@ import asyncio
 import sys
 import time
 
+from .manifest import ROOT
+
 
 def run(workload: str, seed: int, seconds: float, break_=None, every=97,
-        rehearse: bool = False, warm_s: float = 1.0) -> dict:
+        rehearse: bool = False, warm_s: float = 1.0, root: str = ROOT
+        ) -> dict:
     from . import corpus as corpus_mod
     from . import harness
     from .generator import Generator
@@ -27,7 +30,7 @@ def run(workload: str, seed: int, seconds: float, break_=None, every=97,
     from .run import rehearsal_sizes
     from .systems import ReferenceSystem
 
-    manifest = Manifest()
+    manifest = Manifest(root)
     cell = manifest.cell(workload)
     if rehearse:
         rehearsal_sizes(cell)
@@ -50,6 +53,7 @@ def run(workload: str, seed: int, seconds: float, break_=None, every=97,
 def main(argv=None) -> int:
     from . import harness
     from .refbroker import BREAKS
+    from .run import add_root
 
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -58,9 +62,10 @@ def main(argv=None) -> int:
     ap.add_argument("--break", dest="break_", choices=BREAKS, default=None)
     ap.add_argument("--every", type=int, default=97)
     ap.add_argument("--rehearse", action="store_true")
+    add_root(ap)
     a = ap.parse_args(argv)
     result = run(a.workload, a.seed, a.seconds, a.break_, a.every,
-                 a.rehearse)
+                 a.rehearse, root=a.root)
     harness.print_result(result)
     return 0
 

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import importlib
 from dataclasses import dataclass, field
-from typing import Callable, Dict, Iterator, List, Tuple
+from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
@@ -40,7 +40,9 @@ class LiveSession:
     stored: List[Tuple[Words, int]] = field(default_factory=list)
 
     def subscriptions(self) -> Dict[Words, int]:
-        """(client, filter) is a key: a later QoS replaces an earlier."""
+        """(client, filter) is a key: a later QoS replaces an earlier. A
+        membership of a shared subscription keeps its words as subscribed,
+        ``("$share", group, ...)``: the reference tells the two apart."""
         out: Dict[Words, int] = {}
         for words, qos in self.stored:
             out[words] = qos
@@ -63,6 +65,9 @@ class Corpus:
     #: ``pools`` of that publisher's publishes ``start .. start + n``; a
     #: sender and a checker that ask for different ranges see the same
     topics: Callable[[int, int, int], np.ndarray]
+    #: publisher connections of a mix that opens ``one_per_live_session``;
+    #: None: as many as ``live`` (point to point, a publisher a subscriber)
+    publishers: Optional[int] = None
 
 
 def build(config: dict, seed: int) -> Corpus:

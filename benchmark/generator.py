@@ -8,6 +8,7 @@ thread, because the broker runs on the parent's event loop.
 from __future__ import annotations
 
 import multiprocessing as mp
+import time
 from typing import Any, Dict, List
 
 from . import loadgen
@@ -70,7 +71,23 @@ class Generator:
         return self._all("pub", "start", start)
 
     def finish(self, fin: dict) -> List[dict]:
-        return self._all("sub", "finish", fin)
+        """Wait for each delivery owed, then compare. A delivery owed to a
+        shared subscription is read by whichever process holds the member
+        the broker chose, so no process knows when it has read its own:
+        the wait is for the sum, here, while the processes go on reading.
+        It ends when as many frames were read as are owed, after
+        ``drain_max_s``, or when no socket read any for ``drain_quiet_s``."""
+        owed = self._all("sub", "owed", fin)
+        want = sum(o["owed"] for o in owed) + owed[0]["owed_shared"]
+        t0 = time.monotonic()
+        while True:
+            got = self._all("sub", "progress", None)
+            if (sum(g["received"] for g in got) >= want
+                    or time.monotonic() - t0 > fin["drain_max_s"]
+                    or min(g["quiet_s"] for g in got) > fin["drain_quiet_s"]):
+                break
+            time.sleep(0.05)
+        return self._all("sub", "finish", None)
 
     def close(self) -> None:
         for _r, p, c, _s in self.shards:

@@ -103,6 +103,11 @@ class SubscriberShard:
         self.config = spec["config"]
         self.corpus = build(self.config, spec["seed"])
         self.sessions = self.corpus.live[spec["shard"]::spec["shards"]]
+        # shared subscriptions are owed to no process: all of them, and
+        # where this process's sessions stand among the live ones
+        self.shared = reference.Shared(self.corpus.live)
+        self.live_index = np.arange(spec["shard"], len(self.corpus.live),
+                                    spec["shards"], dtype=np.int64)
         self.sel = selectors.DefaultSelector()
         self.socks: List[socket.socket] = []
         self.rest: List[bytes] = []
@@ -189,12 +194,15 @@ class SubscriberShard:
 
     # ---- the comparison
 
-    def finish(self, fin: dict) -> dict:
+    def owed(self, fin: dict) -> dict:
+        """What the reference says is owed, computed once the schedule is
+        known: how many deliveries to this process's own sessions, and how
+        many to shared subscriptions — whichever process's sockets read
+        those. The parent waits for the sum over all processes
+        (``Generator.finish``) while this one goes on reading."""
         corpus = self.corpus
         sizes = [len(pool) for pool in corpus.pools]
         n_sent = fin["n_sent"]
-        pub_qos = fin["pub_qos"]
-        w0, w1 = fin["window_ns"]
         n_pub = max(n_sent, default=-1) + 1
         sent_of = np.zeros(n_pub + 1, np.int64)   # the last row: unknown
         base_of = np.zeros(n_pub + 1, np.int64)
@@ -210,19 +218,23 @@ class SubscriberShard:
                   else np.zeros((0, len(sizes)), np.int32))
         pub_of = np.concatenate(pub_of) if pub_of else np.zeros(0, np.int64)
         seq_of = np.concatenate(seq_of) if seq_of else np.zeros(0, np.int64)
-        trie = reference.session_trie(self.sessions)
+        trie = reference.session_trie(self.sessions, self.shared)
         exp = reference.expected_keys(trie, corpus.pools, sizes, levels,
-                                      pub_of, seq_of, pub_qos)
-        # wait for each delivery owed: late is late, not wrong; what is
-        # still absent when the wait ends is lost
-        t0 = time.monotonic()
-        self.last_frame = max(self.last_frame, t0)
-        while self.received < len(exp):
-            now = time.monotonic()
-            if now - t0 > fin["drain_max_s"] or (
-                    now - self.last_frame > fin["drain_quiet_s"]):
-                break
-            self.pump(0.05)
+                                      pub_of, seq_of, fin["pub_qos"])
+        self.schedule = (fin, sizes, sent_of, base_of, levels, exp)
+        n_shared = int(np.count_nonzero(self.shared.is_shared(exp)))
+        self.last_frame = max(self.last_frame, time.monotonic())
+        return {"owed": len(exp) - n_shared, "owed_shared": n_shared}
+
+    def progress(self) -> dict:
+        return {"received": self.received,
+                "quiet_s": time.monotonic() - self.last_frame}
+
+    def finish(self) -> dict:
+        """After the wait: late was late, what is still absent is lost."""
+        fin, sizes, sent_of, base_of, levels, exp = self.schedule
+        n_sent, n_pub = fin["n_sent"], len(sent_of) - 1
+        w0, w1 = fin["window_ns"]
         t_verdict = time.monotonic_ns()
         rec = np.frombuffer(bytes(self.stamps), RECORD)
         sub = np.repeat(np.asarray(self.chunk_sub, np.int64),
@@ -238,7 +250,10 @@ class SubscriberShard:
         again = ours & (qos > 0) & ((rec["b0"] & DUP) != 0)
         rkeys = reference.key(sub, pub, np.minimum(qos, 1), seq)
         rkeys = np.where(ours, rkeys, -1)  # a frame without our stamp
-        cmp = reference.compare(exp, rkeys[~again])
+        exp, got, share_exp, share_got, by_member = reference.attribute(
+            self.shared, exp, rkeys[~again], self.live_index,
+            fin["pub_qos"])
+        cmp = reference.compare(exp, got)
         # ordering, over deliveries of publishes the schedule knows
         pub_c = np.minimum(pub, n_pub)
         known = ours & ~again & (seq < sent_of[pub_c])
@@ -256,6 +271,8 @@ class SubscriberShard:
         lat_ms = ((t_rx[rec_in_w] - t_pub[rec_in_w]) / 1e6).astype(np.float32)
         exp_pub, exp_seq = reference.pub_seq(exp)
         exp_row = base_of[exp_pub] + exp_seq
+        share_pub, share_seq = reference.pub_seq(share_exp)
+        share_row = base_of[share_pub] + share_seq
         # further windows of one run (a sweep's steps): latencies and
         # counts only
         steps = []
@@ -266,7 +283,10 @@ class SubscriberShard:
                            ).astype(np.float32),
                 "due_s": ((t_pub[inside] - a) / 1e9).astype(np.float32),
                 "owed": int(np.count_nonzero(
-                    (st_all[exp_row] >= a) & (st_all[exp_row] < b)))})
+                    (st_all[exp_row] >= a) & (st_all[exp_row] < b))),
+                # the same in every process: the parent counts it once
+                "owed_shared": int(np.count_nonzero(
+                    (st_all[share_row] >= a) & (st_all[share_row] < b)))})
 
         def pubseq_in_window(keys: np.ndarray) -> np.ndarray:
             """(publisher, sequence) of ``keys`` whose publish lies in the
@@ -292,6 +312,13 @@ class SubscriberShard:
             "closed": self.closed[:8], "n_closed": len(self.closed),
             "examples": {k: [int(x) for x in cmp[k][:4]] for k in
                          ("short_keys", "over_keys", "stray_keys")},
+            # for the parent, which sees every process's: what shared
+            # subscriptions are owed (the same in each process) and what
+            # this one's sockets read for them
+            "share_owed": share_exp, "share_received": share_got,
+            "share_by_member": by_member,
+            "shares": [[g, "/".join(f), len(self.shared.members(i))]
+                       for i, (g, f) in enumerate(self.shared.subs)],
         }
 
     def close(self) -> None:
@@ -472,9 +499,13 @@ class PublisherShard:
 
 def connections(mix: dict, corpus) -> int:
     """Publisher connections of a mix: a number, or one for each live
-    session of the corpus (point to point)."""
+    session of the corpus (point to point) — for a corpus whose publishers
+    are not its subscribers' pairs, as many as it says."""
     n = mix["connections"]
-    return len(corpus.live) if n == "one_per_live_session" else int(n)
+    if n != "one_per_live_session":
+        return int(n)
+    return len(corpus.live) if corpus.publishers is None \
+        else corpus.publishers
 
 
 # ------------------------------------------------------------- the shard
@@ -499,8 +530,12 @@ def main(conn, role: str, spec: dict) -> None:
                 conn.send((True, shard.connect()))
             elif cmd == "start":
                 conn.send((True, shard.run(arg)))
+            elif cmd == "owed":
+                conn.send((True, shard.owed(arg)))
+            elif cmd == "progress":
+                conn.send((True, shard.progress()))
             elif cmd == "finish":
-                conn.send((True, shard.finish(arg)))
+                conn.send((True, shard.finish()))
             elif cmd == "exit":
                 break
     except BaseException:  # reported, then the process ends

@@ -14,19 +14,34 @@ publishes of each connection and none after (acknowledged all the same);
 session no filter of which matches; ``reorder`` holds a publish back and
 serves it after the same connection's next; ``no_ack`` withholds a
 PUBACK.
+
+A subscription ``$share/<group>/<filter>`` makes its session a member of
+the shared subscription (``reference``'s rule): a matching publish goes
+to ONE of its members that hold a connection, drawn by a seeded
+generator, at that member's QoS. Two breaks are the group's own:
+``share_twice`` hands a group's publish to two of its members;
+``share_dead`` hands it to nobody while a member stands connected. Under
+``reorder`` the held publish goes to the member that took the one served
+before it: across members a group has no order to break.
 """
 
 from __future__ import annotations
 
 import asyncio
+import random
 import struct
 from typing import Dict, Optional, Tuple
 
 from . import mqtt
-from .reference import FilterTrie
+from .reference import FilterTrie, split_share
 
 BREAKS = ("lose_qos1", "lose_tail", "duplicate", "stray", "reorder",
-          "no_ack")
+          "no_ack", "share_twice", "share_dead")
+SHARE = object()  # in the trie: a shared subscription, not a client's
+#: how long ``reorder`` holds a publish for the connection's next: past a
+#: tick of either mix, inside the control's wait for PUBACKs (5 s), so the
+#: last tick's held publishes are served late and acknowledged, not never
+HOLD_S = 2.0
 
 
 class _Session(asyncio.Protocol):
@@ -38,6 +53,7 @@ class _Session(asyncio.Protocol):
         self.pid = 0
         self.published = 0
         self.held = None       # a publish held back (``reorder``)
+        self.unhold = None     # the timer that serves it if no next comes
 
     def connection_made(self, transport) -> None:
         self.transport = transport
@@ -58,11 +74,13 @@ class _Session(asyncio.Protocol):
             if kind == mqtt.PUBLISH:
                 if self.held is not None:
                     held, self.held = self.held, None
+                    self.unhold.cancel()
                     b.publish(self, b0, body)
-                    b.publish(self, *held)
+                    b.publish(self, *held, same_member=True)
                 elif b.broken("reorder"):
                     self.held = (b0, body)
-                    asyncio.get_running_loop().call_later(5.0, self._unhold)
+                    self.unhold = asyncio.get_running_loop().call_later(
+                        HOLD_S, self._unhold)
                 else:
                     b.publish(self, b0, body)
             elif kind == 1:
@@ -90,20 +108,19 @@ class _Session(asyncio.Protocol):
         stored = self.client_id in b.subs
         if flags & 0x02:
             b.subs[self.client_id] = {}
+            for members in b.shares.values():
+                members.pop(self.client_id, None)
             stored = False
         b.online[self.client_id] = self
         self.transport.write(bytes([0x20, 2, int(stored), 0]))
 
     def _subscribe(self, body: bytes) -> None:
         pid, at, granted = body[:2], 2, bytearray()
-        mine = self.broker.subs.setdefault(self.client_id, {})
         while at < len(body):
             n = struct.unpack_from(">H", body, at)[0]
             words = tuple(body[at + 2:at + 2 + n].decode().split("/"))
             qos = body[at + 2 + n]
-            if words not in mine:
-                self.broker.trie.add(words, (self.client_id, words))
-            mine[words] = qos
+            self.broker.store(self.client_id, words, qos)
             granted.append(qos)
             at += 3 + n
         self.transport.write(bytes([0x90, 2 + len(granted)]) + pid
@@ -119,6 +136,10 @@ class ReferenceBroker:
         self._n: Dict[str, int] = {}
         self.trie = FilterTrie()
         self.subs: Dict[str, Dict[Tuple[str, ...], int]] = {}
+        #: (group, filter) -> member client id -> QoS
+        self.shares: Dict[tuple, Dict[str, int]] = {}
+        self.pick = random.Random(0x5A4E)
+        self._last: Dict[tuple, str] = {}  # the member each group served
         self.online: Dict[str, _Session] = {}
         self.server = None
         self.publishes = 0
@@ -131,11 +152,37 @@ class ReferenceBroker:
         return n % self.every == 0
 
     def store(self, client_id: str, words, qos: int) -> None:
-        """A row of the persisted subscriber DB."""
+        """A subscription: a SUBSCRIBE's, or a row of the persisted
+        subscriber DB. A shared one is ONE value in the trie under the
+        group's filter, whoever its members are."""
+        words = tuple(words)
+        share = split_share(words)
+        if share is not None:
+            if share not in self.shares:
+                self.trie.add(share[1], (SHARE, share))
+            self.shares.setdefault(share, {})[client_id] = qos
+            return
         mine = self.subs.setdefault(client_id, {})
         if words not in mine:
             self.trie.add(words, (client_id, words))
         mine[words] = qos
+
+    def _members(self, share: tuple, same_member: bool) -> list:
+        """The member(s) a shared subscription's publish goes to: one
+        that holds a connection (``share_twice``: two; ``share_dead``:
+        none)."""
+        members = self.shares[share]
+        online = [c for c in members if c in self.online]
+        if not online or self.broken("share_dead"):
+            return []
+        last = self._last.get(share)
+        first = last if same_member and last in online \
+            else self.pick.choice(online)
+        self._last[share] = first
+        out = [first]
+        if self.broken("share_twice", len(online) > 1):
+            out.append(self.pick.choice([c for c in online if c != first]))
+        return [(c, members[c]) for c in out]
 
     async def start(self, host: str = "127.0.0.1") -> int:
         loop = asyncio.get_running_loop()
@@ -149,7 +196,8 @@ class ReferenceBroker:
         self.server.close()
         await self.server.wait_closed()
 
-    def publish(self, src: _Session, b0: int, body: bytes) -> None:
+    def publish(self, src: _Session, b0: int, body: bytes,
+                same_member: bool = False) -> None:
         qos = (b0 >> 1) & 3
         n = struct.unpack_from(">H", body, 0)[0]
         topic_b = body[2:2 + n]
@@ -164,11 +212,18 @@ class ReferenceBroker:
         if self.break_ == "lose_tail" and src.published > self.every:
             return
         words = topic_b.decode().split("/")
-        rows = [(cid, self.subs.get(cid, {}).get(f))
-                for cid, f in self.trie.match(words)]
+        rows, shares = [], []
+        for cid, f in self.trie.match(words):
+            if cid is SHARE:
+                rows += self._members(f, same_member)
+                shares.append(f)
+            else:
+                rows.append((cid, self.subs.get(cid, {}).get(f)))
         if self.broken("stray"):
+            # a session that subscribed something, nothing of it matching
             other = next((c for c in self.online
-                          if c != src.client_id
+                          if c != src.client_id and self.subs.get(c)
+                          and all(c not in self.shares[f] for f in shares)
                           and all(c != cid for cid, _ in rows)), None)
             if other is not None:
                 rows.append((other, 0))

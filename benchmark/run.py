@@ -29,7 +29,18 @@ def parse(argv):
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearse", action="store_true")
+    add_root(ap)
     return ap.parse_args(argv)
+
+
+def add_root(ap: argparse.ArgumentParser) -> None:
+    """The one optional argument of every entry point: the directory whose
+    ``BENCHMARK.json`` names the cell (its configurations' files lie under
+    it). The package's tests and a builder's trial of a deployment that is
+    no cell yet pass their own; a run of the benchmark passes none."""
+    from .manifest import ROOT
+
+    ap.add_argument("--root", default=ROOT, help=add_root.__doc__)
 
 
 def rehearsal_sizes(cell: dict) -> None:
@@ -103,7 +114,7 @@ def main(argv=None, system_factory=None) -> int:
     from .generator import Generator
     from .manifest import Manifest
 
-    manifest = Manifest()
+    manifest = Manifest(args.root)
     cell = manifest.cell(args.workload)
     if args.rehearse:
         rehearsal_sizes(cell)

@@ -60,7 +60,8 @@ async def sweep(system, gen, cell, corpus, actives, step_s: float,
         lat = np.concatenate([r["steps"][i]["lat_ms"] for r in subs])
         due = np.concatenate([r["steps"][i]["due_s"] for r in subs])
         row.update(offered_pubs_per_s=row["active"] * per_s,
-                   owed=sum(r["steps"][i]["owed"] for r in subs),
+                   owed=sum(r["steps"][i]["owed"] for r in subs)
+                   + subs[0]["steps"][i]["owed_shared"],
                    received=int(len(lat)))
         if len(lat):
             first, last = lat[due < step_s / 3], lat[due >= 2 * step_s / 3]
@@ -75,6 +76,8 @@ async def sweep(system, gen, cell, corpus, actives, step_s: float,
 
 
 def main(argv=None) -> int:
+    from .run import add_root, boot_jax, rehearsal_sizes
+
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
@@ -83,17 +86,19 @@ def main(argv=None) -> int:
     ap.add_argument("--step-seconds", type=float, default=10.0)
     ap.add_argument("--pause-seconds", type=float, default=3.0)
     ap.add_argument("--rehearse", action="store_true")
+    add_root(ap)
     a = ap.parse_args(argv)
     from . import corpus as corpus_mod
     from . import harness
     from .generator import Generator
     from .manifest import Manifest
-    from .run import boot_jax, rehearsal_sizes
 
-    cell = Manifest().cell(a.workload)
+    cell = Manifest(a.root).cell(a.workload)
     if a.rehearse:
         rehearsal_sizes(cell)
-    cell["config"]["live_pairs"] = a.connect
+    for key in ("live_pairs", "live_publishers"):  # whichever it sizes by
+        if key in cell["config"]:
+            cell["config"][key] = a.connect
     gen = Generator(cell["config"], cell["mix"], a.seed)
     try:
         gen.spawn()

@@ -84,7 +84,7 @@ def test_every_declared_reader_reads_a_traced_runs_context():
         "match_publishes", "match_batches", "super_dispatches",
         "host_hybrid_pubs", "busy_host_pubs", "degraded_host_pubs",
         "stalled_host_pubs", "expired_host_pubs", "rebuild_host_pubs",
-        "overload_host_pubs")}
+        "overload_host_pubs", "phase_runs", "phase_dispatches")}
     for fam in ("wire_parse", "collector_wait", "device_dispatch",
                 "queue_flush", "wire_encode"):
         counters[f"stage_{fam}_ms.sum"] = 5.0

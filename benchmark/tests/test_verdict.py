@@ -22,7 +22,10 @@ def reports(lost=(), acked=100, served=95, host=5, system="vernemq_tpu"):
            "lost_qos1": len(lost), "lost_qos0": 0, "duplicates": 0,
            "strays": 0, "misordered": 0, "n_closed": 0,
            "lat_ms": np.ones(5, np.float32), "steps": [],
-           "verdict_ns": 10**9, "examples": {}}
+           "verdict_ns": 10**9, "examples": {},
+           "share_owed": np.zeros(0, np.int64),
+           "share_received": np.zeros(0, np.int64),
+           "share_by_member": np.zeros((0, 3), np.int64), "shares": []}
     fin = harness._finish_request([pub], {"qos": 1}, (0, 10**6))
     delta = {"match_publishes": served, "host_hybrid_pubs": host}
     return harness._reduce([pub], [sub], fin, delta, {}, 1.0, system)
