@@ -129,6 +129,12 @@ async def connected(node: Node, client_id, **kw):
 # ------------------------------------------------------------------- tests
 
 
+def members(node, group, filter_words):
+    """The members node's registry holds for ``$share/<group>/<filter>``."""
+    g = node.broker.registry.share_group("", group, filter_words)
+    return {} if g is None else g.members
+
+
 @pytest.mark.asyncio
 async def test_join_forms_full_mesh():
     nodes = await make_cluster(3)
@@ -218,8 +224,8 @@ async def test_shared_subscription_cross_node():
         remote = await connected(b, "m-remote")
         await local.subscribe("$share/grp/work/#", qos=0)
         await remote.subscribe("$share/grp/work/#", qos=0)
-        await wait_until(
-            lambda: len(a.broker.registry.trie("").match(["work", "1"])) == 2)
+        # ONE row for the group, whatever its members: wait for both
+        await wait_until(lambda: len(members(a, "grp", ["work", "#"])) == 2)
         pub = await connected(a, "sp")
         # prefer_local: the member on the publisher's node gets every message
         for i in range(5):
@@ -231,8 +237,7 @@ async def test_shared_subscription_cross_node():
             await remote.recv(timeout=0.3)
         # local member leaves -> remote member takes over via remote enqueue
         await local.disconnect()
-        await wait_until(
-            lambda: len(a.broker.registry.trie("").match(["work", "1"])) == 1)
+        await wait_until(lambda: len(members(a, "grp", ["work", "#"])) == 1)
         await pub.publish("work/2", b"failover", qos=0)
         msg = await remote.recv()
         assert msg.payload == b"failover"
@@ -849,8 +854,10 @@ async def test_shared_subscription_cross_node_tpu_view():
         await local.subscribe("$share/g2/jobs/#", qos=0)
         await remote.subscribe("$share/g2/jobs/#", qos=0)
         view = a.broker.registry.reg_view("tpu")
-        await wait_until(
-            lambda: len(view.fold("", ["jobs", "1"])) == 2)
+        await wait_until(lambda: len(members(a, "g2", ["jobs", "#"])) == 2)
+        # the device table holds the group as ONE row
+        assert [k for _f, k, _o in view.fold("", ["jobs", "1"])] \
+            == [("$g", "g2", None)]
         pub = await connected(a, "s-pub")
         for i in range(4):
             await pub.publish("jobs/1", b"t%d" % i, qos=0)
@@ -859,7 +866,7 @@ async def test_shared_subscription_cross_node_tpu_view():
         with pytest.raises(asyncio.TimeoutError):
             await remote.recv(timeout=0.3)
         await local.disconnect()
-        await wait_until(lambda: len(view.fold("", ["jobs", "1"])) == 1)
+        await wait_until(lambda: len(members(a, "g2", ["jobs", "#"])) == 1)
         await pub.publish("jobs/2", b"fo", qos=0)
         assert (await remote.recv()).payload == b"fo"
         await remote.disconnect()

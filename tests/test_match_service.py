@@ -242,30 +242,27 @@ def _opts(node):
 
 def test_owned_delta_filtering():
     # plain local rows forward
-    assert owned_delta("w0", ("", "c1"), _opts("w0"))
+    assert owned_delta(("", "c1"))
     # node-pointer rows never forward (string key)
-    assert not owned_delta("w0", "w1", None)
-    # shared adds forward only from the owner
-    g = ("$g", "grp", ("", "c2"))
-    assert owned_delta("w0", g, _opts("w0"))
-    assert not owned_delta("w0", g, _opts("w1"))
-    # shared removes (no opts) forward from everyone (idempotent apply)
-    assert owned_delta("w0", g, None)
+    assert not owned_delta("w1")
+    # a shared subscription's one row (no owner) forwards from every
+    # worker, add and remove alike (idempotent apply)
+    assert owned_delta(("$g", "grp", None))
 
 
 def test_localize_rows_shapes():
     own = _opts("w0")
     foreign = _opts("w1")
-    shared = _opts("w1")
     rows = [
         (("a", "b"), ("", "c-own"), own),
         (("a", "#"), ("", "c-far"), foreign),
-        (("a", "+"), ("$g", "g1", ("", "c-sh")), shared),
+        (("a", "+"), ("$g", "g1", None), None),
     ]
     out = localize_rows(rows, "w0")
     assert out[0] == (("a", "b"), ("", "c-own"), own)  # own: direct
     assert out[1] == (("a", "#"), "w1", None)  # foreign: node pointer
-    assert out[2] == rows[2]  # shared: pass through (policy uses node)
+    # shared: pass through (the worker's registry knows the members)
+    assert out[2] == rows[2]
 
 
 # ------------------------------------------------- service core + client
@@ -398,9 +395,9 @@ def test_reconnect_handoff_transfers_ownership(env):
     # the CURRENT owner's unsub still deletes it
     svc.apply_unsub("", ("h", "t"), key, from_node="w1")
     assert svc.trie("").match(["h", "t"]) == []
-    # shared rows stay exempt: any ring may remove them
-    g = ("$g", "grp", ("", "bounce"))
-    svc.apply_sub("", ("h", "s"), g, _opts("w1"))
+    # a shared subscription's row has no owner: any ring may remove it
+    g = ("$g", "grp", None)
+    svc.apply_sub("", ("h", "s"), g, None)
     svc.apply_unsub("", ("h", "s"), g, from_node="w0")
     assert svc.trie("").match(["h", "s"]) == []
 

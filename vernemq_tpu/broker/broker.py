@@ -127,6 +127,7 @@ class Broker:
                     and self.registry.batched_view_active()),
             )
             self.filter_engine.emit = self._deliver_aggregate
+            self.filter_engine.share_rows = self.registry.share_member_rows
         # mesh slice map (cluster/mesh_map.py): slice→node ownership in
         # the replicated metadata plane, gossiped like the netsplit
         # CAPs. Created whenever a tpu_mesh is configured — single-node
@@ -465,6 +466,20 @@ class Broker:
                                    "arena.",
             "wire_breaker_state": "Wire-codec breaker state (0 closed, "
                                   "1 half-open, 2 open).",
+            # shared subscriptions (broker/shared.py): one member drawn
+            # from a group's own classes a publish
+            "share_picks": "Deliveries made to a member drawn from a "
+                           "shared subscription (one a publish and "
+                           "group).",
+            "share_wire_picks": "Of those, the ones the wire plane's "
+                                "fanout wrote (a lone online session "
+                                "with fast options).",
+            "share_stale_picks": "Draws whose member's queue was online "
+                                 "no more: the group's online list was "
+                                 "repaired and the draw made again.",
+            "share_offline_picks": "Deliveries to an offline member's "
+                                   "queue because no member of the "
+                                   "group was online.",
             # cluster delivery spool (cluster/spool.py): depth +
             # outstanding-ack gauges, published to $SYS/Prometheus
             "cluster_spool_depth_frames": "QoS>=1 cluster frames "

@@ -80,36 +80,29 @@ def _dec(data: bytes) -> Any:
     return pickle.loads(data)
 
 
-def owned_delta(node: str, key: Any, opts: Any) -> bool:
+def owned_delta(key: Any) -> bool:
     """Should a worker forward this registry delta to the service?
 
     Node-pointer rows never forward (the service derives pointers per
     querying worker from ``opts.node``). Plain-sid rows only ever fire
     locally (``reg._trie_add/_trie_remove`` emit them when node ==
-    self), so they forward. Shared-group adds are emitted by EVERY
-    worker for every replicated record — only the owner forwards;
-    removes carry no opts, so they forward from everyone and the
-    service applies them idempotently."""
-    if isinstance(key, str):
-        return False
-    if isinstance(key, tuple) and len(key) == 3 and key[0] == "$g":
-        if opts is None:
-            return True
-        return getattr(opts, "node", node) == node
-    return True
+    self), so they forward. A shared subscription's one row
+    (``("$g", group, None)``, no opts, no owner) is added by every
+    worker's registry when the group's first member joins and removed
+    when its last leaves: every worker forwards both and the service
+    applies them idempotently."""
+    return not isinstance(key, str)
 
 
 def localize_rows(rows: Iterable[Tuple], node: str) -> List[Tuple]:
     """Translate service (node-qualified) rows into the shape THIS
     worker's own trie fold would return: own plain rows stay direct,
     foreign plain rows become node-pointer rows (route_rows dedups the
-    forwards per node), shared rows pass through (their opts.node
-    already drives the shared-sub policy)."""
+    forwards per node), shared rows pass through (they have no owner:
+    the worker's own registry holds each group's members with their
+    nodes, which drive the shared-sub policy)."""
     out: List[Tuple] = []
     for fw, key, opts in rows:
-        if isinstance(key, tuple) and len(key) == 3 and key[0] == "$g":
-            out.append((fw, key, opts))
-            continue
         owner = getattr(opts, "node", None) if opts is not None else None
         if owner is None or owner == node:
             out.append((fw, key, opts))
@@ -216,15 +209,13 @@ class MatchService:
                     from_node: Optional[str] = None) -> None:
         fw = tuple(fw)
         k = (mp, fw, key)
-        if from_node is not None and not (
-                isinstance(key, tuple) and len(key) == 3
-                and key[0] == "$g"):
+        if from_node is not None:
             # plain rows only ever fire from their owner's worker: an
             # unsub from any OTHER ring is a previous owner's racing
             # remove after a reconnect moved the client — the new
-            # owner's re-add must survive it. Shared ($g) removes are
-            # deliberately exempt: every worker forwards them for every
-            # replicated record and the pop below dedups.
+            # owner's re-add must survive it. A shared subscription's
+            # row has no owner (no opts): every worker forwards its
+            # removal and the pop below dedups.
             cur = self._subs.get(k, _MISSING)
             if cur is not _MISSING and \
                     getattr(cur, "node", from_node) != from_node:
@@ -749,8 +740,9 @@ class MatchServiceClient:
         rows: Deque[Tuple] = deque()
         for mp in list(getattr(registry, "_tries", {})):
             for fw, key, opts in registry.fold_subscriptions(mp):
-                if owned_delta(self.node_name, key, opts) \
-                        and not isinstance(key, str) and opts is not None:
+                # a shared subscription's row carries no opts and is
+                # replayed by every worker (owned_delta)
+                if owned_delta(key):
                     rows.append((mp, tuple(fw), key, opts))
         self._op_backlog.appendleft(_enc(("resync", self.node_name)))
         self._resync_rows = rows
@@ -918,7 +910,7 @@ class ShmMatchView:
 
     def on_delta(self, op: str, mountpoint: str, filter_words, key,
                  opts) -> None:
-        if not owned_delta(self.client.node_name, key, opts):
+        if not owned_delta(key):
             return
         if op == "add":
             self.client.send_op(("sub", mountpoint, tuple(filter_words),

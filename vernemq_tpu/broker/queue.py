@@ -112,7 +112,7 @@ class SubscriberQueue:
         init_offline_queue)."""
         was_offline = self.state == OFFLINE
         self.sessions[session] = deliver
-        self.state = ONLINE
+        self._set_state(ONLINE)
         self._cancel_expiry()
         if was_offline:
             self.broker.hooks_fire_all("on_client_wakeup", self.subscriber_id)
@@ -153,7 +153,7 @@ class SubscriberQueue:
         if self.opts.clean_session:
             self.terminate("normal")
         else:
-            self.state = OFFLINE
+            self._set_state(OFFLINE)
             # park the backpressure backlog offline (insert_from_session,
             # vmq_queue.erl:867-881: undelivered messages survive the session)
             backlog, self.backlog = self.backlog, deque()
@@ -175,7 +175,7 @@ class SubscriberQueue:
         inserts, vmq_queue.erl:383-390) and picked up by
         :meth:`drain_pending` — never dropped."""
         prev_state = self.state
-        self.state = DRAIN
+        self._set_state(DRAIN)
         self._cancel_expiry()
         if self._resuming:
             # supersede an in-flight batched resume: the drain needs
@@ -223,7 +223,7 @@ class SubscriberQueue:
             self.offline.extend(parked)
         self.offline_in_store = True
         self.broker.metrics.incr("msg_store_read_errors")
-        self.state = prev_state
+        self._set_state(prev_state)
         if prev_state == OFFLINE:
             self._arm_expiry()
 
@@ -235,7 +235,7 @@ class SubscriberQueue:
         including chunks the target may have acked — locally. Chunks
         the target kept surface as QoS1 dupes if a later handoff
         succeeds; dupes beat loss."""
-        self.state = ONLINE
+        self._set_state(ONLINE)
         self._resuming = False
         buf, self._resume_buf = self._resume_buf, deque()
         for msg in msgs:
@@ -255,7 +255,7 @@ class SubscriberQueue:
     def terminate(self, reason: str) -> None:
         if self.state == TERMINATED:
             return
-        self.state = TERMINATED
+        self._set_state(TERMINATED)
         self._cancel_expiry()
         for msg in self.offline:
             self._drop(msg)
@@ -270,6 +270,15 @@ class SubscriberQueue:
         self.broker.registry.queue_terminated(self.subscriber_id)
         self.broker.hooks_fire_all("on_client_gone", self.subscriber_id)
         self.broker.metrics.incr("queue_teardown")
+
+    def _set_state(self, state: str) -> None:
+        """Every change of state goes through here: a share group's
+        list of online members follows its members' queues
+        (``Registry.share_member_moved``)."""
+        was_online = self.state == ONLINE
+        self.state = state
+        if was_online != (state == ONLINE):
+            self.broker.registry.share_member_moved(self.subscriber_id)
 
     def _arm_expiry(self) -> None:
         """Persistent-session expiry (persistent_client_expiration config or

@@ -38,6 +38,7 @@ from ..protocol.topic import is_shared, unshare
 from ..protocol.types import PROTO_5, SubOpts
 from .message import Msg, SubscriberId, wire_batch_iovs, wire_v4_iov_qos0
 from .queue import OFFLINE, ONLINE, QueueOpts, SubscriberQueue
+from .shared import ShareGroup, row_key
 from .subscriber_db import (SubscriberDB, SubscriberRecord, opts_from_dict,
                             opts_to_dict)
 
@@ -82,7 +83,9 @@ class TrieRegView:
 
     def fold(self, mountpoint: str, topic: Sequence[str]):
         """Yield match rows: (filter, key, subopts). Keys are SubscriberId
-        for plain subs or ("$g", group, SubscriberId) for shared subs."""
+        for plain subs, ("$g", group, None) for a shared subscription's
+        one row (no subopts: the members are the registry's), a node name
+        for a remote node's pointer row."""
         return self._registry.trie(mountpoint).match(topic)
 
 
@@ -118,6 +121,11 @@ class Registry:
         # remote-node fanout hooks, filled by the cluster layer:
         self.remote_publish = None  # fn(node, msg) (vmq_cluster:publish/2)
         self.remote_enqueue_nowait = None  # fn(node, sid, [msg]) shared subs
+        # shared subscriptions: ONE trie/table row each, keyed
+        # ("$g", group, None); the members by (mountpoint, group, filter
+        # words), and each local member's groups for its queue's moves
+        self._groups: Dict[Tuple[str, str, Tuple[str, ...]], ShareGroup] = {}
+        self._member_of: Dict[SubscriberId, List[ShareGroup]] = {}
 
     def bootstrap(self) -> None:
         """Warm-load routing state from a persisted subscriber DB —
@@ -368,9 +376,10 @@ class Registry:
         the diff of old vs new subscriptions (vmq_subscriber:get_changes,
         vmq_subscriber.erl:54-58) becomes trie/TPU-table deltas. Local
         subscribers become direct rows; remote plain subscriptions collapse
-        into per-node pointer rows; shared-subscription rows keep the full
-        (group, sid) identity with the owning node in the opts
-        (the reference trie's {Node, Group, SubscriberId, SubInfo} rows)."""
+        into per-node pointer rows; a shared subscription is ONE row
+        whatever its members, who are kept in its ShareGroup with the
+        owning node in the opts (the reference trie's {Node, Group,
+        SubscriberId, SubInfo} rows, folded by group)."""
         mountpoint = sid[0]
         old_subs = old.subs if old is not None else {}
         new_subs = new.subs if new is not None else {}
@@ -432,9 +441,7 @@ class Registry:
         self._filters_delta("add", mountpoint, opts)
         group, rest = unshare(list(fw))
         if group is not None:
-            key = ("$g", group, sid)
-            trie.add(rest, key, opts)
-            self._emit_delta("add", mountpoint, rest, key, opts)
+            self._share_join(mountpoint, group, tuple(rest), sid, node, opts)
         elif node == self.node_name:
             trie.add(list(fw), sid, opts)
             self._emit_delta("add", mountpoint, list(fw), sid, opts)
@@ -453,9 +460,7 @@ class Registry:
         self._filters_delta("remove", mountpoint, opts, fw, sid)
         group, rest = unshare(list(fw))
         if group is not None:
-            key = ("$g", group, sid)
-            trie.remove(rest, key)
-            self._emit_delta("remove", mountpoint, rest, key, None)
+            self._share_leave(mountpoint, group, tuple(rest), sid)
         elif node == self.node_name:
             trie.remove(list(fw), sid)
             self._emit_delta("remove", mountpoint, list(fw), sid, None)
@@ -468,6 +473,91 @@ class Registry:
                 self._emit_delta("remove", mountpoint, list(fw), node, None)
             else:
                 self._remote_refs[ref] = n
+
+    # -- shared subscriptions: one row, the members in a ShareGroup --------
+
+    def _share_join(self, mountpoint: str, group: str,
+                    rest: Tuple[str, ...], sid: SubscriberId, node: str,
+                    opts: SubOpts) -> None:
+        """A member subscribed (or changed its options or node): the
+        first member adds the group's one row, later ones only join."""
+        gk = (mountpoint, group, rest)
+        g = self._groups.get(gk)
+        if g is None:
+            g = self._groups[gk] = ShareGroup(group)
+            key = row_key(group)
+            self.trie(mountpoint).add(list(rest), key, None)
+            self._emit_delta("add", mountpoint, list(rest), key, None)
+        local = node == self.node_name
+        q = self.queues.get(sid) if local else None
+        g.join(sid, opts, local, q is not None and q.state == ONLINE,
+               bool(getattr(opts, "filter_expr", None))
+               and self.broker.filter_engine is not None)
+        if local:
+            of = self._member_of.setdefault(sid, [])
+            if g not in of:
+                of.append(g)
+
+    def _share_leave(self, mountpoint: str, group: str,
+                     rest: Tuple[str, ...], sid: SubscriberId) -> None:
+        """A member unsubscribed or expired: the last one takes the
+        group's row away."""
+        gk = (mountpoint, group, rest)
+        g = self._groups.get(gk)
+        if g is None or not g.leave(sid):
+            return
+        of = self._member_of.get(sid)
+        if of is not None and g in of:
+            of.remove(g)
+            if not of:
+                del self._member_of[sid]
+        if not g.members:
+            del self._groups[gk]
+            key = row_key(group)
+            self.trie(mountpoint).remove(list(rest), key)
+            self._emit_delta("remove", mountpoint, list(rest), key, None)
+
+    def share_member_moved(self, sid: SubscriberId) -> None:
+        """A local queue's state changed (``SubscriberQueue._set_state``):
+        each group the session is a member of files it under online or
+        offline by the queue the registry now holds for it."""
+        groups = self._member_of.get(sid)
+        if groups:
+            q = self.queues.get(sid)
+            online = q is not None and q.state == ONLINE
+            for g in groups:
+                g.moved(sid, online)
+
+    def share_member_rows(self, mountpoint: str, rows):
+        """The predicate phase's view of a fold result: beside each
+        shared subscription's row, one row ``("$g", group, sid)`` for
+        each of its members that carries a payload predicate (whose
+        predicate then decides, row by row, as for a plain subscription;
+        an aggregating member's value folds into ITS window). Runs on
+        the engine's thread; rows already expanded are not repeated."""
+        out = None
+        for f, key, _opts in rows:
+            if not (isinstance(key, tuple) and len(key) == 3
+                    and key[2] is None and key[0] == "$g"):
+                continue
+            g = self._groups.get((mountpoint, key[1], tuple(f)))
+            if g is None or not g.filtered:
+                continue
+            if out is None:
+                out = list(rows)
+                seen = {k for _f, k, _o in rows}
+            for sid, opts in list(g.filtered.items()):
+                mk = ("$g", key[1], sid)
+                if mk not in seen:
+                    seen.add(mk)
+                    out.append((f, mk, opts))
+        return rows if out is None else out
+
+    def share_group(self, mountpoint: str, group: str,
+                    filter_words: Sequence[str]) -> Optional[ShareGroup]:
+        """The members of ``$share/<group>/<filter_words>`` (introspection
+        and tests)."""
+        return self._groups.get((mountpoint, group, tuple(filter_words)))
 
     def node_left(self, node: str) -> None:
         """A member left: its subscriber records are rewritten by migration
@@ -836,10 +926,27 @@ class Registry:
         recips: List[Tuple[Any, int]] = []
         fast = True
         frame_bound = 0
+        shared: Optional[set] = None  # group names drawn from
         for _f, key, opts in rows:
             if not (isinstance(key, tuple) and len(key) == 2):
-                fast = False  # $g group row or remote node pointer
-                break
+                # a shared subscription's row: its member is drawn now and
+                # classified below as a plain row is. A remote node's
+                # pointer, a member's own predicate row, a group whose
+                # draw is no local online member, or a name met twice
+                # (two filters of one group) take the classic path
+                picked = None
+                if (isinstance(key, tuple) and len(key) == 3
+                        and key[2] is None and key[0] == "$g"
+                        and (shared is None or key[1] not in shared)):
+                    picked = self._share_wire_pick(mountpoint, key[1], _f,
+                                                   from_sid)
+                if picked is None:
+                    fast = False
+                    break
+                if shared is None:
+                    shared = set()
+                shared.add(key[1])
+                key, opts = picked
             if opts.no_local and key == from_sid:
                 continue
             if (getattr(opts, "filter_expr", None)
@@ -886,6 +993,9 @@ class Registry:
             if recips:
                 self._wire_fanout(mountpoint, words, topic_str, payload,
                                   wire_frame, payload_skip, recips)
+            if shared:
+                fastpath.share_picks += len(shared)
+                fastpath.share_wire_picks += len(shared)
             return len(recips)
         # complex fanout: ONE Msg, the exact classic path (host
         # predicate phase included — a racing filter subscription must
@@ -1039,7 +1149,9 @@ class Registry:
         node-row forwards onto the cluster envelope so the receiving
         node resumes it (one cross-node Perfetto trace)."""
         matches = 0
-        groups: Dict[str, List[Tuple[SubscriberId, SubOpts]]] = {}
+        # by group NAME, as upstream's fold collects: the ShareGroup of
+        # each of the name's rows, and the members whose predicate passed
+        groups: Dict[str, List[Any]] = {}
         forwarded_nodes = set()  # one msg frame per remote node per publish
         # batched QoS0 fanout (the host hot path): recipients whose
         # delivery needs NO per-subscription transform and whose session
@@ -1055,9 +1167,14 @@ class Registry:
                 if not origin_local:
                     continue
                 _, group, sid = key
-                if opts.no_local and sid == from_sid:
-                    continue
-                groups.setdefault(group, []).append((sid, opts))
+                if sid is None:  # the shared subscription's one row
+                    g = self._groups.get((msg.mountpoint, group,
+                                          tuple(_filter)))
+                    if g is not None:
+                        groups.setdefault(group, []).append(g)
+                elif not (opts.no_local and sid == from_sid):
+                    # a member whose payload predicate passed
+                    groups.setdefault(group, []).append((sid, opts))
                 continue
             if isinstance(key, str):  # remote node pointer
                 if origin_local and key not in forwarded_nodes:
@@ -1092,8 +1209,8 @@ class Registry:
                 matches += 1
         if fan0:
             self._fanout_qos0(msg, fan0)
-        for group, members in groups.items():
-            if self._publish_shared(msg, members):
+        for found in groups.values():
+            if self._publish_shared(msg, found, from_sid):
                 matches += 1
         if matches:
             self.broker.metrics.incr("router_matches_local", matches)
@@ -1213,47 +1330,155 @@ class Registry:
             m.incr("bytes_sent", delivered * nbytes)
             m.incr("mqtt_publish_sent", delivered)
 
-    def _publish_shared(
-        self, msg: Msg, members: List[Tuple[SubscriberId, SubOpts]]
-    ) -> bool:
-        """Pick one group member by policy, online members first
-        (vmq_shared_subscriptions.erl:26-63,90-106): ``prefer_local`` tries
-        local members before remote ones, ``local_only`` never leaves the
-        node, ``random`` mixes both. Remote member delivery rides the
-        cluster ``enq`` channel (vmq_shared_subscriptions.erl:86-88)."""
+    def _publish_shared(self, msg: Msg, found: List[Any],
+                        from_sid: Optional[SubscriberId]) -> bool:
+        """Deliver to ONE member of a shared subscription, drawn by
+        policy with online members first
+        (vmq_shared_subscriptions.erl:26-63,90-106): uniformly within the
+        first class that yields a delivery — ``prefer_local``: online
+        local, then remote, then offline local; ``random``: online local
+        and remote together, then offline local; ``local_only``: online
+        local, then offline local. ``found`` holds the ShareGroup of each
+        row of the group's name and the members whose payload predicate
+        passed. A member's own QoS, rap and subscription id apply
+        (``_prep_out``); a remote member's delivery rides the cluster
+        ``enq`` channel (vmq_shared_subscriptions.erl:86-88)."""
+        classes = self._share_classes(found)
+        last = len(classes) - 1
+        for c, pools in enumerate(classes):
+            for sid, opts, pool in self._share_order(pools):
+                if opts.no_local and sid == from_sid:
+                    continue
+                if pool[3]:  # drawn as online here: is it still?
+                    q = self.queues.get(sid)
+                    if q is None or q.state != ONLINE:
+                        fastpath.share_stale_picks += 1
+                        if pool[2] is not None:
+                            pool[2].moved(sid, False)
+                        continue
+                if self._share_send(msg, sid, opts):
+                    fastpath.share_picks += 1
+                    if c == last:
+                        fastpath.share_offline_picks += 1
+                    return True
+        return False
+
+    def _share_wire_pick(self, mountpoint: str, group: str, filter_words,
+                         from_sid: Optional[SubscriberId]):
+        """(sid, opts) of the member the wire plane writes a group's
+        delivery to: drawn from the policy's first class, and a local
+        member online here. None (a remote member drawn, no member online,
+        a member with a predicate) leaves the publish to the classic path,
+        whose draw is made afresh from the same classes."""
+        g = self._groups.get((mountpoint, group, tuple(filter_words)))
+        if g is None or g.filtered:
+            return None
+        for sid, opts, pool in self._share_order(self._share_classes([g])[0]):
+            if opts.no_local and sid == from_sid:
+                continue
+            if not pool[3]:
+                return None
+            q = self.queues.get(sid)
+            if q is None or q.state != ONLINE:
+                fastpath.share_stale_picks += 1
+                g.moved(sid, False)
+                continue
+            return sid, opts
+        return None
+
+    def _share_classes(self, found: List[Any]) -> List[List[tuple]]:
+        """The policy's candidate classes, in order, each a list of pools
+        ``(sids, opts by sid, the ShareGroup whose online list a stale
+        draw repairs, whether the member must be online here)``: a
+        group's indexed sets as they stand, and the members whose
+        predicate passed, classed now."""
+        online: List[tuple] = []
+        remote: List[tuple] = []
+        offline: List[tuple] = []
+        extra: Dict[SubscriberId, SubOpts] = {}
+        x_on: List[SubscriberId] = []
+        x_remote: List[SubscriberId] = []
+        x_off: List[SubscriberId] = []
+        for f in found:
+            if type(f) is ShareGroup:
+                online.append((f.online.sids, f.members, f, True))
+                remote.append((f.remote.sids, f.members, None, False))
+                offline.append((f.offline.sids, f.members, None, False))
+                continue
+            sid, opts = f
+            extra[sid] = opts
+            if getattr(opts, "node", self.node_name) != self.node_name:
+                x_remote.append(sid)
+            elif (q := self.queues.get(sid)) is not None \
+                    and q.state == ONLINE:
+                x_on.append(sid)
+            else:
+                x_off.append(sid)
+        if extra:
+            online.append((x_on, extra, None, True))
+            remote.append((x_remote, extra, None, False))
+            offline.append((x_off, extra, None, False))
         policy = self.broker.config.shared_subscription_policy
-        local, remote = [], []
-        for sid, opts in members:
-            node = getattr(opts, "node", self.node_name)
-            (local if node == self.node_name else remote).append((sid, opts, node))
-        random.shuffle(local)
-        random.shuffle(remote)
-        local_online = [m for m in local
-                        if (q := self.queues.get(m[0])) is not None
-                        and q.state == ONLINE]
         if policy == "local_only":
-            candidates = local_online + [m for m in local if m not in local_online]
-        elif policy == "random":
-            mixed = local_online + remote
-            random.shuffle(mixed)
-            candidates = mixed + [m for m in local if m not in local_online]
-        else:  # prefer_local
-            candidates = (local_online + remote
-                          + [m for m in local if m not in local_online])
-        for sid, opts, node in candidates:
-            if node == self.node_name:
-                if self._enqueue_to(sid, msg, opts):
-                    return True
-            elif self.remote_enqueue_nowait is not None:
-                if self.remote_enqueue_nowait(node, sid, [self._prep_out(msg, opts)]):
-                    self.broker.metrics.incr("router_matches_remote")
-                    return True
+            return [online, offline]
+        if policy == "random":
+            return [online + remote, offline]
+        return [online, remote, offline]  # prefer_local
+
+    #: draws by position a class gets before the rest of its members are
+    #: offered in a shuffled order (draws refused: a member already
+    #: offered, the publisher's own no-local membership, a stale entry, a
+    #: delivery that failed)
+    _SHARE_DRAWS = 4
+
+    def _share_order(self, pools: List[tuple]):
+        """The members of one class in a uniformly random order, made
+        lazily: a draw by position is O(1) whatever the class's size, so
+        a consumer that takes the first member pays nothing for the
+        rest. Yields ``(sid, opts, pool)``; the consumer may move a stale
+        member out of a pool between draws."""
+        seen = set()
+        for _ in range(self._SHARE_DRAWS):
+            n = 0
+            for p in pools:
+                n += len(p[0])
+            if n == 0:
+                return
+            i = random.randrange(n)
+            for p in pools:
+                if i < len(p[0]):
+                    break
+                i -= len(p[0])
+            sid = p[0][i]
+            if sid not in seen:
+                seen.add(sid)
+                yield sid, p[1][sid], p
+        rest = [(sid, p) for p in pools for sid in list(p[0])
+                if sid not in seen]
+        random.shuffle(rest)
+        for sid, p in rest:
+            if sid not in seen and sid in p[1]:
+                seen.add(sid)
+                yield sid, p[1][sid], p
+
+    def _share_send(self, msg: Msg, sid: SubscriberId,
+                    opts: SubOpts) -> bool:
+        node = getattr(opts, "node", self.node_name)
+        if node == self.node_name:
+            return self._enqueue_to(sid, msg, opts)
+        if self.remote_enqueue_nowait is not None and \
+                self.remote_enqueue_nowait(node, sid,
+                                           [self._prep_out(msg, opts)]):
+            self.broker.metrics.incr("router_matches_remote")
+            return True
         return False
 
     # -- introspection -----------------------------------------------------
 
     def stats(self) -> Dict[str, float]:
-        total = sum(len(t) for t in self._tries.values())
+        # a shared subscription is one trie row; each member counts
+        total = sum(len(t) for t in self._tries.values()) + sum(
+            len(g.members) - 1 for g in self._groups.values())
         mem = sum(t.stats()["memory"] for t in self._tries.values())
         out = {
             "router_subscriptions": total,

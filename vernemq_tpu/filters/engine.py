@@ -244,6 +244,11 @@ class FilterEngine:
         #: emission hook, wired by the broker:
         #: fn(mountpoint, sub_key, opts, topic_words, payload_bytes)
         self.emit: Optional[Callable[..., None]] = None
+        #: a shared subscription is ONE row; its members that carry a
+        #: predicate come back as rows of their own beside it, keyed
+        #: ("$g", group, sid), from this hook (wired by the broker):
+        #: fn(mountpoint, rows) -> rows
+        self.share_rows: Optional[Callable[..., List[Any]]] = None
         self._lock = threading.Lock()          # registry + window state
         self._device_lock = threading.Lock()   # one device dispatch at a time
         self._tables: Dict[str, _PredTable] = {}
@@ -459,6 +464,8 @@ class FilterEngine:
                 plans.append(plan)
                 if not rows:
                     continue
+                if self.share_rows is not None:
+                    rows = self.share_rows(mountpoint, rows)
                 topic, feat = items[i]
                 schema = None
                 schema_done = False

@@ -109,6 +109,11 @@ egress_writes = 0       #: transports written by them
 egress_publishes = 0    #: PUBLISH frames those writes carried
 egress_joined = 0       #: of those, several chunks sent as one joined write
 egress_scattered = 0    #: of those, several chunks sent through writelines
+# shared subscriptions (broker/reg.py): one member drawn a publish
+share_picks = 0         #: deliveries made to a shared subscription's member
+share_wire_picks = 0    #: of those, written by the wire plane's fanout
+share_stale_picks = 0   #: draws whose member was online no more (repaired)
+share_offline_picks = 0  #: deliveries to an offline member: none online
 
 
 def load_native():
@@ -170,6 +175,10 @@ def stats():
         "wire_egress_joined": float(egress_joined),
         "wire_egress_scattered": float(egress_scattered),
         "wire_breaker_state": float(breaker.state),
+        "share_picks": float(share_picks),
+        "share_wire_picks": float(share_wire_picks),
+        "share_stale_picks": float(share_stale_picks),
+        "share_offline_picks": float(share_offline_picks),
     }
 
 
