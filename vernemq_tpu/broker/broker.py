@@ -459,6 +459,21 @@ class Broker:
                                      "through writelines so a shared "
                                      "payload is not copied per "
                                      "recipient.",
+            "wire_egress_offload_writes": "Of wire_egress_writes, the "
+                                          "transports a flush handed to "
+                                          "the native writer thread "
+                                          "(plain sockets) instead of "
+                                          "writing on the loop.",
+            "wire_egress_offload_sent": "Hand-offs the writer thread "
+                                        "finished sending.",
+            "wire_egress_offload_lag_us": "Sum over those of the time "
+                                          "from hand-off to the last "
+                                          "byte sent, in microseconds: "
+                                          "over wire_egress_offload_sent, "
+                                          "whether the writer keeps up.",
+            "wire_egress_offload_dropped": "Backlogs the writer dropped "
+                                           "because their connection "
+                                           "was lost.",
             "wire_fanout_batches": "One-call batched fanout header "
                                    "encodes (publish_headers_batch): "
                                    "each emitted N per-recipient "
@@ -1845,6 +1860,8 @@ class Broker:
     async def start(self) -> None:
         self._log_handlers: List[Any] = []
         self._setup_logging()
+        # the outbox's writer thread: plain-socket sends off the loop
+        self.outbox.start()
         # observability master switch: off reduces every histogram/
         # profiler seam to one module-global boolean test (PERF.md
         # §6, PR 25, has what the seams cost when on). The flag is
@@ -2183,3 +2200,6 @@ class Broker:
             self._resume_collector.close()
         self.msg_store.close()
         self.metadata.close()
+        # last: every listener is down and every session closed, so what
+        # they wrote is handed off; the writer's thread is joined
+        self.outbox.close()

@@ -13,12 +13,29 @@ the outbox may also run it at the end of its own callback
 (``BatchCollector._release``, ``MQTTServer._serve_inbox``): a release
 chunk's writes then leave before that turn's reads instead of behind
 them, and the scheduled callback is cancelled.
+
+Who makes the ``send`` calls. A transport over a plain socket (TCP or
+PROXY listener: ``get_extra_info("socket")`` has a descriptor and there
+is no ``sslcontext``) is attached, when it is made, to the outbox's
+native writer (``native/egress.cc``, ``_vmq_egress.Writer``): the flush
+hands every such transport's chunks to the writer in ONE call and
+returns, and the writer's thread, which never takes the interpreter
+lock, sends them back to back, in hand-off order per connection, on a
+descriptor of its own (a ``dup``: the loop's may close and be reused at
+any time). Every byte of such a connection goes through the writer, so
+asyncio's own buffer stays empty and a connection's order is one FIFO.
+TLS, WebSocket and any transport without a socket are written on the
+loop as before; so is everything where the extension is absent
+(``VMQ_NO_NATIVE``, no toolchain, a failed build). The writer starts
+with the broker (``Outbox.start``) and is joined at its stop
+(``Outbox.close``).
 """
 
 from __future__ import annotations
 
 import asyncio
 
+from ..native import load_extension
 from ..observability import histogram as obs
 from ..protocol import fastpath
 from .session import Transport
@@ -53,7 +70,7 @@ class Outbox:
     fanout, ``Session.send`` and the PUBACK count into them; ``flush``
     folds them into ``Metrics`` before it writes). One per broker."""
 
-    __slots__ = ("_metrics", "_listed", "_handle", "bytes_sent",
+    __slots__ = ("_metrics", "_listed", "_handle", "_writer", "bytes_sent",
                  "publish_sent", "puback_sent", "queue_in", "queue_out",
                  "matches_local")
 
@@ -62,12 +79,63 @@ class Outbox:
         self._listed: list = []
         # the scheduled flush; not None <=> something is listed or counted
         self._handle = None
+        # the native writer (``start``), or None: every write on the loop
+        self._writer = None
         self.bytes_sent = 0
         self.publish_sent = 0
         self.puback_sent = 0
         self.queue_in = 0
         self.queue_out = 0
         self.matches_local = 0
+
+    def start(self) -> None:
+        """Start the writer thread, where the extension loads."""
+        if self._writer is None:
+            mod = load_extension("_vmq_egress", min_version=1,
+                                 version_attr="EGRESS_VERSION")
+            if mod is not None:
+                self._writer = mod.Writer(JOIN_MAX)
+
+    def close(self) -> None:
+        """Hand what is listed to the writer, then stop and join its
+        thread: it tries each backlog once more, then closes every
+        descriptor it holds."""
+        self.flush()
+        writer, self._writer = self._writer, None
+        if writer is not None:
+            writer.stop()
+            self._take(writer)
+
+    def attach(self, transport) -> int:
+        """The writer's id for a transport over a plain socket, or 0:
+        a TLS or socketless transport, or no writer."""
+        writer = self._writer
+        if writer is None:
+            return 0
+        try:
+            if (transport.is_closing()
+                    or transport.get_extra_info("sslcontext") is not None):
+                return 0
+            fd = transport.get_extra_info("socket").fileno()
+        except AttributeError:  # a fixture, or no socket
+            return 0
+        if fd < 0:
+            return 0
+        try:
+            return writer.attach(fd)
+        except OSError:
+            return 0
+
+    def release(self, wid: int, chunks: list, drain: bool) -> None:
+        """A transport is done with the writer: ``chunks`` (its last) are
+        sent, then the descriptor closes after the last byte (``drain``),
+        or the backlog is dropped and it closes at once."""
+        writer = self._writer
+        if writer is None:
+            return
+        if chunks:
+            writer.submit([wid, chunks])
+        writer.close(wid, drain)
 
     def add(self, transport: "StreamTransport") -> None:
         self._listed.append(transport)
@@ -106,14 +174,20 @@ class Outbox:
             else:
                 self._listed = []
             writes = joined = scattered = 0
+            handoff: list = []
             for transport in listed:
-                form = transport._flush()
+                form = transport._flush(handoff)
                 if form:
                     writes += 1
                     if form == _JOINED:
                         joined += 1
                     elif form == _SCATTERED:
                         scattered += 1
+            if handoff and self._writer is not None:  # None: stopped
+                handed, j, sc = self._writer.submit(handoff)
+                fastpath.egress_offload_writes += handed
+                joined += j
+                scattered += sc
             fastpath.egress_flushes += 1
             fastpath.egress_writes += writes
             fastpath.egress_joined += joined
@@ -121,7 +195,18 @@ class Outbox:
         finally:
             obs.span_end("stage_egress_flush_ms", tok)
 
+    @staticmethod
+    def _take(writer) -> None:
+        sent, lag_us, dropped = writer.take()
+        if sent:
+            fastpath.egress_offload_sent += sent
+            fastpath.egress_offload_lag_us += lag_us
+        if dropped:
+            fastpath.egress_offload_dropped += dropped
+
     def _fold(self) -> None:
+        if self._writer is not None:
+            self._take(self._writer)
         incr = self._metrics.incr
         if self.bytes_sent:
             incr("bytes_sent", self.bytes_sent)
@@ -144,8 +229,9 @@ class Outbox:
             self.matches_local = 0
 
 
-# what one transport's flush sent, for the outbox's gauges
-_SINGLE, _JOINED, _SCATTERED = 1, 2, 3
+# what one transport's flush sent, for the outbox's gauges (_HANDED: its
+# chunks went to the writer, which counts their form)
+_SINGLE, _JOINED, _SCATTERED, _HANDED = 1, 2, 3, 4
 
 
 class StreamTransport(Transport):
@@ -159,8 +245,9 @@ class StreamTransport(Transport):
     ``writelines`` costs a ``sendmsg`` and asyncio's buffer upkeep
     around it; anything larger as ``writelines``, so a fanout's shared
     payload object is referenced from every recipient's iovec and only
-    copied once, inside the transport. Backpressure stays asyncio's:
-    every byte goes through the wrapped transport's own ``write``."""
+    copied once, inside the transport. Over a plain socket the chunks go
+    to the outbox's writer instead (``_wid``, decided once, here), which
+    applies the same rule to what it sends."""
 
     def __init__(self, transport: asyncio.WriteTransport, outbox: Outbox):
         self._transport = transport
@@ -168,6 +255,7 @@ class StreamTransport(Transport):
         self._chunks: list = []
         self._listed = False
         self.closed = False
+        self._wid = outbox.attach(transport)  # 0: written on the loop
 
     def write(self, data: bytes) -> None:
         if self.closed:
@@ -188,15 +276,20 @@ class StreamTransport(Transport):
             self._listed = True
             self._outbox.add(self)
 
-    def _flush(self) -> int:
-        """Send what is pending; returns the form it took (0: nothing —
-        closed, or ``close`` flushed it already). A transport that
-        raises is closed, and the outbox's walk goes on."""
+    def _flush(self, handoff: list) -> int:
+        """Send what is pending, or append it to the flush's ``handoff``
+        to the writer; returns the form it took (0: nothing — closed, or
+        ``close`` flushed it already). A transport that raises is
+        closed, and the outbox's walk goes on."""
         self._listed = False
         chunks = self._chunks
         if self.closed or not chunks:
             return 0
         self._chunks = []
+        if self._wid:
+            handoff.append(self._wid)
+            handoff.append(chunks)
+            return _HANDED
         try:
             if len(chunks) == 1:
                 self._transport.write(chunks[0])
@@ -213,9 +306,26 @@ class StreamTransport(Transport):
     def close(self) -> None:
         if self.closed:
             return
-        self._flush()
+        wid, self._wid = self._wid, 0
+        if wid:
+            # the writer sends what is pending and closes its descriptor
+            # after it: the FIN follows the last byte
+            chunks, self._chunks = self._chunks, []
+            self._outbox.release(wid, chunks, True)
+        else:
+            self._flush([])
         self.closed = True
         try:
             self._transport.close()
         except Exception:
             pass
+
+    def lost(self) -> None:
+        """The connection is gone (its protocol's ``connection_lost``):
+        the writer drops what it still holds for it and releases its
+        descriptor; nothing more is written."""
+        wid, self._wid = self._wid, 0
+        if wid:
+            self.closed = True
+            self._chunks = []
+            self._outbox.release(wid, [], False)

@@ -453,7 +453,7 @@ class MqttProtocol(asyncio.Protocol):
 
     __slots__ = ("server", "transport", "_metrics", "_buf", "_tail",
                  "_session", "_waiter", "_eof", "_exc", "_paused", "_task",
-                 "_closed")
+                 "_closed", "_stream")
 
     def __init__(self, server: "MQTTServer"):
         self.server = server
@@ -468,6 +468,8 @@ class MqttProtocol(asyncio.Protocol):
         self._paused = False
         self._task: Optional[asyncio.Task] = None
         self._closed: Optional[asyncio.Future] = None
+        # the connection's write side, once its task made it
+        self._stream: Optional[StreamTransport] = None
 
     # ------------------------------------------------- asyncio's side
 
@@ -507,6 +509,10 @@ class MqttProtocol(asyncio.Protocol):
         return self.transport.get_extra_info("sslcontext") is None
 
     def connection_lost(self, exc) -> None:
+        if self._stream is not None:
+            # the writer thread drops what it holds for this socket and
+            # closes its descriptor: nothing more is sent
+            self._stream.lost()
         self._eof = True
         if exc is not None and self._exc is None:
             self._exc = exc
@@ -605,9 +611,9 @@ class MqttProtocol(asyncio.Protocol):
             if not ok:
                 transport.close()  # cert required for identity mapping
                 return
+            self._stream = StreamTransport(transport, srv.broker.outbox)
             await mqtt_connection(
-                srv.broker, self,
-                StreamTransport(transport, srv.broker.outbox),
+                srv.broker, self, self._stream,
                 transport.get_extra_info("peername") or ("", 0),
                 srv.max_frame_size, preauth_user=preauth,
                 mountpoint=srv.mountpoint,

@@ -31,7 +31,8 @@ _build_attempted = False
 
 
 _TARGETS = ("libvmq_kvstore.so", "libvmq_counters.so", "libvmq_bcrypt.so",
-            "vmq-passwd", "_vmq_codec.so", "libvmq_fence.so")
+            "vmq-passwd", "_vmq_codec.so", "libvmq_fence.so",
+            "_vmq_egress.so")
 
 
 def _all_built() -> bool:

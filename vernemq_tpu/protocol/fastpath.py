@@ -109,6 +109,10 @@ egress_writes = 0       #: transports written by them
 egress_publishes = 0    #: PUBLISH frames those writes carried
 egress_joined = 0       #: of those, several chunks sent as one joined write
 egress_scattered = 0    #: of those, several chunks sent through writelines
+egress_offload_writes = 0   #: of egress_writes, handed to the native writer
+egress_offload_sent = 0     #: hand-offs the writer finished sending
+egress_offload_lag_us = 0   #: sum over those of hand-off -> last byte sent
+egress_offload_dropped = 0  #: backlogs the writer dropped: connection lost
 # shared subscriptions (broker/reg.py): one member drawn a publish
 share_picks = 0         #: deliveries made to a shared subscription's member
 share_wire_picks = 0    #: of those, written by the wire plane's fanout
@@ -174,6 +178,10 @@ def stats():
         "wire_egress_publishes": float(egress_publishes),
         "wire_egress_joined": float(egress_joined),
         "wire_egress_scattered": float(egress_scattered),
+        "wire_egress_offload_writes": float(egress_offload_writes),
+        "wire_egress_offload_sent": float(egress_offload_sent),
+        "wire_egress_offload_lag_us": float(egress_offload_lag_us),
+        "wire_egress_offload_dropped": float(egress_offload_dropped),
         "wire_breaker_state": float(breaker.state),
         "share_picks": float(share_picks),
         "share_wire_picks": float(share_wire_picks),
