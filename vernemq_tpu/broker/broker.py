@@ -502,6 +502,12 @@ class Broker:
                                    "each emitted N per-recipient "
                                    "pid/alias-patched headers into one "
                                    "arena.",
+            "wire_fanout_pass_pubs": "Wire-plane publishes whose every "
+                                     "matched row the one-pass fan-out "
+                                     "served.",
+            "wire_fanout_classic_pubs": "Wire-plane publishes a complex "
+                                        "row sent to the classic "
+                                        "route_rows path.",
             "wire_breaker_state": "Wire-codec breaker state (0 closed, "
                                   "1 half-open, 2 open).",
             # shared subscriptions (broker/shared.py): one member drawn

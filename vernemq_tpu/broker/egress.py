@@ -142,6 +142,33 @@ class Outbox:
         if self._handle is None:
             self._schedule()
 
+    def queue_frames(self, recipients, heads, tail=None) -> None:
+        """One fan-out's frames: recipient *i*'s ``transport`` takes
+        ``heads[i]`` and then the shared ``tail`` (the payload, uncopied;
+        None: nothing), as ``StreamTransport.write_iov`` would queue them,
+        but straight into its chunk list — no call a recipient. The
+        transports first written in this turn are listed, in recipient
+        order; a closed one takes nothing. Another transport (WebSocket, a
+        fixture, another outbox's) takes its own ``write_iov``."""
+        listed = self._listed
+        n = len(listed)
+        for r, head in zip(recipients, heads):
+            t = r.transport
+            if t.__class__ is not StreamTransport or t._outbox is not self:
+                t.write_iov((head,) if tail is None else (head, tail))
+                continue
+            if t.closed:
+                continue
+            chunks = t._chunks
+            chunks.append(head)
+            if tail is not None:
+                chunks.append(tail)
+            if not t._listed:
+                t._listed = True
+                listed.append(t)
+        if len(listed) > n and self._handle is None:
+            self._schedule()
+
     def touch(self) -> None:
         """A counter was added to: see that a flush is due (a write to a
         ``StreamTransport`` has listed it already; a WebSocket's or a

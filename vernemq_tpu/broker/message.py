@@ -140,18 +140,6 @@ def wire_v4_iov_qos(msg: "Msg", pid: int) -> tuple:
     return (bytes(tpl), msg.payload)
 
 
-def wire_batch_iovs(arena: bytes, offsets, payload: bytes) -> list:
-    """Per-recipient writev iovecs over a batched header arena
-    (``fastpath.publish_headers_batch``): recipient *i*'s header is the
-    zero-copy memoryview slice ``arena[offsets[i]:offsets[i+1]]``, and
-    the shared payload bytes object rides every iovec uncopied — the
-    whole fanout touches ONE arena allocation plus the payload the
-    parser already sliced."""
-    mv = memoryview(arena)
-    return [(mv[offsets[i]:offsets[i + 1]], payload)
-            for i in range(len(offsets) - 1)]
-
-
 def wire_v4_qos0(msg: "Msg") -> bytes:
     """The v4 QoS0 PUBLISH wire frame for ``msg``, cached on the Msg:
     identical for every v4 QoS0 recipient (no packet id, no props, no

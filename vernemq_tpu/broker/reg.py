@@ -29,6 +29,7 @@ import dataclasses
 import logging
 import random
 import time
+from itertools import repeat
 from typing import TYPE_CHECKING, Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 from ..models.trie import SubscriptionTrie
@@ -36,8 +37,9 @@ from ..observability import histogram as obs
 from ..protocol import fastpath
 from ..protocol.topic import is_shared, unshare
 from ..protocol.types import PROTO_5, SubOpts
-from .message import Msg, SubscriberId, wire_batch_iovs, wire_v4_iov_qos0
+from .message import Msg, SubscriberId, wire_v4_iov_qos0
 from .queue import OFFLINE, ONLINE, QueueOpts, SubscriberQueue
+from .session import wire_take_qos_fanout
 from .shared import ShareGroup, row_key
 from .subscriber_db import (SubscriberDB, SubscriberRecord, opts_from_dict,
                             opts_to_dict)
@@ -906,24 +908,30 @@ class Registry:
                     wire_frame: Optional[bytes] = None,
                     payload_skip: int = 0, qos: int = 0,
                     trace=None) -> int:
-        """Classify the fold result: if EVERY matched row is the plain
-        fast shape, write the shared wire bytes to each recipient's
-        transport (verbatim inbound span for v4 QoS0 publishers, one
-        shared native-encoded header, or one batched per-recipient
-        header arena for pid/alias-bearing groups — always with the
-        shared payload riding the iovec uncopied) — the object-free
-        half of the wire plane. One complex row routes the whole
-        fanout through the classic Msg path for exact semantics.
+        """One publish's fan-out on the wire plane, in one pass over its
+        matched rows: each row is classified once, into a flat list of
+        recipients and their kinds; if EVERY row is the plain fast shape
+        they are registered and written by ``_wire_fanout`` (verbatim
+        inbound span for v4 QoS0 publishers, one shared native-encoded
+        header, or one batched per-recipient header arena for
+        pid/alias-bearing groups — always with the shared payload riding
+        the iovec uncopied) — the object-free half of the wire plane. One
+        complex row routes the whole fanout through the classic Msg path
+        for exact semantics; nothing was registered by then.
 
-        Fast rows now include v5 recipients (alias-aware headers from
-        the per-connection LRU via ``wire_alias_for``) and QoS≥1
-        deliveries (in-flight bookkeeping via ``wire_take_qos``); a
+        Fast rows include v5 recipients (alias-aware headers from the
+        per-connection LRU via ``wire_alias_for``) and QoS≥1 deliveries
+        (in-flight bookkeeping via ``wire_take_qos_fanout``); a
         qos-downgrade row (subscription qos below the publish qos but
-        above 0) builds its own shared Msg per effective qos."""
+        above 0) builds its own shared Msg per effective qos. Counted:
+        ``fastpath.fanout_pass_pubs`` / ``fanout_classic_pubs``."""
         rows = list(rows)
-        cfg = self.broker.config
-        upgrade = cfg.upgrade_outgoing_qos
-        recips: List[Tuple[Any, int]] = []
+        upgrade = self.broker.config.upgrade_outgoing_qos
+        queues = self.queues
+        # the recipients in row order, and each one's kind: its effective
+        # QoS times 2, plus 1 for a v5 session
+        sessions: List[Any] = []
+        kinds: List[int] = []
         fast = True
         frame_bound = 0
         shared: Optional[set] = None  # group names drawn from
@@ -949,25 +957,31 @@ class Registry:
                 key, opts = picked
             if opts.no_local and key == from_sid:
                 continue
+            eff = opts.qos
             if (getattr(opts, "filter_expr", None)
                     or getattr(opts, "subscription_id", None)
-                    or (upgrade and opts.qos > 0)):
+                    or (upgrade and eff > 0)):
                 fast = False
                 break
-            q = self.queues.get(key)
+            q = queues.get(key)
             if q is None:
                 continue
-            if q.state is not ONLINE or len(q.sessions) != 1:
-                fast = False  # offline backlog / multi-session queue
+            try:
+                (sess,) = q.sessions
+                # a consumer that is no Session (a bridge endpoint) lacks
+                # these and classifies complex, as in the classic fan0
+                # collection
+                closed = sess.closed
+                is5 = sess.proto_ver == PROTO_5
+            except (ValueError, AttributeError):
+                fast = False  # no session, or several; not a Session
                 break
-            sess = next(iter(q.sessions))
-            # getattr defaults: non-Session consumers (bridge
-            # endpoints) classify complex, same as the classic fan0
-            # collection
-            if getattr(sess, "closed", True):
-                fast = False
+            if closed or q.state is not ONLINE:
+                fast = False  # a closed session, or an offline backlog
                 break
-            if getattr(sess, "proto_ver", 0) == PROTO_5:
+            if eff > qos:
+                eff = qos
+            if is5:
                 ok5 = getattr(sess, "wire_v5_fast_ok", None)
                 if ok5 is None:
                     fast = False
@@ -988,19 +1002,24 @@ class Registry:
                 if not ok5(frame_bound):
                     fast = False  # frame may exceed the session's cap
                     break
-            recips.append((sess, min(opts.qos, qos)))
+                kinds.append(eff + eff + 1)
+            else:
+                kinds.append(eff + eff)
+            sessions.append(sess)
         if fast:
-            if recips:
+            fastpath.fanout_pass_pubs += 1
+            if sessions:
                 self._wire_fanout(mountpoint, words, topic_str, payload,
-                                  wire_frame, payload_skip, recips)
+                                  wire_frame, payload_skip, sessions, kinds)
             if shared:
                 fastpath.share_picks += len(shared)
                 fastpath.share_wire_picks += len(shared)
-            return len(recips)
+            return len(sessions)
         # complex fanout: ONE Msg, the exact classic path (host
         # predicate phase included — a racing filter subscription must
         # still filter). The payload materialises HERE, lazily, when
         # the fast fanout didn't need it as separate bytes.
+        fastpath.fanout_classic_pubs += 1
         if payload is None:
             payload = wire_frame[payload_skip:]
         msg = Msg(topic=tuple(words), payload=payload, qos=qos,
@@ -1011,102 +1030,97 @@ class Registry:
     def _wire_fanout(self, mountpoint: str, words: Tuple[str, ...],
                      topic_str: str, payload: Optional[bytes],
                      wire_frame: Optional[bytes], payload_skip: int,
-                     recips: List[Tuple[Any, int]]) -> None:
-        """The object-free fast fanout write. Recipients group by
-        (effective qos, protocol):
+                     sessions: List[Any], kinds: List[int]) -> None:
+        """The object-free fast fanout write of ``_wire_route``'s
+        recipients, one group a kind — all of them, as a rule, in one:
 
         - v4 effective-QoS0 recipients share ONE frame — the verbatim
           inbound span when the publisher gave us one, else one
           encoded header + payload iovec;
         - every pid- or alias-bearing group (QoS≥1 and/or v5) encodes
           ALL its per-recipient headers in ONE
-          ``fastpath.publish_headers_batch`` call and writes
+          ``fastpath.publish_headers_batch`` call, and the outbox queues
           memoryview slices of the arena, the shared payload riding
-          each iovec uncopied;
+          each iovec uncopied (``Outbox.queue_frames``);
         - QoS≥1 recipients register the (lazily built, shared) Msg in
-          their in-flight window first (``wire_take_qos``); a full
-          window parks the Msg in pending exactly like the classic
-          deliver path — no wire write now, the ack-driven pump owns
-          it."""
-        m = self.broker.metrics
+          their in-flight window first (``wire_take_qos_fanout``); a full
+          window parks the Msg in pending, or past its cap in the queue's
+          backlog, exactly like the classic deliver path — no wire write
+          now, the ack-driven pump owns it. A message in a session's
+          pending was handed to the session and counts as out of its
+          queue, as there."""
         t0 = time.monotonic()
+        b = self.broker
+        cfg = b.config
+        ob = b.outbox
+        k0 = kinds[0]
+        if kinds.count(k0) == len(kinds):
+            groups = [(k0, sessions)]
+        else:
+            # a downgrade, or v4 beside v5: v4 QoS0 first, then each kind
+            # in the order it first appears
+            order = sorted(dict.fromkeys(kinds), key=bool)
+            groups = [(k, [s for s, sk in zip(sessions, kinds) if sk == k])
+                      for k in order]
         nbytes = 0
         sent = 0
+        pended = 0  # parked in a session's pending: handed to it
         parked = 0
-        v4_plain: List[Any] = []
-        groups: Dict[Tuple[int, bool], List[Tuple[Any, Optional[int],
-                                                  Optional[int]]]] = {}
         msg_by_eff: Dict[int, Msg] = {}
-        for sess, eff in recips:
-            is5 = getattr(sess, "proto_ver", 0) == PROTO_5
-            if eff == 0:
-                if not is5:
-                    v4_plain.append(sess)
+        for kind, members in groups:
+            if kind == 0:  # v4 QoS0: one frame for all
+                if wire_frame is not None:
+                    fb = len(wire_frame)
+                    ob.queue_frames(members, repeat(wire_frame))
                 else:
-                    alias = sess.wire_alias_for(words)
-                    groups.setdefault((0, True), []).append(
-                        (sess, None, alias))
+                    hdr = fastpath.publish_header(
+                        topic_str, 0, False, False, None, len(payload))
+                    fb = len(hdr) + len(payload)
+                    ob.queue_frames(members, repeat(hdr), payload)
+                nbytes += fb * len(members)
+                sent += len(members)
                 continue
-            msg = msg_by_eff.get(eff)
-            if msg is None:
-                if payload is None:
-                    payload = wire_frame[payload_skip:]
-                msg = Msg(topic=tuple(words), payload=payload, qos=eff,
-                          mountpoint=mountpoint)
-                msg_by_eff[eff] = msg
-            pid = sess.wire_take_qos(msg)
-            if not pid:
-                if pid == 0:
-                    parked += 1  # window full: pending pump owns it
-                continue  # None: dropped (counted by wire_take_qos)
-            if is5:
-                alias = sess.wire_alias_for(words)
-                groups.setdefault((eff, True), []).append(
-                    (sess, pid, alias))
+            if payload is None:
+                payload = wire_frame[payload_skip:]
+            eff, is5 = kind >> 1, kind & 1
+            if eff:
+                msg = msg_by_eff.get(eff)
+                if msg is None:
+                    msg = msg_by_eff[eff] = Msg(
+                        topic=tuple(words), payload=payload, qos=eff,
+                        mountpoint=mountpoint)
+                members, pids, n, nq = wire_take_qos_fanout(
+                    members, msg, cfg.max_inflight_messages,
+                    cfg.max_online_messages)
+                pended += n
+                parked += n + nq
+                if not members:
+                    continue
             else:
-                groups.setdefault((eff, False), []).append(
-                    (sess, pid, None))
-        if v4_plain:
-            if wire_frame is not None:
-                fb = len(wire_frame)
-                for sess in v4_plain:
-                    sess.transport.write(wire_frame)
-            else:
-                hdr = fastpath.publish_header(
-                    topic_str, 0, False, False, None, len(payload))
-                iov = (hdr, payload)
-                fb = len(hdr) + len(payload)
-                for sess in v4_plain:
-                    sess.transport.write_iov(iov)
-            nbytes += fb * len(v4_plain)
-            sent += len(v4_plain)
-        if groups and payload is None:
-            payload = wire_frame[payload_skip:]
-        for (eff, is5), members in groups.items():
-            pids = [p for _s, p, _a in members]
-            aliases = [a for _s, _p, a in members] if is5 else None
-            arena, offs = fastpath.publish_headers_batch(
-                topic_str, eff, False, False, pids, len(payload),
-                is5, aliases)
-            fastpath.fanout_batches += 1
+                pids = [None] * len(members)
+            aliases = ([s.wire_alias_for(words) for s in members]
+                       if is5 else None)
             plen = len(payload)
-            for i, iov in enumerate(wire_batch_iovs(arena, offs,
-                                                    payload)):
-                members[i][0].transport.write_iov(iov)
-                nbytes += (offs[i + 1] - offs[i]) + plen
+            arena, offs = fastpath.publish_headers_batch(
+                topic_str, eff, False, False, pids, plen, bool(is5),
+                aliases)
+            fastpath.fanout_batches += 1
+            ob.queue_frames(members,
+                            map(memoryview(arena).__getitem__,
+                                map(slice, offs, offs[1:])), payload)
+            nbytes += offs[-1] - offs[0] + plen * len(members)
             sent += len(members)
         if sent or parked:
-            m.observe("stage_wire_encode_ms",
-                      (time.monotonic() - t0) * 1e3)
+            b.metrics.observe("stage_wire_encode_ms",
+                              (time.monotonic() - t0) * 1e3)
             self.fanout_fast_pubs += 1
             # plain integers, folded into Metrics by the outbox's flush
             # ahead of this turn's writes (broker/egress.py)
-            ob = self.broker.outbox
             ob.queue_in += sent + parked
-            ob.queue_out += sent
+            ob.queue_out += sent + pended
             ob.bytes_sent += nbytes
             ob.publish_sent += sent
-            ob.matches_local += len(recips)
+            ob.matches_local += len(sessions)
             ob.touch()
 
     def _pre_publish(self, msg: Msg) -> Msg:

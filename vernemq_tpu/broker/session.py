@@ -1046,34 +1046,6 @@ class Session:
         fastpath.fastpath_acks += 1
         return True
 
-    def wire_take_qos(self, msg: Msg) -> Optional[int]:
-        """Register a wire-plane QoS≥1 delivery in the in-flight
-        window: allocate the packet id and the waiting_acks entry (the
-        bookkeeping half of the classic deliver path) WITHOUT encoding
-        the frame — the registry batch-encodes all recipients' headers
-        in one native call. 0 = window full, message parked — session
-        pending first, then the queue-level backlog via the same
-        ``_backpressure`` tier the classic refusal takes (the
-        ack-driven pump and ``notify_ready`` replay deliver it
-        classically later); None = no park tier available, dropped.
-        Neither takes a wire write now."""
-        window = min(self.broker.config.max_inflight_messages,
-                     self.receive_max_out)
-        if len(self.waiting_acks) >= window:
-            if len(self.pending) >= \
-                    self.broker.config.max_online_messages:
-                if self.queue is not None:
-                    self.queue._backpressure(msg)
-                    return 0
-                self.broker.metrics.incr("queue_message_drop")
-                return None
-            self.pending.append(msg)
-            return 0
-        pid = self._next_packet_id()
-        self.waiting_acks[pid] = ["puback" if msg.qos == 1 else "pubrec",
-                                  msg, time.monotonic(), False]
-        return pid
-
     def wire_v5_fast_ok(self, frame_bound: int = 0) -> bool:
         """May this v5 session take wire-plane fast delivery? Capless
         sessions always can. A client maximum_packet_size admits the
@@ -1764,6 +1736,54 @@ WIRE_OPEN = 2
 #: seconds of reader pauses ``Session.wire_pause`` sleeps at once (the
 #: bound the governor's token wait keeps, for the same keep-alive)
 WIRE_PAUSE_MAX = 1.0
+
+
+def wire_take_qos_fanout(sessions, msg: Msg, max_inflight: int,
+                         max_online: int) -> Tuple[list, list, int, int]:
+    """Register a wire-plane QoS≥1 delivery of ``msg`` in the in-flight
+    window of every recipient of one fan-out, in order — the bookkeeping
+    half of the classic deliver path (``Session._route``), WITHOUT
+    encoding a frame: the registry batch-encodes all recipients' headers
+    in one native call. What is the same for every recipient is taken
+    once: the config half of the window (``max_inflight``) and the
+    pending cap (``max_online``), the entries' kind and their one clock
+    reading; packet ids are allocated inline, by ``_next_packet_id``'s
+    rule. A full window parks the message in the session's pending; past
+    ``max_online`` the queue-level backlog takes it
+    (``SubscriberQueue._backpressure``, as a session's refusal does on
+    the classic path); a session with no queue drops it and counts
+    ``queue_message_drop``. The ack-driven pump and ``notify_ready``
+    replay deliver a parked message classically later. Returns the
+    sessions that took it, their packet ids, and how many parked in a
+    session's pending and in a queue's backlog."""
+    kind = "puback" if msg.qos == 1 else "pubrec"
+    now = time.monotonic()
+    takers: list = []
+    pids: list = []
+    pended = backlogged = 0
+    for s in sessions:
+        acks = s.waiting_acks
+        window = s.receive_max_out
+        if window > max_inflight:
+            window = max_inflight
+        if len(acks) >= window:
+            if len(s.pending) < max_online:
+                s.pending.append(msg)
+                pended += 1
+            elif s.queue is not None:
+                s.queue._backpressure(msg)
+                backlogged += 1
+            else:
+                s.broker.metrics.incr("queue_message_drop")
+            continue
+        pid = s._next_pid % 65535 + 1
+        s._next_pid = pid
+        if pid in acks:
+            pid = s._next_packet_id()
+        acks[pid] = [kind, msg, now, False]
+        takers.append(s)
+        pids.append(pid)
+    return takers, pids, pended, backlogged
 
 
 def wire_gate(b: "Broker") -> int:

@@ -104,6 +104,8 @@ fastpath_acks = 0       #: ack frames resolved object-free
 inline_chunks = 0       #: recv chunks served whole by the connection's protocol
 task_chunks = 0         #: chunks, or remainders, handed to the connection's task
 fanout_batches = 0      #: batched fanout header encodes (one per fanout)
+fanout_pass_pubs = 0    #: wire-plane publishes whose every row the pass served
+fanout_classic_pubs = 0  #: wire-plane publishes whose rows sent them to route_rows
 egress_flushes = 0      #: outbox flushes (one a loop turn that wrote)
 egress_writes = 0       #: transports written by them
 egress_publishes = 0    #: PUBLISH frames those writes carried
@@ -173,6 +175,8 @@ def stats():
         "wire_inline_chunks": float(inline_chunks),
         "wire_task_chunks": float(task_chunks),
         "wire_fanout_batches": float(fanout_batches),
+        "wire_fanout_pass_pubs": float(fanout_pass_pubs),
+        "wire_fanout_classic_pubs": float(fanout_classic_pubs),
         "wire_egress_flushes": float(egress_flushes),
         "wire_egress_writes": float(egress_writes),
         "wire_egress_publishes": float(egress_publishes),
