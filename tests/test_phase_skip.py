@@ -357,14 +357,18 @@ def test_warm_ladder_compiles_one_form_a_rung(combo, monkeypatch):
 def test_counters_and_ring_name_the_phases(pair, monkeypatch):
     monkeypatch.setattr(tm, "phase_dispatches", 0)
     monkeypatch.setattr(tm, "phase_runs", 0)
+    monkeypatch.setattr(tm, "phase_dense_dispatches", 0)
     rng = random.Random(1)
     pair.m.match_batch(topics_for(rng, 12), _warmup=True)
-    assert (tm.phase_dispatches, tm.phase_runs) == (0, 0)
+    assert (tm.phase_dispatches, tm.phase_runs,
+            tm.phase_dense_dispatches) == (0, 0, 0)
     pair.m.match_batch(topics_for(rng, 12))
     pair.m.match_many([topics_for(rng, 12), topics_for(rng, 9)])
-    # one count an execution, single or super, and its phases summed
+    # one count an execution, single or super, and its phases summed;
+    # the executions that held the dense pass over region 0 apart
     assert (tm.phase_dispatches, tm.phase_runs) \
         == (2, 2 * len(pair.phases))
+    assert tm.phase_dense_dispatches == (2 if "g" in pair.phases else 0)
     single, many = profiler().snapshot("match")[-2:]
     assert single["phases"] == many["phases"] == pair.phases
     assert (single["k"], many["k"]) == (1, 2)
@@ -372,8 +376,9 @@ def test_counters_and_ring_name_the_phases(pair, monkeypatch):
 
 def test_unbucketed_table_counts_no_phase(monkeypatch):
     monkeypatch.setattr(tm, "phase_dispatches", 0)
+    monkeypatch.setattr(tm, "phase_dense_dispatches", 0)
     m = TpuMatcher(max_levels=4, initial_capacity=64)
     m.table.add(["a", "+"], "k", None)
     assert m.match_batch([("a", "b")])[0]
-    assert tm.phase_dispatches == 0
+    assert tm.phase_dispatches == tm.phase_dense_dispatches == 0
     assert "phases" not in profiler().snapshot("match")[-1]

@@ -330,6 +330,10 @@ class Broker:
                               "summed (1-3 each: the dense pass over "
                               "region 0 and probe B are left out while "
                               "their rows hold no live subscription).",
+            "tpu_phase_dense_dispatches": "Of those programs, the ones "
+                                          "that held the dense pass over "
+                                          "region 0 (a live "
+                                          "wildcard-first subscription).",
             "tpu_release_rows": "Matched rows (at least one a "
                                 "submission) the collector's release "
                                 "queue released.",
@@ -514,7 +518,8 @@ class Broker:
             # from a group's own classes a publish
             "share_picks": "Deliveries made to a member drawn from a "
                            "shared subscription (one a publish and "
-                           "group).",
+                           "shared subscription, ShareName and "
+                           "filter).",
             "share_wire_picks": "Of those, the ones the wire plane's "
                                 "fanout wrote (a lone online session "
                                 "with fast options).",
@@ -524,6 +529,10 @@ class Broker:
             "share_offline_picks": "Deliveries to an offline member's "
                                    "queue because no member of the "
                                    "group was online.",
+            "wire_share_split_picks": "Of the share_picks, draws for a "
+                                      "ShareName already drawn for under "
+                                      "another filter in the same "
+                                      "publish.",
             # cluster delivery spool (cluster/spool.py): depth +
             # outstanding-ack gauges, published to $SYS/Prometheus
             "cluster_spool_depth_frames": "QoS>=1 cluster frames "

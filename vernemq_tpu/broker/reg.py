@@ -536,22 +536,24 @@ class Registry:
         each of its members that carries a payload predicate (whose
         predicate then decides, row by row, as for a plain subscription;
         an aggregating member's value folds into ITS window). Runs on
-        the engine's thread; rows already expanded are not repeated."""
+        the engine's thread; rows already expanded are not repeated, and
+        a member of two filters of one ShareName gets a row under each."""
         out = None
         for f, key, _opts in rows:
             if not (isinstance(key, tuple) and len(key) == 3
                     and key[2] is None and key[0] == "$g"):
                 continue
-            g = self._groups.get((mountpoint, key[1], tuple(f)))
+            fw = tuple(f)
+            g = self._groups.get((mountpoint, key[1], fw))
             if g is None or not g.filtered:
                 continue
             if out is None:
                 out = list(rows)
-                seen = {k for _f, k, _o in rows}
+                seen = {(tuple(rf), k) for rf, k, _o in rows}
             for sid, opts in list(g.filtered.items()):
                 mk = ("$g", key[1], sid)
-                if mk not in seen:
-                    seen.add(mk)
+                if (fw, mk) not in seen:
+                    seen.add((fw, mk))
                     out.append((f, mk, opts))
         return rows if out is None else out
 
@@ -934,26 +936,27 @@ class Registry:
         kinds: List[int] = []
         fast = True
         frame_bound = 0
-        shared: Optional[set] = None  # group names drawn from
+        shared: Optional[List[str]] = None  # ShareName of each draw
         for _f, key, opts in rows:
             if not (isinstance(key, tuple) and len(key) == 2):
-                # a shared subscription's row: its member is drawn now and
-                # classified below as a plain row is. A remote node's
-                # pointer, a member's own predicate row, a group whose
-                # draw is no local online member, or a name met twice
-                # (two filters of one group) take the classic path
+                # a shared subscription's row, one a (ShareName, filter):
+                # its member is drawn now and classified below as a
+                # plain row is; two filters of one name are two rows and
+                # two draws. A remote node's pointer, a member's own
+                # predicate row, or a group whose draw is no local online
+                # member take the classic path
                 picked = None
                 if (isinstance(key, tuple) and len(key) == 3
-                        and key[2] is None and key[0] == "$g"
-                        and (shared is None or key[1] not in shared)):
+                        and key[2] is None and key[0] == "$g"):
                     picked = self._share_wire_pick(mountpoint, key[1], _f,
                                                    from_sid)
                 if picked is None:
                     fast = False
                     break
                 if shared is None:
-                    shared = set()
-                shared.add(key[1])
+                    shared = [key[1]]
+                else:
+                    shared.append(key[1])
                 key, opts = picked
             if opts.no_local and key == from_sid:
                 continue
@@ -1012,8 +1015,11 @@ class Registry:
                 self._wire_fanout(mountpoint, words, topic_str, payload,
                                   wire_frame, payload_skip, sessions, kinds)
             if shared:
-                fastpath.share_picks += len(shared)
-                fastpath.share_wire_picks += len(shared)
+                n = len(shared)
+                fastpath.share_picks += n
+                fastpath.share_wire_picks += n
+                if n > 1:
+                    fastpath.share_split_picks += n - len(set(shared))
             return len(sessions)
         # complex fanout: ONE Msg, the exact classic path (host
         # predicate phase included — a racing filter subscription must
@@ -1154,8 +1160,14 @@ class Registry:
         trace=None,
     ) -> int:
         """The fold body (vmq_reg:publish/3 fold fun, vmq_reg.erl:326-353):
-        local rows enqueue, shared rows collect into groups, node rows
-        forward. Shared groups then go through policy selection.
+        local rows enqueue, shared rows collect by shared subscription,
+        node rows forward; each shared subscription then gets one member
+        by policy selection. A shared subscription is (ShareName, filter)
+        as MQTT 5.0 §4.8.2 keys it: "a message that matches both Shared
+        Subscriptions will be processed separately by both of them", so
+        ``$share/g/a/+`` and ``$share/g/a/#`` each get a delivery of
+        ``a/x``. Upstream 1.6's fold collects by ShareName alone and
+        would make one.
         ``origin_local=False`` (publish arriving over the cluster channel)
         serves local plain rows only — node and group rows were already
         covered by the origin node (vmq_cluster_com.erl:198-203).
@@ -1163,9 +1175,9 @@ class Registry:
         node-row forwards onto the cluster envelope so the receiving
         node resumes it (one cross-node Perfetto trace)."""
         matches = 0
-        # by group NAME, as upstream's fold collects: the ShareGroup of
-        # each of the name's rows, and the members whose predicate passed
-        groups: Dict[str, List[Any]] = {}
+        # by (ShareName, filter words): the shared subscription's
+        # ShareGroup, and its members whose predicate passed
+        groups: Dict[Tuple[str, Tuple[str, ...]], List[Any]] = {}
         forwarded_nodes = set()  # one msg frame per remote node per publish
         # batched QoS0 fanout (the host hot path): recipients whose
         # delivery needs NO per-subscription transform and whose session
@@ -1181,14 +1193,14 @@ class Registry:
                 if not origin_local:
                     continue
                 _, group, sid = key
+                sk = (group, tuple(_filter))
                 if sid is None:  # the shared subscription's one row
-                    g = self._groups.get((msg.mountpoint, group,
-                                          tuple(_filter)))
+                    g = self._groups.get((msg.mountpoint,) + sk)
                     if g is not None:
-                        groups.setdefault(group, []).append(g)
+                        groups.setdefault(sk, []).append(g)
                 elif not (opts.no_local and sid == from_sid):
                     # a member whose payload predicate passed
-                    groups.setdefault(group, []).append((sid, opts))
+                    groups.setdefault(sk, []).append((sid, opts))
                 continue
             if isinstance(key, str):  # remote node pointer
                 if origin_local and key not in forwarded_nodes:
@@ -1223,9 +1235,14 @@ class Registry:
                 matches += 1
         if fan0:
             self._fanout_qos0(msg, fan0)
-        for found in groups.values():
-            if self._publish_shared(msg, found, from_sid):
-                matches += 1
+        if groups:
+            names = set()
+            for (group, _f), found in groups.items():
+                if self._publish_shared(msg, found, from_sid):
+                    matches += 1
+                    if group in names:
+                        fastpath.share_split_picks += 1
+                    names.add(group)
         if matches:
             self.broker.metrics.incr("router_matches_local", matches)
         return matches
@@ -1352,8 +1369,8 @@ class Registry:
         first class that yields a delivery — ``prefer_local``: online
         local, then remote, then offline local; ``random``: online local
         and remote together, then offline local; ``local_only``: online
-        local, then offline local. ``found`` holds the ShareGroup of each
-        row of the group's name and the members whose payload predicate
+        local, then offline local. ``found`` holds the shared
+        subscription's ShareGroup and the members whose payload predicate
         passed. A member's own QoS, rap and subscription id apply
         (``_prep_out``); a remote member's delivery rides the cluster
         ``enq`` channel (vmq_shared_subscriptions.erl:86-88)."""
@@ -1565,6 +1582,7 @@ class Registry:
             # compiled into them (1-3 each; 1 = probe A alone)
             out["tpu_phase_dispatches"] = _tm.phase_dispatches
             out["tpu_phase_runs"] = _tm.phase_runs
+            out["tpu_phase_dense_dispatches"] = _tm.phase_dense_dispatches
         col = getattr(self.broker, "_collector", None)
         if col is not None:
             # small flushes served host-side by hybrid dispatch

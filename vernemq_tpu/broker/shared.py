@@ -1,12 +1,17 @@
 """Shared subscriptions (``$share/<group>/<filter>``) as ONE routing row.
 
-A shared subscription is one value in the host trie, one row in the
-device table and one row through the match service, whatever its number
-of members: keyed ``("$g", group, None)`` and carrying no options. Its
-members live in the registry's :class:`ShareGroup`, keyed by (mountpoint,
-group, filter words), sid -> options, split into the classes upstream's
-selection draws from (``vmq_shared_subscriptions.erl:26-106``): local
-members whose queue is online, remote members, local members offline.
+A shared subscription is (ShareName, filter), as MQTT 5.0 §4.8.2 keys
+it: ``$share/g/a/+`` and ``$share/g/a/#`` are two, each owed its own
+delivery of a publish both match (upstream 1.6 collects by ShareName and
+makes one). It is one value in the host trie, one row in the device table
+and one row through the match service, whatever its number of members:
+keyed ``("$g", group, None)`` under its filter and carrying no options, so
+the (filter, key) pair every row store already keys by tells two filters
+of one name apart. Its members live in the registry's :class:`ShareGroup`,
+keyed by (mountpoint, group, filter words), sid -> options, split into the
+classes upstream's selection draws from
+(``vmq_shared_subscriptions.erl:26-106``): local members whose queue is
+online, remote members, local members offline.
 Each class is an indexed set, so a draw costs the same at 500 members as
 at 3; the queue's own state changes keep the online class
 (``Registry.share_member_moved``).
@@ -21,7 +26,8 @@ from __future__ import annotations
 from typing import Any, Dict, List, Tuple
 
 def row_key(group: str) -> Tuple[str, str, None]:
-    """The key of a shared subscription's one row in a trie or table."""
+    """The key of a shared subscription's one row in a trie or table,
+    stored under the subscription's filter."""
     return ("$g", group, None)
 
 

@@ -60,10 +60,13 @@ wide_failures = 0
 #: The flat form's phases, process totals beside them (gauges
 #: ``tpu_phase_*``): executions of a windowed match program, single or
 #: super, that counted as a dispatch, and the phases compiled into each
-#: (1-3: dense region 0, probe A, probe B), summed. Folded once a
-#: dispatch, where its ring record is written.
+#: (1-3: dense region 0, probe A, probe B), summed, and of those
+#: executions the ones whose program held the dense pass over region 0
+#: (a live wildcard-first row). Folded once a dispatch, where its ring
+#: record is written.
 phase_dispatches = 0
 phase_runs = 0
+phase_dense_dispatches = 0
 #: Device programs still compiling: a reg view's warm ladder not yet
 #: complete, and each background warm-up of a cold shape in flight. The
 #: sysmon stall witness reads it to mark a stall ``cold``.
@@ -335,7 +338,7 @@ class _FoldPhases:
     def record(self, t_disp: float, dur: float, **fields: Any) -> None:
         """One observation a family and the dispatch ring's record
         (``dur``: what ``stage_device_dispatch_ms`` observed)."""
-        global phase_dispatches, phase_runs
+        global phase_dispatches, phase_runs, phase_dense_dispatches
         self.close()
         ms = self.ms.get
         prep = ms("stage_fold_prep_ms", 0.0)
@@ -355,6 +358,8 @@ class _FoldPhases:
             with _totals_lock:
                 phase_dispatches += 1
                 phase_runs += len(self.phases)
+                if self.phases[0] == "g":
+                    phase_dense_dispatches += 1
         record_dispatch(
             "match", t_disp, dur, prep_ms=round(prep, 4),
             launch_ms=round(launch, 4), wait_ms=round(wait, 4),

@@ -115,11 +115,13 @@ egress_offload_writes = 0   #: of egress_writes, handed to the native writer
 egress_offload_sent = 0     #: hand-offs the writer finished sending
 egress_offload_lag_us = 0   #: sum over those of hand-off -> last byte sent
 egress_offload_dropped = 0  #: backlogs the writer dropped: connection lost
-# shared subscriptions (broker/reg.py): one member drawn a publish
+# shared subscriptions (broker/reg.py): one member drawn a publish and
+# shared subscription, (ShareName, filter)
 share_picks = 0         #: deliveries made to a shared subscription's member
 share_wire_picks = 0    #: of those, written by the wire plane's fanout
 share_stale_picks = 0   #: draws whose member was online no more (repaired)
 share_offline_picks = 0  #: deliveries to an offline member: none online
+share_split_picks = 0   #: of share_picks, a name drawn again (other filter)
 
 
 def load_native():
@@ -191,6 +193,7 @@ def stats():
         "share_wire_picks": float(share_wire_picks),
         "share_stale_picks": float(share_stale_picks),
         "share_offline_picks": float(share_offline_picks),
+        "wire_share_split_picks": float(share_split_picks),
     }
 
 
